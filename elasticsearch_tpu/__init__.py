@@ -32,16 +32,14 @@ jax.config.update("jax_enable_x64", True)
 # it on disk makes cold-start p99 a one-time cost per machine instead of a
 # per-process multi-second stall (ref: the reference warms searchers via
 # indices/warmer/; here the "warm" artifact is the compiled executable).
-_cache_dir = os.environ.get(
-    "ELASTICSEARCH_TPU_XLA_CACHE",
-    os.path.join(os.path.expanduser("~"), ".cache", "elasticsearch_tpu",
-                 "xla"))
-if _cache_dir and _cache_dir != "0":
-    try:
-        os.makedirs(_cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", _cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.2)
-    except Exception:  # noqa: BLE001 — cache is an optimization only
-        pass
+# JAX reads JAX_COMPILATION_CACHE_DIR itself; only without it does the
+# cache go to one fixed, git-ignored directory beside the package (the
+# path is part of the cache key, so it never moves).
+if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+    jax.config.update(
+        "jax_compilation_cache_dir",
+        os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), ".xla_cache"))
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.2)
 
 __version__ = "0.1.0"

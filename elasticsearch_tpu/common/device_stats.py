@@ -39,7 +39,14 @@ import contextvars
 import threading
 import time
 
+import jax
+
 _LOCK = threading.Lock()
+
+# the trace state of top-level (eager) code; any jit/vmap/grad/shard_map
+# trace in progress compares unequal to it
+with jax.core.eval_context():
+    _TOP_LEVEL_TRACE = jax.core.get_opaque_trace_state()
 
 # registry bound: programs enter via bounded plan caches, so this cap is a
 # backstop against key churn, not a working-set limit
@@ -119,7 +126,6 @@ _REGISTRY: dict[tuple[str, str], ProgramRecord] = {}
 
 def _aval_of(x):
     if hasattr(x, "shape") and hasattr(x, "dtype"):
-        import jax
         return jax.ShapeDtypeStruct(x.shape, x.dtype)
     return x
 
@@ -148,21 +154,15 @@ class InstrumentedProgram:
         self.record = rec
 
     def __call__(self, *args, **kwargs):
-        import jax.core as _core
-        if not _core.trace_state_clean():
+        if jax.core.get_opaque_trace_state() != _TOP_LEVEL_TRACE:
             return self.jit(*args, **kwargs)
         from .metrics import current_profiler, device_events_snapshot
         c0, cms0 = device_events_snapshot()
         t0 = time.perf_counter()
-        out = self.jit(*args, **kwargs)
-        try:
-            # charge THIS program for its own device work: without the
-            # barrier an async backend bills the next caller's wall
-            # clock for whatever this dispatch left enqueued
-            import jax
-            jax.block_until_ready(out)
-        except Exception:  # noqa: BLE001 — non-array outputs stay timed
-            pass
+        # charge THIS program for its own device work: without the
+        # barrier an async backend bills the next caller's wall clock
+        # for whatever this dispatch left enqueued
+        out = jax.block_until_ready(self.jit(*args, **kwargs))
         dt = (time.perf_counter() - t0) * 1000.0
         c1, cms1 = device_events_snapshot()
         rec = self.record
@@ -174,12 +174,8 @@ class InstrumentedProgram:
                 rec.compiles += c1 - c0
                 rec.compile_ms += cms1 - cms0
             if rec._avals is None:
-                try:
-                    import jax
-                    rec._avals = jax.tree_util.tree_map(
-                        _aval_of, (args, kwargs))
-                except Exception:  # noqa: BLE001 — cost stays None-safe
-                    rec._avals = None
+                rec._avals = jax.tree_util.tree_map(
+                    _aval_of, (args, kwargs))
         prof = current_profiler()
         if prof is not None:
             prof.note_program(rec.name, dt)
@@ -267,18 +263,10 @@ def hbm_poll() -> dict[str, dict]:
     memory_stats (CPU) report zeros with supported=False instead of
     erroring — the sampler ring and gauges stay shape-stable across
     platforms. Updates the process-lifetime high-water mark."""
-    try:
-        import jax
-        devs = jax.devices()
-    except Exception:  # noqa: BLE001 — no backend at all
-        return {}
     out: dict[str, dict] = {}
-    for d in devs:
-        ident = f"{getattr(d, 'platform', 'dev')}:{getattr(d, 'id', 0)}"
-        try:
-            ms = d.memory_stats()
-        except Exception:  # noqa: BLE001 — backend refuses: degrade
-            ms = None
+    for d in jax.devices():
+        ident = f"{d.platform}:{d.id}"
+        ms = d.memory_stats()
         if not ms:
             out[ident] = {"bytes_in_use": 0, "peak_bytes": 0,
                           "high_water_bytes":

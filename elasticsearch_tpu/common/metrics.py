@@ -200,29 +200,26 @@ _DEVICE_LOCK = threading.Lock()
 _LISTENER_INSTALLED = False
 
 
+def _on_compile_duration(name, secs, **kw):  # noqa: ANN001 — jax callback
+    if "/jax/core/compile/" not in name:
+        return
+    with _DEVICE_LOCK:
+        if name.endswith("backend_compile_duration"):
+            _DEVICE_EVENTS["compiles"] += 1
+        _DEVICE_EVENTS["compile_ms"] += secs * 1000.0
+
+
 def _install_compile_listener() -> None:
     """Register a jax.monitoring duration listener (idempotent). Compile
     events fire only on an actual retrace+compile, never on a cache-hit
-    dispatch — exactly the signal the no-retrace tripwire needs. Degrades
-    to zeros on jax builds without the monitoring API."""
+    dispatch — exactly the signal the no-retrace tripwire needs."""
     global _LISTENER_INSTALLED
     if _LISTENER_INSTALLED:
         return
     _LISTENER_INSTALLED = True
-    try:
-        import jax
-
-        def _on_duration(name, secs, **kw):  # noqa: ANN001 — jax callback
-            if "/jax/core/compile/" not in name:
-                return
-            with _DEVICE_LOCK:
-                if name.endswith("backend_compile_duration"):
-                    _DEVICE_EVENTS["compiles"] += 1
-                _DEVICE_EVENTS["compile_ms"] += secs * 1000.0
-
-        jax.monitoring.register_event_duration_secs_listener(_on_duration)
-    except Exception:  # noqa: BLE001 — observability must never break serving
-        pass
+    import jax
+    jax.monitoring.register_event_duration_secs_listener(
+        _on_compile_duration)
 
 
 def device_events_snapshot() -> tuple[int, float]:

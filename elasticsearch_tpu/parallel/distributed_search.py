@@ -43,19 +43,6 @@ K1_DEFAULT = 1.2
 B_DEFAULT = 0.75
 
 
-def _shard_map(fn, *, mesh, in_specs, out_specs):
-    """jax.shard_map across jax versions: top-level `check_vma` (new) vs
-    experimental `check_rep` (0.4.x) — replica-consistency checks off
-    either way (the query batch is INTENTIONALLY different per replica)."""
-    try:
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    except (AttributeError, TypeError):
-        from jax.experimental.shard_map import shard_map as _sm
-        return _sm(fn, mesh=mesh, in_specs=in_specs,
-                   out_specs=out_specs, check_rep=False)
-
-
 def _query_step(doc_ids, tf, dl, sum_dl, doc_counts,
                 term_starts, term_lens, boosts, *, Wt: int, n_pad: int,
                 k: int, k1: float, b: float):
@@ -148,10 +135,11 @@ class DistributedSearcher:
         query_specs = P(SHARD_AXIS, REPLICA_AXIS)
         out_specs = (P(REPLICA_AXIS), P(REPLICA_AXIS),
                      P(REPLICA_AXIS), P(REPLICA_AXIS))
-        mapped = _shard_map(
+        # check_vma off: the query batch is INTENTIONALLY different per replica
+        mapped = jax.shard_map(
             fn, mesh=self.mesh,
             in_specs=(shard_specs,) * 5 + (query_specs,) * 3,
-            out_specs=out_specs)
+            out_specs=out_specs, check_vma=False)
         from ..common.device_stats import instrument
         step = instrument("dist:query_step", jax.jit(mapped), key=key)
         self._step_cache.put(key, step, weight=1)
@@ -193,11 +181,12 @@ class DistributedSearcher:
         from ..common.device_stats import instrument
         step = instrument(
             "dist:knn_step",
-            jax.jit(_shard_map(
+            jax.jit(jax.shard_map(
                 knn_step, mesh=self.mesh,
                 in_specs=(P(SHARD_AXIS), P(SHARD_AXIS), P(REPLICA_AXIS),
                           P(REPLICA_AXIS)),
-                out_specs=(P(REPLICA_AXIS), P(REPLICA_AXIS)))),
+                out_specs=(P(REPLICA_AXIS), P(REPLICA_AXIS)),
+                check_vma=False)),
             key=key)
         self._step_cache.put(key, step, weight=1)
         return step

@@ -258,11 +258,13 @@ def _plan_histogram(spec, pctx):
     bases = np.zeros((stack.s_pad, stack.g_pad), np.float64)
     hvalid = np.zeros((stack.s_pad, stack.g_pad), bool)
     n_bins = 1
+    int_column = True
     for si, rows in enumerate(stack.shard_rows):
         for gi, (_i, seg) in enumerate(rows):
             nc = seg.numerics.get(field)
             if nc is None:
                 continue
+            int_column = int_column and nc.dtype == "i64"
             mn, mx = _col_minmax(seg, field, nc)
             if not (np.isfinite(mn) and np.isfinite(mx)):
                 continue              # empty column: zero contribution
@@ -275,16 +277,22 @@ def _plan_histogram(spec, pctx):
             bases[si, gi] = base
             hvalid[si, gi] = True
             n_bins = max(n_bins, bins)
-    pctx.emit(bases, _OP_S)
+    # the same bucketing operands as the per-segment collect
+    # (ops/aggs.hist_bins): exact i64 for integral columns, and the
+    # interval a runtime operand either way
+    from ..ops.aggs import hist_bins, hist_operands
+    base_op, interval_op = hist_operands(int_column, bases, interval)
+    pctx.emit(base_op, _OP_S)
     pctx.emit(hvalid, _OP_S)
-    sig = (spec.type, field, float(interval), n_bins)
+    pctx.emit(interval_op, _OP_R)
+    sig = (spec.type, field, float(interval), n_bins, base_op.dtype.str)
 
     def dev(d, m):
         base = d.pop()                           # [G]
         ok_g = d.pop()                           # [G]
+        iv = d.pop()                             # scalar
         num = d.fields[field]
-        idx = jnp.floor((num.vals.astype(jnp.float64)
-                         - base[:, None]) / interval).astype(jnp.int32)
+        idx = hist_bins(num.vals, base[:, None], iv)
         ok = (~num.missing) & (idx >= 0) & (idx < n_bins) \
             & ok_g[:, None]                      # [G, N]
 

@@ -62,7 +62,6 @@ from ..search.query_dsl import (
     SegmentContext, TermFilterNode, _bisect, _coerce_to_column, _next_down,
     _next_up, _pow2_window,
 )
-from .distributed_search import _shard_map
 from .mesh import (REPLICA_AXIS, SHARD_AXIS, SHARED_EXEC_LOCK, index_sharding,
                    make_mesh)
 
@@ -1023,8 +1022,8 @@ def _build_program(mesh, devfn, field_kinds: tuple, op_kinds: tuple,
                      + field_specs + op_specs)
     out_specs = (P(REPLICA_AXIS),) * 3 \
         + (P(None, REPLICA_AXIS),) * (2 + len(agg_devfns))
-    return jax.jit(_shard_map(step, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs))
+    return jax.jit(jax.shard_map(step, mesh=mesh, in_specs=in_specs,
+                                 out_specs=out_specs, check_vma=False))
 
 
 def _build_sorted_program(mesh, devfn, field_kinds: tuple, op_kinds: tuple,
@@ -1133,8 +1132,8 @@ def _build_sorted_program(mesh, devfn, field_kinds: tuple, op_kinds: tuple,
                      + field_specs + op_specs)
     out_specs = (P(REPLICA_AXIS),) * 3 \
         + (P(None, REPLICA_AXIS),) * (2 + len(agg_devfns))
-    return jax.jit(_shard_map(step, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs))
+    return jax.jit(jax.shard_map(step, mesh=mesh, in_specs=in_specs,
+                                 out_specs=out_specs, check_vma=False))
 
 
 def execute_sorted(stack: MeshStack, node: Node, stats, sort_specs,
@@ -1300,8 +1299,8 @@ def _build_blockwise_program(mesh, bplan, *, k: int, n_queries: int,
     in_specs = tuple([P(SHARD_AXIS), P(SHARD_AXIS)]
                      + field_specs + op_specs)
     out_specs = (P(REPLICA_AXIS),) * 3 + (P(None, REPLICA_AXIS),) * 2
-    return jax.jit(_shard_map(step, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs))
+    return jax.jit(jax.shard_map(step, mesh=mesh, in_specs=in_specs,
+                                 out_specs=out_specs, check_vma=False))
 
 
 def _try_blockwise(stack: MeshStack, node: Node, stats, *, k: int,

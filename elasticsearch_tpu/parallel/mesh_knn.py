@@ -50,7 +50,6 @@ from ..index.segment import next_pow2
 from ..ops import ann as ann_ops
 from ..ops import bm25 as bm25_ops
 from ..ops.topk import merge_running_topk
-from .distributed_search import _shard_map
 from .mesh import REPLICA_AXIS, SHARD_AXIS, index_sharding
 from . import mesh_exec
 from .mesh_exec import SEG_SHIFT, _DevCtx, _PlanCtx, _Unsupported
@@ -749,5 +748,5 @@ def _build_knn_program(vstack, *, metric, precision, k, kk, n_queries,
     in_specs.extend(f_op_specs)
     in_specs.append(P(REPLICA_AXIS))         # qv
     out_specs = (P(REPLICA_AXIS),) * 3 + (P(None, REPLICA_AXIS),) * 2
-    return jax.jit(_shard_map(step, mesh=mesh, in_specs=tuple(in_specs),
-                              out_specs=out_specs))
+    return jax.jit(jax.shard_map(step, mesh=mesh, in_specs=tuple(in_specs),
+                                 out_specs=out_specs, check_vma=False))

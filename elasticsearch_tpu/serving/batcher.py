@@ -199,11 +199,10 @@ class SearchBatcher:
             outs = self.node._packed_search(
                 name, [x.body for x in batch], size=size, from_=from_,
                 t0=t0, specs=[x.spec for x in batch])
-        except Exception as ex:  # noqa: BLE001 — degrade each to general
+        except Exception as ex:  # noqa: BLE001 — every member's error
             self._record_error(ex)
-            self.node._packed_error()
             for x in batch:
-                x.out = None
+                x.err = ex
                 x.event.set()
             return
         self._book(batch)
@@ -233,8 +232,8 @@ class SearchBatcher:
     def drain_batched(self, key: tuple, index: str) -> None:
         """Leader epilogue: serve every follower that queued behind this
         leader's solo execution as Q>1 `_search_batched` batches, then
-        release leadership. Never raises — a failing batch degrades its
-        members to the general path."""
+        release leadership. Never raises — a failing batch is its
+        members' error (each follower re-raises it)."""
         key = ("gen", *key)
         try:
             while True:
@@ -261,13 +260,10 @@ class SearchBatcher:
         try:
             outs = self.node._search_batched(
                 [(index, x.body) for x in batch])
-        except Exception as ex:  # noqa: BLE001 — degrade each to general
+        except Exception as ex:  # noqa: BLE001 — every member's error
             self._record_error(ex)
-            self._log_anomaly(
-                "coalesced batch failed; members fall to the general "
-                "path", exc_info=True)
             for x in batch:
-                x.out = None
+                x.err = ex
                 x.event.set()
             return
         self._book(batch)

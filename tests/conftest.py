@@ -13,11 +13,6 @@ if "xla_force_host_platform_device_count" not in _flags:
     os.environ["XLA_FLAGS"] = (_flags + " --xla_force_host_platform_device_count=8").strip()
 
 import jax  # noqa: E402
-
-# The hosted environment prepends its own TPU platform to jax_platforms even
-# when the env var says cpu; re-pin after import (before backend init).
-jax.config.update("jax_platforms", "cpu")
-
 import pytest  # noqa: E402
 
 # Arm the chaos leak detectors for the WHOLE suite: every Engine.close()
@@ -40,3 +35,20 @@ def pytest_configure(config):
 @pytest.fixture(scope="session")
 def devices():
     return jax.devices()
+
+
+@pytest.fixture(scope="session", autouse=True)
+def _roomy_disk():
+    """Tier-1 must not depend on how full the host's disk is: the cluster
+    tests allocate replicas through the disk-watermark decider, which
+    refuses every node once the real filesystem reports >= 85% used
+    (total - free, the reference's formula) — as a quota'd sandbox volume
+    does with 20 GB free. Tests of the watermarks inject their own usages."""
+    import collections
+    import shutil
+    usage = collections.namedtuple("usage", "total used free")
+    mp = pytest.MonkeyPatch()
+    mp.setattr(shutil, "disk_usage",
+               lambda path: usage(100 << 30, 40 << 30, 60 << 30))
+    yield
+    mp.undo()

@@ -169,19 +169,31 @@ class TestFallbackLadder:
         s.execute_knn("vec", qv[:1].tolist(), k=5)
         assert s.last_knn_mode == "exact"
 
-    def test_failed_build_counts_fallback(self, searcher, corpus,
-                                          monkeypatch):
+    def test_declined_build_counts_fallback(self, searcher, corpus,
+                                            monkeypatch):
         _v, _t, qv = corpus
         from elasticsearch_tpu.index import segment as segment_mod
         monkeypatch.setattr(segment_mod.VectorColumn, "build_ivf",
-                            lambda self, *a, **k: (_ for _ in ()).throw(
-                                RuntimeError("boom")))
+                            lambda self, *a, **k: None)
         searcher._ivf_local.clear()
         before = searcher._path_stats.get("ann_fallbacks", 0)
         r = searcher.execute_knn("vec", qv[:1].tolist(), k=5)
         assert searcher.last_knn_mode == "exact"
         assert searcher._path_stats.get("ann_fallbacks", 0) == before + 1
         assert local_ids(r)          # still serves results
+
+    def test_failed_build_is_the_requests_error(self, searcher, corpus,
+                                                monkeypatch):
+        """A build that raises (a device failure) is never hidden behind
+        the exact lane."""
+        _v, _t, qv = corpus
+        from elasticsearch_tpu.index import segment as segment_mod
+        monkeypatch.setattr(segment_mod.VectorColumn, "build_ivf",
+                            lambda self, *a, **k: (_ for _ in ()).throw(
+                                RuntimeError("boom")))
+        searcher._ivf_local.clear()
+        with pytest.raises(RuntimeError, match="boom"):
+            searcher.execute_knn("vec", qv[:1].tolist(), k=5)
 
     def test_tombstones_are_excluded(self, tmp_path, corpus):
         vecs, _t, qv = corpus

@@ -189,8 +189,23 @@ class TestQuantFallback:
         assert s.last_quant_mode is None
         assert s._path_stats.get("ann_quantized_fallbacks", 0) >= 1
 
-    def test_failed_build_counts_fallback(self, engine, corpus,
-                                          monkeypatch):
+    def test_declined_build_counts_fallback(self, engine, corpus,
+                                            monkeypatch):
+        from elasticsearch_tpu.index.segment import VectorColumn
+        _v, _p, qv = corpus
+        monkeypatch.setattr(VectorColumn, "build_quant",
+                            lambda self, *a, **kw: None)
+        s = make_searcher(engine, quantization="int8")
+        res = s.execute_knn("vec", qv[:1].tolist(), k=5)
+        assert s.last_knn_mode == "ann"        # f32 IVF still serves
+        assert s.last_quant_mode is None
+        assert s._path_stats.get("ann_quantized_fallbacks", 0) >= 1
+        assert (res.doc_keys[0] >= 0).any()
+
+    def test_failed_build_is_the_requests_error(self, engine, corpus,
+                                                monkeypatch):
+        """A build that raises (a device failure) is never hidden behind
+        the f32 scan (ISSUE 21)."""
         from elasticsearch_tpu.index.segment import VectorColumn
         _v, _p, qv = corpus
 
@@ -198,11 +213,8 @@ class TestQuantFallback:
             raise RuntimeError("quant build failed")
         monkeypatch.setattr(VectorColumn, "build_quant", boom)
         s = make_searcher(engine, quantization="int8")
-        res = s.execute_knn("vec", qv[:1].tolist(), k=5)
-        assert s.last_knn_mode == "ann"        # f32 IVF still serves
-        assert s.last_quant_mode is None
-        assert s._path_stats.get("ann_quantized_fallbacks", 0) >= 1
-        assert (res.doc_keys[0] >= 0).any()
+        with pytest.raises(RuntimeError, match="quant build failed"):
+            s.execute_knn("vec", qv[:1].tolist(), k=5)
 
 
 ANN_SETTINGS = {"number_of_shards": 1,

@@ -1,12 +1,12 @@
 """bench.py always-emit guard (ISSUE 5 satellite — the r05 regression).
 
 Round 5 exited rc=124 with NO one-line JSON ("parsed": null): the harness
-timeout struck while a leg hung on an experimental platform and the
-bailout handler wasn't armed yet. The guards now install at module import
-— BEFORE the first leg — so a forced hang still prints the headline line:
-SIGALRM at the budget edge, SIGTERM/SIGINT from the harness's first
-strike. `BENCH_SELFTEST_HANG=1` simulates the hang without touching jax,
-keeping this tier-1 fast.
+timeout struck while a leg hung and the bailout handler wasn't armed yet.
+The guards install at module import — BEFORE the first leg — so a forced
+hang still prints the headline line: SIGALRM at the budget edge,
+SIGTERM/SIGINT from the harness's first strike. The line is evidence, not
+success: every bail-out exits NON-ZERO (ISSUE 21). `BENCH_SELFTEST_HANG=1`
+simulates the hang without touching jax, keeping this tier-1 fast.
 """
 
 import json
@@ -36,12 +36,13 @@ def _json_line(stdout: str) -> dict:
 
 def test_sigalrm_budget_edge_emits_json_on_hang():
     """A leg hung past the whole budget: the import-time SIGALRM guard
-    prints the line and exits 0 instead of dying silently at rc=124."""
+    prints the line instead of dying silently at rc=124 — and exits
+    non-zero, because a run that bailed out did not succeed."""
     out = subprocess.run(
         [sys.executable, BENCH],
         env=_env(BENCH_TIME_BUDGET="1", BENCH_ALARM_MARGIN="1"),
         capture_output=True, text=True, timeout=60)
-    assert out.returncode == 0, out.stderr[-500:]
+    assert out.returncode == 1, out.stderr[-500:]
     line = _json_line(out.stdout)
     assert "error" in line
     assert "budget" in line["error"] or "signal" in line["error"]
@@ -58,7 +59,7 @@ def test_sigterm_first_strike_emits_json_on_hang():
     time.sleep(2.0)                       # let the guards arm + hang start
     proc.send_signal(signal.SIGTERM)
     stdout, stderr = proc.communicate(timeout=30)
-    assert proc.returncode == 0, stderr[-500:]
+    assert proc.returncode == 1, stderr[-500:]
     line = _json_line(stdout)
     assert "terminated by signal" in line.get("error", "")
 
@@ -74,7 +75,7 @@ def test_tail_latency_keys_survive_forced_timeout():
     time.sleep(2.0)
     proc.send_signal(signal.SIGTERM)
     stdout, stderr = proc.communicate(timeout=30)
-    assert proc.returncode == 0, stderr[-500:]
+    assert proc.returncode == 1, stderr[-500:]
     line = _json_line(stdout)
     for key in ("conc_p99_ms", "shed_429s", "hedged_wins",
                 # quantized ANN tier (ISSUE 12): same seeded-null contract
@@ -121,3 +122,17 @@ def test_guards_installed_before_first_leg():
     assert "SIGALRM" in src
     # per-leg budget enforcement by elapsed-time subtraction
     assert "_arm_leg_alarm" in src.split("def _run_all_legs", 1)[1]
+
+
+def test_no_virtual_device_numbers_and_no_assumed_ratio():
+    """ISSUE 21 tripwire: the pod leg never re-executes itself on virtual
+    CPU devices (with fewer than four real devices it reports
+    `pod_skipped`), a CPU run's vs_baseline is null rather than 1.0, and
+    a leg that raised makes the run exit non-zero."""
+    src = open(BENCH).read()
+    assert "xla_force_host_platform_device_count" not in src
+    assert "BENCH_POD_CHILD" not in src
+    assert "pod_skipped" in src
+    assert "1.0 for k in ratio_keys" not in src
+    tail = src.split("def main_engine", 1)[1].split("def ", 1)[0]
+    assert "_FAILED_LEGS" in tail and "sys.exit(1)" in tail

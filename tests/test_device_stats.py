@@ -293,3 +293,24 @@ def test_sampler_carries_hbm_gauges(http):
     assert "hbm_peak_bytes" in snap
     # CPU backend: zeros, never an error
     assert snap["hbm_bytes_in_use"] >= 0
+
+
+def test_in_trace_calls_pass_through_top_level_calls_count():
+    """An instrumented program called under an outer jit is part of that
+    trace, not a dispatch: it must not be counted (or synced). The same
+    program called from eager code is."""
+    import jax
+    import jax.numpy as jnp
+
+    from elasticsearch_tpu.common.device_stats import instrument
+
+    inner = instrument("test:inner", jax.jit(lambda x: x * 2.0),
+                       key="in_trace")
+    x = jnp.arange(4.0)
+    outer = jax.jit(lambda v: inner(v) + 1.0)
+    assert outer(x).tolist() == [1.0, 3.0, 5.0, 7.0]
+    assert jax.vmap(inner)(x[:, None]).shape == (4, 1)
+    assert inner.record.invocations == 0
+    assert inner(x).tolist() == [0.0, 2.0, 4.0, 6.0]
+    assert inner.record.invocations == 1
+    assert inner.record.compiles >= 1

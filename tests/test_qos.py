@@ -585,9 +585,6 @@ class _StubNode:
     def _search_batched(self, metas):
         return [{"served": body} for _, body in metas]
 
-    def _packed_error(self):
-        pass
-
 
 class TestBatcherAccounting:
     def test_follower_wait_timeout_counted_and_falls_back(self):
@@ -637,8 +634,13 @@ class TestBatcherAccounting:
         key = ("k",)
         assert b.join_batched(key, {"q": 0}) is LEAD
         got = []
-        th = threading.Thread(
-            target=lambda: got.append(b.join_batched(key, {"q": 1})))
+
+        def follower():
+            try:
+                got.append(b.join_batched(key, {"q": 1}))
+            except RuntimeError as e:
+                got.append(e)
+        th = threading.Thread(target=follower)
         th.start()
         deadline = time.time() + 5
         while time.time() < deadline:
@@ -648,7 +650,9 @@ class TestBatcherAccounting:
             time.sleep(0.01)
         b.drain_batched(key, "i")
         th.join(5)
-        assert got == [None], "a failing batch degrades to general"
+        assert not th.is_alive()
+        # a failing batch is its members' error, never a slower lane
+        assert len(got) == 1 and isinstance(got[0], RuntimeError)
         st = b.stats()
         assert st["run_errors_total"] == 1
         assert "device fell over" in st["last_error"]

@@ -12,9 +12,9 @@ computed LAZILY at scrape time by re-lowering against the captured
 argument avals — `Lowered.cost_analysis()` runs no backend compile and
 fires no jax.monitoring compile events (verified: the no-retrace
 tripwires stay exact across scrapes) — and is None-safe on backends
-that report nothing. The hot path pays two `perf_counter` reads and a
-couple of dict updates per dispatch: no host syncs, no retraces
-(tests/test_no_retrace.py pins this).
+that report nothing. The hot path pays one `program` span and a couple
+of dict updates per dispatch: no host syncs beyond the barrier, no
+retraces (tests/test_no_retrace.py pins this).
 
 **HBM accounting** — `device.memory_stats()` polled into the stats
 sampler ring with a process-lifetime high-water mark per device. CPU
@@ -37,9 +37,10 @@ from __future__ import annotations
 import contextlib
 import contextvars
 import threading
-import time
 
 import jax
+
+from . import tracing
 
 _LOCK = threading.Lock()
 
@@ -54,13 +55,14 @@ _MAX_PROGRAMS = 512
 
 
 class ProgramRecord:
-    """One compiled program's lifetime accounting. `device_ms` is wall
-    time around dispatch with `block_until_ready` on the program's OWN
-    outputs — so on async backends a call is charged for its own device
-    work, not for whatever an unrelated concurrent program (another
-    node's pool, since ISSUE 19) left in the queue. Program cache keys
-    carry the owning node's device set (`_mesh_devkey`), so records from
-    different pools never alias."""
+    """One compiled program's lifetime accounting. `device_ms` (exported
+    as `device_time_in_millis`) is HOST wall time from dispatch until
+    `block_until_ready` returns on the program's OWN outputs: dispatch
+    latency, the program's wait behind whatever the device still runs,
+    its transfers and the wake-up of the blocked thread included. It is
+    not device time; that comes from a profiler trace only. Program cache
+    keys carry the owning node's device set (`_mesh_devkey`), so records
+    from different pools never alias."""
 
     __slots__ = ("name", "key", "invocations", "device_ms", "compile_ms",
                  "compiles", "last_invoked", "_fn", "_avals", "_cost",
@@ -131,9 +133,10 @@ def _aval_of(x):
 
 
 class InstrumentedProgram:
-    """Transparent wrapper around a jitted callable: per-call wall-ms +
-    invocation counting, first-call aval capture, compile attribution by
-    diffing the process-wide compile-event counters around the dispatch.
+    """Transparent wrapper around a jitted callable: each dispatch is one
+    `program` span (common/tracing.flight) whose duration is the record's
+    wall-ms, plus invocation counting, first-call aval capture and compile
+    attribution by diffing the process-wide compile-event counters.
     Calls made INSIDE an active trace (jit-of-jit) pass straight through
     unaccounted — they are not device dispatches."""
 
@@ -158,18 +161,20 @@ class InstrumentedProgram:
             return self.jit(*args, **kwargs)
         from .metrics import current_profiler, device_events_snapshot
         c0, cms0 = device_events_snapshot()
-        t0 = time.perf_counter()
-        # charge THIS program for its own device work: without the
-        # barrier an async backend bills the next caller's wall clock
-        # for whatever this dispatch left enqueued
-        out = jax.block_until_ready(self.jit(*args, **kwargs))
-        dt = (time.perf_counter() - t0) * 1000.0
+        # charge THIS program for its own work: without the barrier an
+        # async backend bills the next caller's wall clock for whatever
+        # this dispatch left enqueued. The `program` span's two clock reads
+        # are the record's, the request profiler's and the gap ledger's.
+        flight = tracing.flight(self.record.name)
+        with flight:
+            out = jax.block_until_ready(self.jit(*args, **kwargs))
+        dt = flight.duration_ms
         c1, cms1 = device_events_snapshot()
         rec = self.record
         with _LOCK:
             rec.invocations += 1
             rec.device_ms += dt
-            rec.last_invoked = t0
+            rec.last_invoked = flight.start_ns
             if c1 > c0:
                 rec.compiles += c1 - c0
                 rec.compile_ms += cms1 - cms0
@@ -368,8 +373,8 @@ def _note(component: str, lane: str, reason: str) -> None:
         rec.note(component, lane, reason)
     # zero-duration marker on the active trace span (no-op untraced):
     # forced-retained traces carry the full ladder walk
-    from .tracing import add_event
-    add_event("lane", component=component, lane=lane, reason=reason)
+    tracing.add_event("lane", component=component, lane=lane,
+                      reason=reason)
 
 
 def lane_chosen(component: str, lane: str) -> None:

@@ -351,17 +351,18 @@ def _nbytes(x) -> int:
 
 
 def device_fetch(x):
-    """jax.device_get with per-request accounting: when a profiler is
-    active, the fetch counts as one device round-trip and its payload as
-    device→host bytes. The hot paths call this INSTEAD of jax.device_get,
-    so `"profile": true` sees every transfer without touching the kernels.
-    An active trace additionally gets a timed `device_fetch` span and its
-    bytes in the trace's device section (common/tracing.py)."""
+    """jax.device_get with accounting: the fetch is one `device_fetch` span
+    (common/tracing.py) carrying its bytes, counts process-wide as one
+    device round-trip and its payload as device→host bytes, and in the
+    active RequestProfiler and request trace when there is one. The hot
+    paths call this INSTEAD of jax.device_get, so `"profile": true` sees
+    every transfer without touching the kernels."""
     import jax
     from . import tracing
-    t0 = tracing.note_fetch_start()
-    out = jax.device_get(x)
-    nb = _nbytes(out)
+    fetch = tracing.span("device_fetch")
+    with fetch:
+        out = jax.device_get(x)
+        nb = fetch.attrs["bytes"] = _nbytes(out)
     with _DEVICE_LOCK:
         _DEVICE_EVENTS["d2h_bytes"] += nb
         _DEVICE_EVENTS["fetches"] += 1
@@ -369,8 +370,7 @@ def device_fetch(x):
     if prof is not None:
         prof.note_dispatch()
         prof.note_d2h(nb)
-    if t0 is not None:
-        tracing.note_fetch_end(t0, nb)
+    tracing.note_fetch(nb)
     return out
 
 

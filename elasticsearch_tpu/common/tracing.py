@@ -31,6 +31,24 @@ bounded ring, exportable to standard viewers.
 
 Spans carry monotonic-ns timestamps (duration-exact); a wall-clock anchor
 captured at trace start converts to unix nanos for OTLP export.
+
+`span()` and `add_span()` are the one timing primitive of the serving
+path. The same two clock reads of a span feed three sinks:
+
+  1. `AGGREGATE`, always on: count / seconds / self seconds / max by span
+     name, `es_span_*{span=}` on `/_metrics`. Self time is the duration
+     minus what child `span()` blocks on the SAME thread cover.
+  2. a `jax.profiler.TraceAnnotation("es:<name>")` held open for the
+     block, so a running profiler session (xprof, the benchmark's traced
+     slice) shows the span on the host plane beside `XLA Ops`, on the
+     profiler's clock. `add_span` (past timestamps) cannot be one.
+  3. the request's span tree above, when a request trace is active.
+
+`flight(site)` is the `program` span around one blocking device dispatch
+(common/device_stats.InstrumentedProgram) and also feeds `GAPS`, the
+device-gap ledger: whenever no program is in flight the device is idle as
+the host sees it, and the gap is charged to the spans of the thread that
+ends it (`es_device_gap_seconds_total{during=}`).
 """
 
 from __future__ import annotations
@@ -42,6 +60,8 @@ import time
 from collections import deque
 from contextvars import ContextVar
 
+from jax.profiler import TraceAnnotation
+
 # (trace, current span) of the running request; copied into shard jobs by
 # the fan-out's contextvars.copy_context() and into transport handlers by
 # Tracer.remote()
@@ -49,8 +69,12 @@ _ACTIVE: ContextVar["tuple[Trace, Span] | None"] = \
     ContextVar("es_active_trace", default=None)
 
 
+# every timestamp of this module is one read of this clock (a test seam)
+_clock = time.monotonic_ns
+
+
 def now_ns() -> int:
-    return time.monotonic_ns()
+    return _clock()
 
 
 def current_trace() -> "Trace | None":
@@ -100,7 +124,7 @@ class Trace:
         from .metrics import device_events_snapshot
         self._jit0 = device_events_snapshot()
         self._wall_anchor_ns = time.time_ns()
-        self._mono_anchor_ns = time.monotonic_ns()
+        self._mono_anchor_ns = _clock()
         self._seq = 0
         self._lock = threading.Lock()
 
@@ -174,33 +198,223 @@ class Trace:
 
 
 # ---------------------------------------------------------------------------
-# in-request instrumentation primitives (module-level: call sites never
-# need a Tracer reference, and every one is a no-op without an active trace)
+# sink 1: the always-on aggregate by span name
+# ---------------------------------------------------------------------------
+
+class SpanAggregate:
+    """count / total / self / max nanoseconds by span name. Names are the
+    static strings of the call sites, so the table is bounded by the code."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._rows: dict[str, list[int]] = {}
+
+    def add(self, name: str, dur_ns: int, self_ns: int) -> None:
+        with self._lock:
+            row = self._rows.get(name)
+            if row is None:
+                row = self._rows[name] = [0, 0, 0, 0]
+            row[0] += 1
+            row[1] += dur_ns
+            row[2] += self_ns
+            if dur_ns > row[3]:
+                row[3] = dur_ns
+
+    def stats(self) -> dict[str, dict]:
+        """The `es_span_*{span=}` payload of the `/_metrics` walk."""
+        with self._lock:
+            rows = {n: tuple(r) for n, r in self._rows.items()}
+        return {n: {"total": r[0], "seconds_total": r[1] / 1e9,
+                    "self_seconds_total": r[2] / 1e9,
+                    "max_seconds": r[3] / 1e9}
+                for n, r in sorted(rows.items())}
+
+
+AGGREGATE = SpanAggregate()
+
+
+# ---------------------------------------------------------------------------
+# the device-gap ledger: what the host did while no program was in flight
+# ---------------------------------------------------------------------------
+
+class _ThreadState:
+    """One thread's open `span()` blocks, and the ones it closed since its
+    request reached it or it last dispatched a program (whichever is later:
+    nothing older can lie in a gap this thread ends)."""
+
+    __slots__ = ("stack", "trail", "request_start_ns")
+
+    def __init__(self):
+        self.stack: list[_SpanCtx] = []
+        # (name, start_ns, end_ns); bounded: a thread that never dispatches
+        # only ever loses attribution it would not have been asked for
+        self.trail: deque = deque(maxlen=64)
+        self.request_start_ns: int | None = None
+
+
+_LOCAL = threading.local()
+
+
+def _thread_state() -> _ThreadState:
+    st = getattr(_LOCAL, "state", None)
+    if st is None:
+        st = _LOCAL.state = _ThreadState()
+    return st
+
+
+def charge_gap(g0: int, g1: int, state: _ThreadState) -> dict[str, int]:
+    """Split the gap [g0, g1) by what `state`'s thread did in it; the
+    charges sum to g1 - g0. Each instant goes to the innermost span of the
+    thread that covers it (spans of one thread nest or are disjoint). What
+    no span covers is `no_request` before the thread's request reached it
+    (nothing was waiting for the device) and `unattributed` after."""
+    ivs = [(max(s, g0), min(e, g1), n) for n, s, e in state.trail]
+    ivs += [(max(c._entered_ns, g0), g1, c.name) for c in state.stack]
+    ivs = sorted((iv for iv in ivs if iv[1] > iv[0]),
+                 key=lambda iv: (iv[0], -iv[1]))
+    own = [e - s for s, e, _ in ivs]
+    covered = 0
+    nest: list[int] = []
+    for i, (s, e, _) in enumerate(ivs):
+        while nest and ivs[nest[-1]][1] <= s:
+            nest.pop()
+        if nest:
+            own[nest[-1]] -= e - s
+        else:
+            covered += e - s
+        nest.append(i)
+    out: dict[str, int] = {}
+    for (_, _, name), ns in zip(ivs, own):
+        if ns > 0:
+            out[name] = out.get(name, 0) + ns
+    bare = (g1 - g0) - covered
+    r0 = state.request_start_ns
+    before = bare if r0 is None else min(bare, max(r0 - g0, 0))
+    if before > 0:
+        out["no_request"] = before
+    if bare > before:
+        out["unattributed"] = bare - before
+    return out
+
+
+class GapLedger:
+    """Process-wide count of programs in flight (dispatch to ready, as the
+    blocked host thread sees it). While it is 0 the device is idle in the
+    host's view; the dispatch that ends a gap charges it to its thread's
+    spans. A host view: a flight includes dispatch latency and the wake-up
+    of the blocked thread, so the gap total reads at or below the device
+    trace's idle time."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._in_flight = 0
+        self._flight_start_ns = 0
+        self._idle_since_ns: int | None = None   # None before any landing
+        self._flight_ns = 0
+        self._gap_ns: dict[str, int] = {}
+
+    def takeoff(self, now: int, state: _ThreadState) -> None:
+        gap0 = None
+        with self._lock:
+            self._in_flight += 1
+            if self._in_flight == 1:
+                self._flight_start_ns = now
+                gap0 = self._idle_since_ns
+        if gap0 is not None and now > gap0:
+            charges = charge_gap(gap0, now, state)
+            with self._lock:
+                for name, ns in charges.items():
+                    self._gap_ns[name] = self._gap_ns.get(name, 0) + ns
+        # a later gap starts at a landing, so after now: the closed spans
+        # of this thread can lie in none
+        state.trail.clear()
+
+    def land(self, now: int) -> None:
+        with self._lock:
+            self._in_flight -= 1
+            if self._in_flight == 0:
+                # threads race from their clock read to this lock: never
+                # let a landing be booked before its flight's start
+                now = max(now, self._flight_start_ns)
+                self._idle_since_ns = now
+                self._flight_ns += now - self._flight_start_ns
+
+    def gap_stats(self) -> dict[str, dict]:
+        """The `es_device_gap_seconds_total{during=}` payload."""
+        with self._lock:
+            return {n: {"seconds_total": ns / 1e9}
+                    for n, ns in sorted(self._gap_ns.items())}
+
+    def flight_stats(self) -> dict:
+        """The `es_device_flight_seconds_total` payload: the union of the
+        in-flight intervals."""
+        with self._lock:
+            return {"seconds_total": self._flight_ns / 1e9}
+
+
+GAPS = GapLedger()
+
+
+def begin_request(submit_ns: int) -> None:
+    """First line on the pool thread that serves a request submitted at
+    `submit_ns`: records `pool.queue_wait`, and restarts this thread's
+    trail at the request (the wait is the one `add_span` a gap may be
+    charged to: the request WAS waiting for the device then)."""
+    now = _clock()
+    st = _thread_state()
+    st.trail.clear()
+    st.request_start_ns = submit_ns
+    st.trail.append(("pool.queue_wait", submit_ns, now))
+    add_span("pool.queue_wait", submit_ns, now)
+
+
+# ---------------------------------------------------------------------------
+# the instrumentation primitives (module-level: call sites never need a
+# Tracer reference)
 # ---------------------------------------------------------------------------
 
 class _SpanCtx:
     """`with span("name", k=v) as sp:` — class-based (not
-    contextlib.contextmanager) to keep the inactive path allocation-light
-    on seams that run on every request."""
+    contextlib.contextmanager) to keep the path allocation-light on seams
+    that run on every request. `sp` is the request tree's Span, or None when
+    no request trace is active; `attrs`, `start_ns` and `end_ns` of the
+    context itself are there either way, `end_ns` once the block ended."""
 
-    __slots__ = ("name", "attrs", "start_ns", "_span", "_tok")
+    __slots__ = ("name", "attrs", "start_ns", "end_ns", "_entered_ns",
+                 "_state", "_span", "_tok", "_ann", "_child_ns")
 
     def __init__(self, name: str, start_ns: int | None, attrs: dict):
         self.name = name
         self.attrs = attrs
         self.start_ns = start_ns
+        self.end_ns = None
         self._span = None
         self._tok = None
+        self._ann = None
+        self._child_ns = 0
+
+    @property
+    def duration_ms(self) -> float:
+        return (self.end_ns - self.start_ns) / 1e6
 
     def __enter__(self) -> Span | None:
+        self._ann = TraceAnnotation("es:" + self.name, **self.attrs)
+        self._ann.__enter__()
+        # a backdated span is on this thread only from now on: self time
+        # and the gap ledger count it from here, so that the spans of one
+        # thread always nest
+        self._entered_ns = _clock()
+        if self.start_ns is None:
+            self.start_ns = self._entered_ns
+        self._state = _thread_state()
+        self._state.stack.append(self)
         active = _ACTIVE.get()
         if active is None:
             return None
         trace, parent = active
         span = trace.new_span(
             self.name, parent.span_id if parent is not None else None,
-            self.start_ns if self.start_ns is not None
-            else time.monotonic_ns(), self.attrs)
+            self.start_ns, self.attrs)
         if span is None:            # per-trace span cap: dropped, counted
             return None
         self._span = span
@@ -208,21 +422,53 @@ class _SpanCtx:
         return span
 
     def __exit__(self, *exc) -> bool:
+        end = self.end_ns = _clock()
+        self._ann.__exit__(None, None, None)
+        dur = end - self.start_ns
+        here = end - self._entered_ns
+        st = self._state
+        st.stack.pop()
+        if st.stack:
+            st.stack[-1]._child_ns += here
+        st.trail.append((self.name, self._entered_ns, end))
+        AGGREGATE.add(self.name, dur, max(dur - self._child_ns, 0))
         if self._span is not None:
-            self._span.end_ns = time.monotonic_ns()
+            self._span.end_ns = end
             _ACTIVE.reset(self._tok)
         return False
 
 
 def span(name: str, start_ns: int | None = None, **attrs) -> _SpanCtx:
-    """Open a child span of the current span for the block. `start_ns`
-    backdates the start (the shard-span-covers-queue-wait case)."""
+    """Time the block as a span: aggregate, profiler annotation and, when a
+    request trace is active, a child of the current span. `start_ns`
+    backdates the start (the shard-span-covers-queue-wait case). A stats
+    registry that reports the same interval (PhaseTimers, MetricsRegistry,
+    RequestProfiler, ProgramRecord) is fed after the block from the
+    context's own `start_ns` / `end_ns`, not from a second pair of reads."""
     return _SpanCtx(name, start_ns, attrs)
 
 
-def add_span(name: str, start_ns: int, end_ns: int, **attrs) -> None:
-    """Record a completed child span with explicit timestamps (phases the
-    caller already timed — queue_wait, parse — need no second timer)."""
+class _FlightCtx(_SpanCtx):
+    __slots__ = ()
+
+    def __enter__(self) -> Span | None:
+        span_ = super().__enter__()
+        GAPS.takeoff(self.start_ns, self._state)
+        return span_
+
+    def __exit__(self, *exc) -> bool:
+        super().__exit__(*exc)
+        GAPS.land(self.end_ns)
+        return False
+
+
+def flight(site: str) -> _SpanCtx:
+    """The `program` span: one blocking device dispatch of the program at
+    `site`, dispatch to ready. Also a flight of the gap ledger."""
+    return _FlightCtx("program", None, {"site": site})
+
+
+def _tree_span(name: str, start_ns: int, end_ns: int, attrs: dict) -> None:
     active = _ACTIVE.get()
     if active is None:
         return
@@ -234,10 +480,22 @@ def add_span(name: str, start_ns: int, end_ns: int, **attrs) -> None:
         sp.end_ns = int(end_ns)
 
 
+def add_span(name: str, start_ns: int, end_ns: int, **attrs) -> None:
+    """Record a completed span with explicit timestamps (a wait that ended
+    on another thread than it began on, a phase the caller's own two reads
+    already bound). It counts its own duration in the aggregate and is
+    subtracted from no parent: it may belong to another request."""
+    dur = max(int(end_ns) - int(start_ns), 0)
+    AGGREGATE.add(name, dur, dur)
+    _tree_span(name, start_ns, end_ns, attrs)
+
+
 def add_event(name: str, **attrs) -> None:
-    """Zero-duration marker span (cache evictions, ...)."""
-    t = time.monotonic_ns()
-    add_span(name, t, t, **attrs)
+    """Zero-duration marker in the request tree (cache evictions, lane
+    decisions): no interval, so nothing for the aggregate."""
+    if _ACTIVE.get() is not None:
+        t = _clock()
+        _tree_span(name, t, t, attrs)
 
 
 def mark_slowlog() -> None:
@@ -248,18 +506,11 @@ def mark_slowlog() -> None:
         trace.slowlogged = True
 
 
-def note_fetch_start() -> int | None:
-    """ns timestamp when a trace is active, else None — the device_fetch
-    seam's cheap gate."""
-    return time.monotonic_ns() if _ACTIVE.get() is not None else None
-
-
-def note_fetch_end(start_ns: int, nbytes: int) -> None:
-    active = _ACTIVE.get()
-    if active is None:
-        return
-    active[0].note_fetch(nbytes)
-    add_span("device_fetch", start_ns, time.monotonic_ns(), bytes=nbytes)
+def note_fetch(nbytes: int) -> None:
+    """One device fetch of `nbytes` for the active trace's device section."""
+    trace = current_trace()
+    if trace is not None:
+        trace.note_fetch(nbytes)
 
 
 def note_h2d(nbytes: int) -> None:
@@ -350,7 +601,7 @@ class Tracer:
                       max_spans=self.max_spans)
         trace.forced = bool(force)
         trace.opaque_id = opaque_id
-        trace.root = trace.new_span(name, None, time.monotonic_ns(),
+        trace.root = trace.new_span(name, None, _clock(),
                                     dict(attrs or {}))
         with self._lock:
             self.active += 1
@@ -359,7 +610,7 @@ class Tracer:
         try:
             yield trace
         finally:
-            trace.root.end_ns = time.monotonic_ns()
+            trace.root.end_ns = _clock()
             _ACTIVE.reset(tok)
             self._finalize(trace)
 
@@ -377,7 +628,7 @@ class Tracer:
         trace.forced = True        # explicitly propagated => keep it
         rp = header.get("span")
         trace.remote_parent = int(rp) if rp is not None else None
-        trace.root = trace.new_span(name, None, time.monotonic_ns(),
+        trace.root = trace.new_span(name, None, _clock(),
                                     dict(attrs or {}))
         with self._lock:
             self.active += 1
@@ -386,7 +637,7 @@ class Tracer:
         try:
             yield trace
         finally:
-            trace.root.end_ns = time.monotonic_ns()
+            trace.root.end_ns = _clock()
             _ACTIVE.reset(tok)
             self._finalize(trace)
 

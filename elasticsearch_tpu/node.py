@@ -18,7 +18,7 @@ import os
 import re
 import threading
 import time
-from typing import Any
+from typing import Any, NamedTuple
 
 from .common import tracing
 from .common.settings import Settings
@@ -809,11 +809,111 @@ class NodeService:
                      size: int | None = None, from_: int | None = None,
                      scroll: str | None = None, scan: bool = False,
                      request_cache: bool | None = None) -> dict:
-        t0 = time.perf_counter()
-        tns0 = tracing.now_ns()
         from .common.metrics import device_events_snapshot
         compiles0 = device_events_snapshot()[0]
-        body = body or {}
+        # the request's clock starts with its plan: `took`, the `total`
+        # phase and the general lane's `parse` all count from this read
+        planning = tracing.span("search.plan")
+        with planning:
+            plan = self._search_plan(index, body or {}, size, from_,
+                                     request_cache, scroll is not None)
+        tns0 = planning.start_ns
+        body, size, from_ = plan.body, plan.size, plan.from_
+        if scroll is not None:
+            return self._scroll_start(index, body, size, scroll, scan=scan)
+        if plan.cached is not None:
+            return plan.cached
+        names, sort, alias_flt = plan.names, plan.sort, plan.alias_flt
+        cache_key = plan.cache_key
+
+        # the packed fast path: one device program over every shard/segment
+        # of the index (serving/packed_view) — the production serving lane.
+        # Concurrent solo requests COALESCE through the batcher: under load
+        # the device serves whole queues of independent requests as one
+        # program (serving/batcher.py), which is where TPU QPS comes from.
+        from .common.device_stats import lane_chosen, lane_decline
+        if len(names) == 1:
+            if plan.spec is None:
+                lane_decline("serve", "packed", "plan_shape")
+            else:
+                spec = plan.spec
+                key = (names[0], size, from_, spec[1], spec[2], spec[3])
+                stay = tracing.span("packed_batch", index=names[0])
+                with stay:
+                    # queue wait + the shared device program of the
+                    # coalesced batch (serving/batcher.py): the span
+                    # covers this request's whole stay in the lane. A
+                    # program that raises is this request's error — a
+                    # device failure is never served by a slower lane.
+                    out = self._batcher.submit(key, names[0], body,
+                                               spec, size, from_, tns0)
+                if out is None:
+                    lane_decline("serve", "packed", "batcher_declined")
+                else:
+                    lane_chosen("serve", "packed")
+                    # batcher lane: only TOTAL is honest here — the
+                    # request's wall time includes queue wait and
+                    # shared-batch work, not this request's device time.
+                    # From the plan span's start to the stay's end: the
+                    # spans' own clock reads, no second pair
+                    self._served_total(names[0], body,
+                                       (stay.end_ns - tns0) / 1e6, compiles0)
+                    return out
+
+        # coalesced general lane (serving/batcher.py, ISSUE 9): bodies the
+        # packed kernel can't serve but the batched executor can (plan-
+        # shaped queries, aggs, knn, rescore) coalesce behind a leader.
+        # The LEADER runs the ordinary solo path below — idle-path latency
+        # and solo responses are exactly the pre-QoS engine's — while
+        # requests arriving during its run queue as followers and are
+        # served as ONE Q>1 batched program riding the stacked/blockwise/
+        # mesh replica axis, bitwise-identical to solo execution
+        # (tests/test_qos.py parity matrix). Cacheable bodies skip the
+        # lane so the request cache keeps filling.
+        if (len(names) == 1 and cache_key is None
+                and not body.get("profile") and self.qos.enabled()):
+            from .common.metrics import current_profiler as _cur_prof
+            bkey = self._msearch_batch_key(names[0], body) \
+                if _cur_prof() is None else None
+            if bkey is not None:
+                from .serving.batcher import LEAD
+                got = self._batcher.join_batched(bkey, body)
+                if got is LEAD:
+                    try:
+                        return self._search_general(
+                            index, names, body, size, from_, sort,
+                            alias_flt, cache_key, tns0, compiles0)
+                    finally:
+                        self._batcher.drain_batched(bkey, names[0])
+                if got is not None:
+                    # follower served from the shared batch: only TOTAL is
+                    # honest (wall time includes queue wait + shared work)
+                    lane_chosen("serve", "batched")
+                    self._served_total(names[0], body,
+                                       (tracing.now_ns() - tns0) / 1e6,
+                                       compiles0)
+                    return got
+                # timeout/strand/unservable batch: serve solo below
+        return self._search_general(index, names, body, size, from_, sort,
+                                    alias_flt, cache_key, tns0, compiles0)
+
+    def _served_total(self, name: str, body: dict, took_ms: float,
+                      compiles0: int) -> None:
+        """A fast lane served the request in `took_ms`: the `total` phase
+        and the index's slowlog."""
+        self._record_phase("total", took_ms, compiles0)
+        tid, oid = self._trace_ids()
+        if self.slowlog.maybe_log(self.indices[name].settings, name,
+                                  took_ms, body, trace_id=tid,
+                                  opaque_id=oid) is not None:
+            tracing.mark_slowlog()
+
+    def _search_plan(self, index: str, body: dict, size: int | None,
+                     from_: int | None, request_cache: bool | None,
+                     scrolling: bool) -> "_SearchPlan":
+        """Everything `_search_exec` decides before a lane runs: the
+        rendered body and page, the indices, the request cache's answer or
+        key, alias filters, the sort and the packed lane's spec."""
         if "template" in body and "query" not in body:
             # body-level search template (ref RestSearchTemplateAction when
             # the template arrives inside a plain _search body)
@@ -827,8 +927,8 @@ class NodeService:
                     **rendered}
         size = int(body.get("size", 10) if size is None else size)
         from_ = int(body.get("from", 0) if from_ is None else from_)
-        if scroll is not None:
-            return self._scroll_start(index, body, size, scroll, scan=scan)
+        if scrolling:       # the scroll driver plans its own searches
+            return _SearchPlan(body, size, from_)
         names = self._resolve(index)
         if not names:
             raise IndexMissingException(index)
@@ -873,7 +973,7 @@ class NodeService:
                 if hit is not None:
                     for n in names:
                         self.indices[n].request_cache_hits += 1
-                    return hit
+                    return _SearchPlan(body, size, from_, cached=hit)
                 for n in names:
                     self.indices[n].request_cache_misses += 1
 
@@ -886,92 +986,18 @@ class NodeService:
         from .search.sort import parse_sort
         sort = parse_sort(body.get("sort"),
                           [self.indices[n].mappers for n in names])
-
-        # the packed fast path: one device program over every shard/segment
-        # of the index (serving/packed_view) — the production serving lane.
-        # Concurrent solo requests COALESCE through the batcher: under load
-        # the device serves whole queues of independent requests as one
-        # program (serving/batcher.py), which is where TPU QPS comes from.
-        from .common.device_stats import lane_chosen, lane_decline
+        spec = None
         if len(names) == 1:
             from .search.query_parser import QueryParser
             from .serving.executor import packed_spec_of
             spec = packed_spec_of(
                 QueryParser(self.indices[names[0]].mappers), body)
-            if spec is None:
-                lane_decline("serve", "packed", "plan_shape")
-            else:
-                key = (names[0], size, from_, spec[1], spec[2], spec[3])
-                with tracing.span("packed_batch", index=names[0]):
-                    # queue wait + the shared device program of the
-                    # coalesced batch (serving/batcher.py): the span
-                    # covers this request's whole stay in the lane. A
-                    # program that raises is this request's error — a
-                    # device failure is never served by a slower lane.
-                    out = self._batcher.submit(key, names[0], body,
-                                               spec, size, from_, t0)
-                if out is None:
-                    lane_decline("serve", "packed", "batcher_declined")
-                else:
-                    lane_chosen("serve", "packed")
-                    # batcher lane: only TOTAL is honest here — the
-                    # request's wall time includes queue wait and
-                    # shared-batch work, not this request's device time
-                    took = (time.perf_counter() - t0) * 1000
-                    self._record_phase("total", took, compiles0)
-                    tid, oid = self._trace_ids()
-                    if self.slowlog.maybe_log(
-                            self.indices[names[0]].settings, names[0],
-                            took, body, trace_id=tid,
-                            opaque_id=oid) is not None:
-                        tracing.mark_slowlog()
-                    return out
-
-        # coalesced general lane (serving/batcher.py, ISSUE 9): bodies the
-        # packed kernel can't serve but the batched executor can (plan-
-        # shaped queries, aggs, knn, rescore) coalesce behind a leader.
-        # The LEADER runs the ordinary solo path below — idle-path latency
-        # and solo responses are exactly the pre-QoS engine's — while
-        # requests arriving during its run queue as followers and are
-        # served as ONE Q>1 batched program riding the stacked/blockwise/
-        # mesh replica axis, bitwise-identical to solo execution
-        # (tests/test_qos.py parity matrix). Cacheable bodies skip the
-        # lane so the request cache keeps filling.
-        if (len(names) == 1 and cache_key is None
-                and not body.get("profile") and self.qos.enabled()):
-            from .common.metrics import current_profiler as _cur_prof
-            bkey = self._msearch_batch_key(names[0], body) \
-                if _cur_prof() is None else None
-            if bkey is not None:
-                from .serving.batcher import LEAD
-                got = self._batcher.join_batched(bkey, body)
-                if got is LEAD:
-                    try:
-                        return self._search_general(
-                            index, names, body, size, from_, sort,
-                            alias_flt, cache_key, t0, tns0, compiles0)
-                    finally:
-                        self._batcher.drain_batched(bkey, names[0])
-                if got is not None:
-                    # follower served from the shared batch: only TOTAL is
-                    # honest (wall time includes queue wait + shared work)
-                    lane_chosen("serve", "batched")
-                    took = (time.perf_counter() - t0) * 1000
-                    self._record_phase("total", took, compiles0)
-                    tid, oid = self._trace_ids()
-                    if self.slowlog.maybe_log(
-                            self.indices[names[0]].settings, names[0],
-                            took, body, trace_id=tid,
-                            opaque_id=oid) is not None:
-                        tracing.mark_slowlog()
-                    return got
-                # timeout/strand/unservable batch: serve solo below
-        return self._search_general(index, names, body, size, from_, sort,
-                                    alias_flt, cache_key, t0, tns0,
-                                    compiles0)
+        return _SearchPlan(body, size, from_, names=names, sort=sort,
+                           alias_flt=alias_flt, cache_key=cache_key,
+                           spec=spec)
 
     def _search_general(self, index, names, body, size, from_, sort,
-                        alias_flt, cache_key, t0, tns0, compiles0):
+                        alias_flt, cache_key, tns0, compiles0):
         """The general QUERY_THEN_FETCH driver (mesh -> concurrent fan-out
         -> per-segment ladder) — everything below the fast serving lanes.
         Split from _search_exec so a coalescing LEADER can execute it for
@@ -1092,13 +1118,13 @@ class NodeService:
             global_stats = CollectionStats.from_segments(
                 all_segs, terms_by_field)
 
-        t_parse_done = time.perf_counter()
-        self._record_phase("parse", (t_parse_done - t0) * 1000)
-        tracing.add_span("parse", tns0, tracing.now_ns())
+        tns_parsed = tracing.now_ns()
+        tracing.add_span("parse", tns0, tns_parsed)
+        self._record_phase("parse", (tns_parsed - tns0) / 1e6)
         from .common.metrics import current_profiler
         prof = current_profiler()
         if prof is not None:
-            prof.record_phase("parse", (t_parse_done - t0) * 1000)
+            prof.record_phase("parse", (tns_parsed - tns0) / 1e6)
         def _run_shard(i: int, s: ShardSearcher,
                        submit_ns: int | None = None):
             # shard-level action registered under the coordinator task
@@ -1245,12 +1271,10 @@ class NodeService:
                         and first_error is not None:
                     raise first_error
 
-        t_device_done = time.perf_counter()
         tns_fetch0 = tracing.now_ns()
-        self._record_phase("device",
-                           (t_device_done - t_parse_done) * 1000)
+        self._record_phase("device", (tns_fetch0 - tns_parsed) / 1e6)
         if prof is not None:
-            prof.record_phase("query", (t_device_done - t_parse_done) * 1000)
+            prof.record_phase("query", (tns_fetch0 - tns_parsed) / 1e6)
         # the mesh lane already reduced ON DEVICE — sort_docs (the host
         # cross-shard merge) runs only for the fan-out path; rank bodies
         # fuse the two retrievers' GLOBAL lists on device instead
@@ -1326,7 +1350,7 @@ class NodeService:
         if shard_failure_details:
             shards_section["failures"] = shard_failure_details
         resp: dict[str, Any] = {
-            "took": int((time.perf_counter() - t0) * 1000),
+            "took": 0,              # set once the response is assembled
             "timed_out": False,
             "_shards": shards_section,
             "hits": {"total": reduced.total_hits,
@@ -1335,8 +1359,8 @@ class NodeService:
                      "hits": hits},
         }
         if agg_specs:
-            t_agg0 = time.perf_counter()
-            with tracing.span("aggregations"):
+            aggs_span = tracing.span("aggregations")
+            with aggs_span:
                 if mesh_aggs_merged is not None:
                     # the mesh program already collected + merged the
                     # partials on device (parallel/mesh_aggs.py)
@@ -1346,27 +1370,27 @@ class NodeService:
                         agg_specs, [r.aggs for r in results if r.aggs])
                 resp["aggregations"] = render_aggs(agg_specs, merged)
             if prof is not None:
-                prof.record_phase("aggregations",
-                                  (time.perf_counter() - t_agg0) * 1000)
+                prof.record_phase("aggregations", aggs_span.duration_ms)
         if body.get("suggest"):
             resp["suggest"] = self.suggest(index, body["suggest"])
-        now = time.perf_counter()
-        tracing.add_span("fetch", tns_fetch0, tracing.now_ns())
-        self._record_phase("fetch", (now - t_device_done) * 1000)
-        self._record_phase("total", (now - t0) * 1000, compiles0)
+        tns_done = tracing.now_ns()
+        tracing.add_span("fetch", tns_fetch0, tns_done)
+        fetch_ms = (tns_done - tns_fetch0) / 1e6
+        took_ms = (tns_done - tns0) / 1e6
+        self._record_phase("fetch", fetch_ms)
+        self._record_phase("total", took_ms, compiles0)
         if prof is not None:
             # response-assembly remainder: everything after the device
             # phase that isn't already booked (reduce/fetch/highlight/aggs)
             post = sum(v for k, v in prof.phases.items()
                        if k not in ("parse", "query"))
-            prof.record_phase("serialize", max(
-                (now - t_device_done) * 1000 - post, 0.0))
-        resp["took"] = int((now - t0) * 1000)
+            prof.record_phase("serialize", max(fetch_ms - post, 0.0))
+        resp["took"] = int(took_ms)
         tid, oid = self._trace_ids()
         slow = None
         for n in names:     # every searched index's thresholds apply
             slow = self.slowlog.maybe_log(self.indices[n].settings, n,
-                                          (now - t0) * 1000, body,
+                                          took_ms, body,
                                           trace_id=tid, opaque_id=oid) \
                 or slow
         if slow is not None:
@@ -1775,12 +1799,14 @@ class NodeService:
                            mappers=self.indices[names[0]].mappers)
 
     def _packed_search(self, name: str, bodies: list[dict], *, size: int,
-                       from_: int, t0: float, raw: bool = False,
+                       from_: int, t0: list[int], raw: bool = False,
                        specs: list | None = None) -> list | None:
         """Serve a batch of same-shaped requests through the packed view:
         ONE device program across all shards/segments, one upload, one
-        download (serving/). Returns per-body responses (dicts, or raw JSON
-        strings when `raw` and `_source: false`), or None to fall back."""
+        download (serving/). `t0[i]` (ns) is when the request of body i
+        began: each response's `took` is its own, whoever led the batch.
+        Returns per-body responses (dicts, or raw JSON strings when `raw`
+        and `_source: false`), or None to fall back."""
         from .serving.executor import (packed_spec_of, response_dict,
                                        response_raw)
         svc = self.indices[name]
@@ -1805,22 +1831,25 @@ class NodeService:
             scores, docs, hits = view.search(field, queries, k=k, k1=k1, b=b)
         except FilterColumnRefused:
             return None    # breaker refused a filter column: general path
-        took = int((time.perf_counter() - t0) * 1000)
-        out = []
-        for qi, body in enumerate(bodies):
-            src_spec = body.get("_source", True)
-            if raw and src_spec is False and view.ids_json_safe:
-                out.append(response_raw(
-                    view, name, scores[qi], docs[qi], hits[qi],
-                    n_shards=svc.n_shards, took=took,
-                    from_=from_, size=size))
-            else:
-                fn = (lambda s: _source_filter(s, src_spec)) \
-                    if src_spec not in (True, False) else None
-                out.append(response_dict(
-                    view, name, scores[qi], docs[qi], hits[qi],
-                    n_shards=svc.n_shards, took=took, from_=from_,
-                    size=size, src_spec=src_spec, src_filter_fn=fn))
+        respond = tracing.span("packed.respond")
+        with respond:
+            # `took` ends where the rendering starts (the span's own read)
+            out = []
+            for qi, body in enumerate(bodies):
+                took = (respond.start_ns - t0[qi]) // 1_000_000
+                src_spec = body.get("_source", True)
+                if raw and src_spec is False and view.ids_json_safe:
+                    out.append(response_raw(
+                        view, name, scores[qi], docs[qi], hits[qi],
+                        n_shards=svc.n_shards, took=took,
+                        from_=from_, size=size))
+                else:
+                    fn = (lambda s: _source_filter(s, src_spec)) \
+                        if src_spec not in (True, False) else None
+                    out.append(response_dict(
+                        view, name, scores[qi], docs[qi], hits[qi],
+                        n_shards=svc.n_shards, took=took, from_=from_,
+                        size=size, src_spec=src_spec, src_filter_fn=fn))
         # count AFTER successful response assembly — a failure above is the
         # request's error and must not be booked as a packed serve
         svc.search_stats["packed"] = \
@@ -2043,45 +2072,47 @@ class NodeService:
         import json
         from .common.device_stats import lane_chosen, lane_decline
         from .serving.executor import packed_spec_of
-        t0 = time.perf_counter()
         responses: list = [None] * len(requests)
         metas: list[tuple[str, dict]] = []
         packed_groups: dict[Any, list[int]] = {}
         packed_specs: dict[int, Any] = {}
         parsers: dict[str, Any] = {}
         leftovers: list[int] = []
-        for i, (header, body) in enumerate(requests):
-            index = (header or {}).get("index") or "_all"
-            body = body or {}
-            metas.append((index, body))
-            key = None
-            try:
-                names = self._resolve(index)
-                if len(names) == 1:
-                    name = names[0]
-                    if name not in parsers:
-                        from .search.query_parser import QueryParser
-                        parsers[name] = QueryParser(
-                            self.indices[name].mappers)
-                    spec = packed_spec_of(parsers[name], body)
-                    if spec is not None:
-                        packed_specs[i] = spec
-                        key = (name, int(body.get("size", 10)),
-                               int(body.get("from", 0)),
-                               repr(body.get("_source", True)))
-            except Exception:  # noqa: BLE001 — solo path reports the error
+        planning = tracing.span("search.plan")
+        with planning:
+            for i, (header, body) in enumerate(requests):
+                index = (header or {}).get("index") or "_all"
+                body = body or {}
+                metas.append((index, body))
                 key = None
-            if key is not None:
-                packed_groups.setdefault(key, []).append(i)
-            else:
-                leftovers.append(i)
+                try:
+                    names = self._resolve(index)
+                    if len(names) == 1:
+                        name = names[0]
+                        if name not in parsers:
+                            from .search.query_parser import QueryParser
+                            parsers[name] = QueryParser(
+                                self.indices[name].mappers)
+                        spec = packed_spec_of(parsers[name], body)
+                        if spec is not None:
+                            packed_specs[i] = spec
+                            key = (name, int(body.get("size", 10)),
+                                   int(body.get("from", 0)),
+                                   repr(body.get("_source", True)))
+                except Exception:  # noqa: BLE001 — solo path reports it
+                    key = None
+                if key is not None:
+                    packed_groups.setdefault(key, []).append(i)
+                else:
+                    leftovers.append(i)
+        t0 = planning.start_ns      # one `took` clock for every item
 
         for key, idxs in packed_groups.items():
             name, size, from_, _src = key
             try:
                 outs = self._packed_search(
                     name, [metas[i][1] for i in idxs], size=size,
-                    from_=from_, t0=t0, raw=raw,
+                    from_=from_, t0=[t0] * len(idxs), raw=raw,
                     specs=[packed_specs[i] for i in idxs])
             except Exception as e:  # noqa: BLE001 — per-item error contract
                 # a program that raised is its members' error, never a
@@ -2118,10 +2149,13 @@ class NodeService:
                 responses[i] = out
 
         if raw:
-            payload = '{"responses":[' + ",".join(
-                r if isinstance(r, str) else json.dumps(r)
-                for r in responses) + ']}'
-            return payload.encode()
+            # the raw lane serializes here, so the HTTP layer's own
+            # `rest.serialize` finds bytes and has nothing left to do
+            with tracing.span("rest.serialize"):
+                payload = '{"responses":[' + ",".join(
+                    r if isinstance(r, str) else json.dumps(r)
+                    for r in responses) + ']}'
+                return payload.encode()
         return {"responses": responses}
 
     def _msearch_one(self, index: str, body: dict) -> dict:
@@ -3115,6 +3149,15 @@ class NodeService:
             # span tracer: started/retained/sampled-out trace counters,
             # ring-eviction + span-cap drop counters, live gauges
             "tracing": (None, self.tracer.stats()),
+            # the always-on span aggregate (common/tracing.py): count,
+            # seconds, self seconds and max by span name, process-wide —
+            # es_span_*{span=}; and the device-gap ledger: host-seen idle
+            # time of the device by what the dispatching thread did in it —
+            # es_device_gap_seconds_total{during=} beside the in-flight
+            # union es_device_flight_seconds_total
+            "span": ("span", tracing.AGGREGATE.stats()),
+            "device_gap": ("during", tracing.GAPS.gap_stats()),
+            "device_flight": (None, tracing.GAPS.flight_stats()),
             "rate": ("op", {n: m.stats() for n, m in self.meters.items()}),
             "process": (None, {
                 "resident_bytes": proc.get("mem", {})
@@ -3287,6 +3330,19 @@ class NodeService:
 
 
 # ---------------------------------------------------------------------------
+
+class _SearchPlan(NamedTuple):
+    """What `_search_plan` decided for one `_search` (node.py)."""
+    body: dict
+    size: int
+    from_: int
+    cached: dict | None = None      # the request cache's answer
+    names: list | None = None       # None: a scroll plans for itself
+    sort: Any = None
+    alias_flt: dict | None = None
+    cache_key: tuple | None = None
+    spec: tuple | None = None       # the packed lane's, or None
+
 
 class _ShardJob:
     """Claim-once shard execution for the concurrent query fan-out: a

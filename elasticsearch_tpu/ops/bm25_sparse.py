@@ -219,6 +219,8 @@ def bm25_serve_packed(packed_q: jax.Array, doc_ids: jax.Array, tf: jax.Array,
 
 def _serve_packed_impl(packed_q, doc_ids, tf, dl, live, pad_doc,
                        k1, b, avgdl, const, *, S, CHUNK, R, k, filters):
+    # each phase is a `jax.named_scope`: metadata only (same program, same
+    # outputs), so a profile's operations group under stable names
     Q = packed_q.shape[0]
     starts = packed_q[:, :S]
     lens = packed_q[:, S:2 * S]
@@ -234,31 +236,39 @@ def _serve_packed_impl(packed_q, doc_ids, tf, dl, live, pad_doc,
         valid = jnp.arange(CHUNK, dtype=jnp.int32) < ln
         return jnp.where(valid, d, PAD), t, l, valid
 
-    d, t, l, valid = jax.vmap(jax.vmap(slice_slot))(starts, lens)
-
-    norm = k1 * (1.0 - b + b * l / avgdl)
-    impact = t / (t + norm)
-    contrib = jnp.where(valid, weights[:, :, None] * impact, 0.0)
+    with jax.named_scope("packed.gather"):
+        d, t, l, valid = jax.vmap(jax.vmap(slice_slot))(starts, lens)
 
     W = S * CHUNK
-    d = d.reshape(Q, W)
-    contrib = contrib.reshape(Q, W).astype(jnp.float32)
-    cnt = valid.astype(jnp.float32).reshape(Q, W)
-    d, contrib, cnt = jax.lax.sort((d, contrib, cnt), dimension=1, num_keys=1)
+    with jax.named_scope("packed.score"):
+        norm = k1 * (1.0 - b + b * l / avgdl)
+        impact = t / (t + norm)
+        contrib = jnp.where(valid, weights[:, :, None] * impact, 0.0)
+        d = d.reshape(Q, W)
+        contrib = contrib.reshape(Q, W).astype(jnp.float32)
+        cnt = valid.astype(jnp.float32).reshape(Q, W)
 
-    total = contrib
-    count = cnt
-    for j in range(1, R):
-        same = d == jnp.roll(d, j, axis=1)
-        same = same.at[:, :j].set(False)
-        total = total + jnp.where(same, jnp.roll(contrib, j, axis=1), 0.0)
-        count = count + jnp.where(same, jnp.roll(cnt, j, axis=1), 0.0)
+    with jax.named_scope("packed.sort"):
+        d, contrib, cnt = jax.lax.sort((d, contrib, cnt), dimension=1,
+                                       num_keys=1)
 
-    is_real = d != PAD
-    ends = jnp.concatenate([d[:, :-1] != d[:, 1:], jnp.ones((Q, 1), bool)],
-                           axis=1) & is_real
-    accepted = live.take(d, mode="clip")
-    keep = ends & accepted & (count >= min_match[:, None].astype(jnp.float32))
+    with jax.named_scope("packed.combine_runs"):
+        total = contrib
+        count = cnt
+        for j in range(1, R):
+            same = d == jnp.roll(d, j, axis=1)
+            same = same.at[:, :j].set(False)
+            total = total + jnp.where(same, jnp.roll(contrib, j, axis=1),
+                                      0.0)
+            count = count + jnp.where(same, jnp.roll(cnt, j, axis=1), 0.0)
+
+    with jax.named_scope("packed.live_mask"):
+        is_real = d != PAD
+        ends = jnp.concatenate(
+            [d[:, :-1] != d[:, 1:], jnp.ones((Q, 1), bool)], axis=1) & is_real
+        accepted = live.take(d, mode="clip")
+        keep = ends & accepted \
+            & (count >= min_match[:, None].astype(jnp.float32))
 
     if filters is not None:
         (fcols, fr_col, fr_lo, fr_hi, fr_neg,
@@ -285,24 +295,28 @@ def _serve_packed_impl(packed_q, doc_ids, tf, dl, live, pad_doc,
                 ok = ok & jnp.where(ft_c[fi] != -1, m, True)
             return ok
 
-        keep = keep & jax.vmap(eval_one)(
-            d, fr_col, fr_lo, fr_hi, fr_neg, ft_col, ft_targets, ft_neg)
+        with jax.named_scope("packed.filters"):
+            keep = keep & jax.vmap(eval_one)(
+                d, fr_col, fr_lo, fr_hi, fr_neg, ft_col, ft_targets, ft_neg)
 
-    masked = jnp.where(keep, total + const, -jnp.inf)
+    with jax.named_scope("packed.topk"):
+        masked = jnp.where(keep, total + const, -jnp.inf)
+        top, pos = jax.lax.top_k(masked, min(k, W))
+        top_docs = jnp.where(top > -jnp.inf,
+                             jnp.take_along_axis(d, pos, axis=1), PAD)
 
-    top, pos = jax.lax.top_k(masked, min(k, W))
-    top_docs = jnp.where(top > -jnp.inf,
-                         jnp.take_along_axis(d, pos, axis=1), PAD)
-    if k > W:   # degenerate tiny-index case: pad out to the contract shape
-        fill = ((Q, k - W))
-        top = jnp.concatenate(
-            [top, jnp.full(fill, -jnp.inf, top.dtype)], axis=1)
-        top_docs = jnp.concatenate(
-            [top_docs, jnp.broadcast_to(PAD, fill).astype(jnp.int32)], axis=1)
-    total_hits = jnp.sum(keep, axis=1, dtype=jnp.int32)
-    return jnp.concatenate(
-        [jax.lax.bitcast_convert_type(top, jnp.int32), top_docs,
-         total_hits[:, None]], axis=1)
+    with jax.named_scope("packed.pack_out"):
+        if k > W:   # degenerate tiny-index case: pad to the contract shape
+            fill = ((Q, k - W))
+            top = jnp.concatenate(
+                [top, jnp.full(fill, -jnp.inf, top.dtype)], axis=1)
+            top_docs = jnp.concatenate(
+                [top_docs, jnp.broadcast_to(PAD, fill).astype(jnp.int32)],
+                axis=1)
+        total_hits = jnp.sum(keep, axis=1, dtype=jnp.int32)
+        return jnp.concatenate(
+            [jax.lax.bitcast_convert_type(top, jnp.int32), top_docs,
+             total_hits[:, None]], axis=1)
 
 
 @functools.partial(jax.jit, static_argnames=("Wt", "k", "n_docs"))

@@ -24,12 +24,14 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Any, Callable
 from urllib.parse import parse_qs, urlparse
 
+from ..common import tracing
 from ..index.engine import VersionConflictException, DocumentMissingException
 from ..node import (IndexAlreadyExistsException, IndexClosedException,
                     IndexMissingException, InvalidIndexNameException,
                     NodeService)
 from ..search.aggs import AggregationParsingException
 from ..search.query_dsl import QueryParsingException
+from ..serving.qos import QosShedException
 
 
 class RestError(Exception):
@@ -41,7 +43,6 @@ class RestError(Exception):
 def _status_of(e: Exception) -> int:
     from ..common.breaker import CircuitBreakingException
     from ..common.threadpool import EsRejectedExecutionException
-    from ..serving.qos import QosShedException
     if isinstance(e, RestError):
         return e.status
     if isinstance(e, (CircuitBreakingException, EsRejectedExecutionException,
@@ -257,6 +258,18 @@ def _register_routes(c: RestController, node: NodeService) -> None:
 
     # -- search (must register before the generic doc routes) -------------
     def search(g, p, b):
+        with tracing.span("rest.parse_body"):
+            body = _search_body(p, b)
+        scroll = p.get("scroll", [None])[0]
+        scan = p.get("search_type", [None])[0] == "scan"
+        rc = p.get("request_cache", [None])[0]
+        return 200, node.search(g.get("index", "_all"), body, scroll=scroll,
+                                scan=scan,
+                                request_cache=None if rc is None
+                                else rc == "true")
+
+    def _search_body(p, b) -> dict:
+        """The `_search` body with the URL's overrides folded in."""
         body = _json_body(b)
         if "q" in p:   # URI search (ref RestSearchAction query_string support)
             body.setdefault("query", {"query_string": {"query": p["q"][0]}})
@@ -290,13 +303,7 @@ def _register_routes(c: RestController, node: NodeService) -> None:
                  else [cur] if isinstance(cur, str) else None)
             body["_source"] = {"include": inc_l,
                                "exclude": exc.split(",") if exc else None}
-        scroll = p.get("scroll", [None])[0]
-        scan = p.get("search_type", [None])[0] == "scan"
-        rc = p.get("request_cache", [None])[0]
-        return 200, node.search(g.get("index", "_all"), body, scroll=scroll,
-                                scan=scan,
-                                request_cache=None if rc is None
-                                else rc == "true")
+        return body
 
     def scroll_next(g, p, b):
         body = _json_body(b) if b and b.strip().startswith(b"{") else {}
@@ -346,16 +353,18 @@ def _register_routes(c: RestController, node: NodeService) -> None:
     def msearch(g, p, b):
         # NDJSON: alternating header / body lines
         # (ref rest/action/search/RestMultiSearchAction)
-        lines = [json.loads(ln) for ln in b.decode("utf-8").split("\n")
-                 if ln.strip()]
-        if len(lines) % 2:
-            raise RestError(400, "msearch body must be header/body pairs")
-        requests = []
-        for i in range(0, len(lines), 2):
-            header = dict(lines[i])
-            if g.get("index") and "index" not in header:
-                header["index"] = g["index"]
-            requests.append((header, lines[i + 1]))
+        with tracing.span("rest.parse_body"):
+            lines = [json.loads(ln) for ln in b.decode("utf-8").split("\n")
+                     if ln.strip()]
+            if len(lines) % 2:
+                raise RestError(400,
+                                "msearch body must be header/body pairs")
+            requests = []
+            for i in range(0, len(lines), 2):
+                header = dict(lines[i])
+                if g.get("index") and "index" not in header:
+                    header["index"] = g["index"]
+                requests.append((header, lines[i + 1]))
         # raw=True: the packed serving lane pre-serializes hit JSON with
         # vectorized string ops; bytes pass straight through to the socket
         return 200, node.msearch(requests, raw=True)
@@ -3063,28 +3072,41 @@ class HttpServer:
                 pass
 
             def _handle(self, method: str):
+                # the root of the request's spans (common/tracing.py): first
+                # byte of the body read to the last byte written. The HTTP
+                # thread's spans feed the aggregate and the profiler only;
+                # the request's span TREE roots in `dispatch`, on the pool
+                with tracing.span("rest.request", method=method):
+                    self._serve(method)
+
+            def _serve(self, method: str):
                 parsed = urlparse(self.path)
                 params = parse_qs(parsed.query)
-                length = int(self.headers.get("Content-Length") or 0)
-                body = self.rfile.read(length) if length else b""
-                # XContent seam (common/xcontent.py; ref XContentFactory):
-                # YAML/CBOR request bodies normalize to JSON at the edge so
-                # every handler stays single-format
-                ctype_in = self.headers.get("Content-Type") or ""
-                if body and ("yaml" in ctype_in or "cbor" in ctype_in
-                             or "smile" in ctype_in):
-                    from ..common import xcontent
-                    try:
-                        body = json.dumps(
-                            xcontent.decode(body, ctype_in)).encode()
-                    except Exception as e:  # noqa: BLE001 — yaml/cbor
-                        # parsers raise their own types; ALL malformed
-                        # bodies must 406, never drop the connection
-                        self._reply(406, json.dumps(
-                            {"error": f"{type(e).__name__}: {e}",
-                             "status": 406}).encode(),
-                            "application/json; charset=UTF-8", method)
-                        return
+                bad_body = None
+                with tracing.span("rest.read_body"):
+                    length = int(self.headers.get("Content-Length") or 0)
+                    body = self.rfile.read(length) if length else b""
+                    # XContent seam (common/xcontent.py; ref
+                    # XContentFactory): YAML/CBOR request bodies normalize
+                    # to JSON at the edge so every handler stays
+                    # single-format
+                    ctype_in = self.headers.get("Content-Type") or ""
+                    if body and ("yaml" in ctype_in or "cbor" in ctype_in
+                                 or "smile" in ctype_in):
+                        from ..common import xcontent
+                        try:
+                            body = json.dumps(
+                                xcontent.decode(body, ctype_in)).encode()
+                        except Exception as e:  # noqa: BLE001 — yaml/cbor
+                            # parsers raise their own types; ALL malformed
+                            # bodies must 406, never drop the connection
+                            bad_body = e
+                if bad_body is not None:
+                    self._reply(406, json.dumps(
+                        {"error": f"{type(bad_body).__name__}: {bad_body}",
+                         "status": 406}).encode(),
+                        "application/json; charset=UTF-8", method)
+                    return
                 req_headers = {k.lower(): v for k, v in self.headers.items()}
                 extra_headers: dict = {}
                 try:
@@ -3098,17 +3120,30 @@ class HttpServer:
                     tp = getattr(node, "thread_pool", None)
                     qos = getattr(node, "qos", None)
                     tclass = _TRAFFIC_CLASS_OF.get(pool)
-                    admission = qos.admit(tclass) \
-                        if qos is not None and tclass is not None \
-                        else contextlib.nullcontext()
+                    admission = contextlib.nullcontext()
+                    if qos is not None and tclass is not None:
+                        admit = tracing.span("qos.admit")
+                        with admit:
+                            try:
+                                admission = qos.admit(tclass)
+                            except QosShedException:
+                                admit.attrs["shed"] = True
+                                raise
                     with admission:
                         if pool is None or tp is None:
                             status, payload = controller.dispatch(
                                 method, parsed.path, params, body,
                                 req_headers)
                         else:
+                            submitted = tracing.now_ns()
+
+                            def on_pool(*args):
+                                # first line on the pool thread: the end
+                                # of `pool.queue_wait`
+                                tracing.begin_request(submitted)
+                                return controller.dispatch(*args)
                             status, payload = tp.submit(
-                                pool, controller.dispatch,
+                                pool, on_pool,
                                 method, parsed.path, params, body,
                                 req_headers).result()
                 except Exception as e:  # noqa: BLE001 — REST error contract
@@ -3126,22 +3161,24 @@ class HttpServer:
                         extra_headers["Retry-After"] = \
                             str(int(_math.ceil(retry or 1.0)))
                 fmt = params.get("format", [None])[0]
-                if isinstance(payload, bytes):
-                    data = payload           # pre-serialized JSON fast lane
-                    ctype = "application/json; charset=UTF-8"
-                elif isinstance(payload, str):
-                    data = payload.encode("utf-8")
-                    ctype = "text/plain; charset=UTF-8"
-                elif fmt in ("yaml", "cbor"):
-                    from ..common import xcontent
-                    try:
-                        data, ctype = xcontent.encode(payload, fmt)
-                    except Exception:  # noqa: BLE001 — unencodable value:
-                        data = json.dumps(payload).encode()  # JSON fallback
+                with tracing.span("rest.serialize"):
+                    if isinstance(payload, bytes):
+                        data = payload       # pre-serialized JSON fast lane
                         ctype = "application/json; charset=UTF-8"
-                else:
-                    data = json.dumps(payload).encode("utf-8")
-                    ctype = "application/json; charset=UTF-8"
+                    elif isinstance(payload, str):
+                        data = payload.encode("utf-8")
+                        ctype = "text/plain; charset=UTF-8"
+                    elif fmt in ("yaml", "cbor"):
+                        from ..common import xcontent
+                        try:
+                            data, ctype = xcontent.encode(payload, fmt)
+                        except Exception:  # noqa: BLE001 — unencodable
+                            # value: JSON fallback
+                            data = json.dumps(payload).encode()
+                            ctype = "application/json; charset=UTF-8"
+                    else:
+                        data = json.dumps(payload).encode("utf-8")
+                        ctype = "application/json; charset=UTF-8"
                 self._reply(status, data, ctype, method,
                             opaque_id=req_headers.get("x-opaque-id"),
                             extra=extra_headers)
@@ -3150,16 +3187,17 @@ class HttpServer:
                        extra=None):
                 if method == "HEAD":
                     data = b""
-                self.send_response(status)
-                self.send_header("Content-Type", ctype)
-                self.send_header("Content-Length", str(len(data)))
-                if opaque_id:
-                    # the reference echoes X-Opaque-Id on every response
-                    self.send_header("X-Opaque-Id", opaque_id)
-                for k, v in (extra or {}).items():
-                    self.send_header(k, v)
-                self.end_headers()
-                self.wfile.write(data)
+                with tracing.span("rest.write", status=status):
+                    self.send_response(status)
+                    self.send_header("Content-Type", ctype)
+                    self.send_header("Content-Length", str(len(data)))
+                    if opaque_id:
+                        # the reference echoes X-Opaque-Id on every response
+                        self.send_header("X-Opaque-Id", opaque_id)
+                    for k, v in (extra or {}).items():
+                        self.send_header(k, v)
+                    self.end_headers()
+                    self.wfile.write(data)
 
             def do_GET(self):
                 self._handle("GET")

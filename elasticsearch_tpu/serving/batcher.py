@@ -43,7 +43,8 @@ from __future__ import annotations
 
 import logging
 import threading
-import time
+
+from ..common import tracing
 
 logger = logging.getLogger("elasticsearch_tpu.serving.batcher")
 
@@ -53,16 +54,18 @@ LEAD = object()
 
 
 class _Entry:
-    __slots__ = ("body", "spec", "event", "out", "err", "t_submit",
-                 "abandoned")
+    __slots__ = ("body", "spec", "event", "out", "err", "t0", "t_submit",
+                 "t_taken", "abandoned")
 
-    def __init__(self, body, spec):
+    def __init__(self, body, spec, t0: int | None = None):
         self.body = body
         self.spec = spec
         self.event = threading.Event()
         self.out = None          # response dict, or None -> general path
         self.err = None
-        self.t_submit = time.perf_counter()
+        self.t0 = t0             # ns: the request's own start, for `took`
+        self.t_submit = tracing.now_ns()
+        self.t_taken = None      # ns: a batch took the entry off the queue
         self.abandoned = False   # follower timed out; don't spend a row
 
 
@@ -120,7 +123,10 @@ class SearchBatcher:
     def _wait(self, e: _Entry):
         """Follower wait with the deadline-aware timeout; a timeout falls
         to the general path, counted and logged instead of silent."""
-        if not e.event.wait(timeout=self._wait_timeout()):
+        with tracing.span("batcher.follow"):
+            served = e.event.wait(timeout=self._wait_timeout())
+        self._note_wait(e)
+        if not served:
             e.abandoned = True
             with self._lock:
                 self.wait_timeouts += 1
@@ -132,6 +138,26 @@ class SearchBatcher:
         if e.err is not None:
             raise e.err
         return e.out
+
+    def _take(self, batch: list[_Entry]) -> None:
+        """The batch leaves the queue for the device: the end of each
+        member's queue wait (leader ≈ 0; followers accrue while the
+        previous batch runs) — the admission-latency half of batcher cost,
+        invisible to the device timers because it happens on the host."""
+        now = tracing.now_ns()
+        for x in batch:
+            x.t_taken = now
+
+    def _note_wait(self, e: _Entry) -> None:
+        """Each member books its own `batcher.queue_wait`, on its own
+        thread, so the span lands in its own request's tree."""
+        if e.t_taken is None:
+            return               # never taken: timed out or stranded
+        tracing.add_span("batcher.queue_wait", e.t_submit, e.t_taken)
+        metrics = getattr(self.node, "metrics", None)
+        if metrics is not None:
+            metrics.record("batcher.queue_wait",
+                           (e.t_taken - e.t_submit) / 1e6)
 
     def _release(self, key: tuple) -> None:
         """Leader exit: release leadership and unblock any leftover
@@ -152,12 +178,12 @@ class SearchBatcher:
     # -- the packed lane ---------------------------------------------------
 
     def submit(self, key: tuple, name: str, body: dict, spec,
-               size: int, from_: int, t0: float):
-        """Execute (or join) a packed batch for this request. Returns the
-        response dict, or None when the request must take the general path
-        (unservable batch / view refusal)."""
+               size: int, from_: int, t0: int):
+        """Execute (or join) a packed batch for this request, which began
+        at `t0` (ns). Returns the response dict, or None when the request
+        must take the general path (unservable batch / view refusal)."""
         key = ("packed", *key)
-        e = _Entry(body, spec)
+        e = _Entry(body, spec, t0)
         with self._lock:
             self._queues.setdefault(key, []).append(e)
             leader = key not in self._busy
@@ -177,28 +203,20 @@ class SearchBatcher:
                     if len(batch) > window:
                         self._queues[key] = batch[window:]
                         batch = batch[:window]
-                self._run(key, name, batch, size, from_, t0)
+                self._run(name, batch, size, from_)
         finally:
             self._release(key)
+        self._note_wait(e)
         if e.err is not None:
             raise e.err
         return e.out
 
-    def _run(self, key, name, batch, size, from_, t0):
-        # queue-wait timer: time each entry spent waiting for the device
-        # (leader ≈ 0; followers accrue while the previous batch runs) —
-        # the admission-latency half of batcher cost, invisible to the
-        # device timers because it happens entirely on the host
-        now = time.perf_counter()
-        metrics = getattr(self.node, "metrics", None)
-        if metrics is not None:
-            for x in batch:
-                metrics.record("batcher.queue_wait",
-                               (now - x.t_submit) * 1000)
+    def _run(self, name, batch, size, from_):
+        self._take(batch)
         try:
             outs = self.node._packed_search(
                 name, [x.body for x in batch], size=size, from_=from_,
-                t0=t0, specs=[x.spec for x in batch])
+                t0=[x.t0 for x in batch], specs=[x.spec for x in batch])
         except Exception as ex:  # noqa: BLE001 — every member's error
             self._record_error(ex)
             for x in batch:
@@ -251,12 +269,7 @@ class SearchBatcher:
             self._release(key)
 
     def _run_batched(self, index: str, batch: list[_Entry]) -> None:
-        now = time.perf_counter()
-        metrics = getattr(self.node, "metrics", None)
-        if metrics is not None:
-            for x in batch:
-                metrics.record("batcher.queue_wait",
-                               (now - x.t_submit) * 1000)
+        self._take(batch)
         try:
             outs = self.node._search_batched(
                 [(index, x.body) for x in batch])

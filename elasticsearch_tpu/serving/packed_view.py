@@ -33,6 +33,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ..common import tracing
+from ..common.metrics import device_fetch, note_h2d
 from ..index.segment import Segment, next_pow2
 from ..ops.bm25_sparse import bm25_serve_packed, bm25_serve_packed_filtered
 
@@ -430,47 +432,58 @@ class PackedIndexView:
             return (np.full((Q, k), -np.inf, np.float32),
                     np.full((Q, k), -1, np.int64), np.zeros(Q, np.int64))
 
-        packed_q, S, R = self._build_slots(pf, queries, field, k1, b)
-        k_pad = next_pow2(k, floor=8)
-        Q_pad = packed_q.shape[0]
-        if any(q.filters for q in queries):
-            (fields, fr_col, fr_lo, fr_hi, fr_neg,
-             ft_col, ft_targets, ft_neg) = \
-                self._filter_descriptors(queries, Q_pad)
+        # everything the host does before the dispatch is one span: the slot
+        # table, the filter descriptors and their uploads (the packed view's
+        # own arrays are device-resident already)
+        prep = tracing.span("packed.build_slots")
+        with prep:
+            packed, S, R = self._build_slots(pf, queries, field, k1, b)
+            k_pad = next_pow2(k, floor=8)
+            host = [packed]
+            stack = None
+            if any(q.filters for q in queries):
+                fields, *descriptors = \
+                    self._filter_descriptors(queries, packed.shape[0])
+                host += descriptors
+                stack = self._filter_stack(fields)
+            dev = [jnp.asarray(a) for a in host]
+            scalars = (jnp.int32(self.pad_doc), jnp.float32(k1),
+                       jnp.float32(b), jnp.float32(self.avgdl(field)),
+                       jnp.float32(0.0))
+            prep.attrs["h2d_bytes"] = \
+                sum(a.nbytes for a in host) + 4 * len(scalars)
+            note_h2d(prep.attrs["h2d_bytes"])
+        if stack is not None:
             out = bm25_serve_packed_filtered(
-                packed_q, pf.doc_ids, pf.tf, pf.dl, self.live_dev,
-                jnp.int32(self.pad_doc), jnp.float32(k1), jnp.float32(b),
-                jnp.float32(self.avgdl(field)), jnp.float32(0.0),
-                self._filter_stack(fields),
-                jnp.asarray(fr_col), jnp.asarray(fr_lo),
-                jnp.asarray(fr_hi), jnp.asarray(fr_neg),
-                jnp.asarray(ft_col), jnp.asarray(ft_targets),
-                jnp.asarray(ft_neg),
-                S=S, CHUNK=CHUNK, R=R, k=k_pad,
+                dev[0], pf.doc_ids, pf.tf, pf.dl, self.live_dev, *scalars,
+                stack, *dev[1:], S=S, CHUNK=CHUNK, R=R, k=k_pad,
                 FR=F_RANGE, FT=F_TERM, TV=F_TERM_VALS)
         else:
             out = bm25_serve_packed(
-                packed_q, pf.doc_ids, pf.tf, pf.dl, self.live_dev,
-                jnp.int32(self.pad_doc), jnp.float32(k1), jnp.float32(b),
-                jnp.float32(self.avgdl(field)), jnp.float32(0.0),
+                dev[0], pf.doc_ids, pf.tf, pf.dl, self.live_dev, *scalars,
                 S=S, CHUNK=CHUNK, R=R, k=k_pad)
         self.device_calls += 1
-        arr = np.asarray(out)            # the ONE D2H transfer
-        arr = arr[:Q]
-        scores = np.ascontiguousarray(arr[:, :k_pad]).view(np.float32)[:, :k]
-        docs = arr[:, k_pad:2 * k_pad][:, :k].astype(np.int64)
-        hits = arr[:, 2 * k_pad].astype(np.int64)
-        consts = np.array([q.const for q in queries], np.float32)
-        if consts.any():
-            scores = np.where(scores > -np.inf,
-                              scores + consts[:, None], scores)
-        docs = np.where(scores > -np.inf, docs, -1)
+        fetch = tracing.span("packed.d2h")
+        with fetch:
+            arr = device_fetch(out)          # the ONE D2H transfer
+            fetch.attrs["d2h_bytes"] = arr.nbytes
+            arr = arr[:Q]
+            scores = np.ascontiguousarray(
+                arr[:, :k_pad]).view(np.float32)[:, :k]
+            docs = arr[:, k_pad:2 * k_pad][:, :k].astype(np.int64)
+            hits = arr[:, 2 * k_pad].astype(np.int64)
+            consts = np.array([q.const for q in queries], np.float32)
+            if consts.any():
+                scores = np.where(scores > -np.inf,
+                                  scores + consts[:, None], scores)
+            docs = np.where(scores > -np.inf, docs, -1)
         return scores, docs, hits
 
     def _build_slots(self, pf: PackedField, queries: list[PackedQuery],
                      field: str, k1: float, b: float):
         """Vectorized slot-table construction: terms -> fixed-CHUNK postings
-        slots scattered into the packed i32[Q_pad, 3S+1] upload."""
+        slots scattered into the packed i32[Q_pad, 3S+1] table (host side;
+        `search` uploads it)."""
         Q = len(queries)
         # Q buckets are {1, 32, 64, 128, ...}: the dynamic batcher produces
         # arbitrary batch sizes, and a compile per pow2 bucket would stall
@@ -509,7 +522,7 @@ class PackedIndexView:
             S = 4
             packed = np.zeros((Q_pad, 3 * S + 1), np.int32)
             packed[:, 3 * S] = min_match
-            return jnp.asarray(packed), S, R
+            return packed, S, R
 
         qi_a = np.asarray(qi_l, np.int64)
         tid_a = np.asarray(tid_l, np.int64)
@@ -551,7 +564,7 @@ class PackedIndexView:
         packed[slot_q, S + pos] = slot_len
         packed[slot_q, 2 * S + pos] = slot_w.view(np.int32)
         packed[:, 3 * S] = min_match
-        return jnp.asarray(packed), S, R
+        return packed, S, R
 
     # -- filter columns (lazy, cached) -------------------------------------
 

@@ -105,3 +105,53 @@ class TestBatcher:
             assert out["hits"]["total"] == 10, i
             ids = {int(h["_id"]) for h in out["hits"]["hits"]}
             assert ids == set(range(i, i + 10))
+
+    def test_took_of_a_batched_search_is_the_members_own(self, node):
+        """Two followers coalesced into ONE batch report their own `took`
+        (ISSUE 24): from their own arrival, not the leader's, and not
+        each other's."""
+        import time
+        from elasticsearch_tpu.common import tracing
+        body = {"query": {"match": {"body": "common"}}}
+        node.search("bt", body)                      # warm the shapes
+        leader_in, release = threading.Event(), threading.Event()
+        real = node._packed_search
+        batch_sizes = []
+
+        def held(name, bodies, **kw):
+            batch_sizes.append(len(bodies))
+            if len(batch_sizes) == 1:                # the leader's own batch
+                leader_in.set()
+                assert release.wait(10)
+            return real(name, bodies, **kw)
+
+        node._packed_search = held
+        results: dict[str, tuple] = {}
+
+        def one(tag):
+            t = time.perf_counter()
+            out = node.search("bt", body)
+            results[tag] = (out["took"], (time.perf_counter() - t) * 1000)
+
+        follows0 = tracing.AGGREGATE.stats().get(
+            "batcher.follow", {"total": 0})["total"]
+        leader = threading.Thread(target=one, args=("leader",))
+        leader.start()
+        assert leader_in.wait(10)
+        early = threading.Thread(target=one, args=("early",))
+        early.start()
+        time.sleep(0.25)                             # arrivals differ
+        late = threading.Thread(target=one, args=("late",))
+        late.start()
+        time.sleep(0.05)                             # both are queued
+        release.set()
+        for t in (leader, early, late):
+            t.join(10)
+            assert not t.is_alive()
+        assert batch_sizes == [1, 2], "the followers must share one batch"
+        for tag, (took, client_ms) in results.items():
+            assert 0 <= took <= client_ms, (tag, took, client_ms)
+        assert results["early"][0] - results["late"][0] >= 200
+        # each follower waited in a `batcher.follow` span
+        assert tracing.AGGREGATE.stats()["batcher.follow"]["total"] \
+            == follows0 + 2

@@ -130,9 +130,9 @@ class IndexService:
         self._incarnation = next(_INCARNATIONS)
         # fused serving view over all shards' segments (serving/packed_view):
         # rebuilt only when the segment set changes; tombstone-only changes
-        # refresh its liveness row in place. A single-entry common.cache
-        # Cache so its bytes/evictions surface uniformly; the removal
-        # listener releases the "request" breaker charge on every exit
+        # are folded into its packed postings in place. A single-entry
+        # common.cache Cache so its bytes/evictions surface uniformly; the
+        # removal listener releases the "request" breaker charge on every exit
         from ..common.cache import Cache
         self._packed_view_cache = Cache(
             "packed_view", max_entries=1,

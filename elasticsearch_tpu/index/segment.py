@@ -344,8 +344,8 @@ class Segment:
         self._live_all_dev: jax.Array | None = None
         self._parent_dev: jax.Array | None = None
         # monotonic tombstone generation: serving views (serving/packed_view)
-        # cache packed liveness keyed on this, so delete-only changes refresh
-        # one device row instead of rebuilding the view
+        # fold liveness into their packed postings keyed on this, so
+        # delete-only changes cost one fold instead of rebuilding the view
         self.live_gen = 0
         if not self.live_count:
             self.live_count = int(self.live_host[: self.n_docs].sum())

@@ -37,6 +37,19 @@ import functools
 import jax
 import jax.numpy as jnp
 
+# The packed lane's one sentinel doc id (bm25_serve_packed's docstring says
+# why it is a constant): padding of `doc_ids`, the unused lanes of a slot, and
+# every posting of a document that is not live.
+PACKED_PAD_DOC = int(jnp.iinfo(jnp.int32).max)
+
+# packed_fold_ids compares this many dead ids in one pass over the postings,
+# and takes a list of at most FOLD_IDS_MAX (its one compile shape). Longer
+# lists go to packed_fold_live: on the v5e a pass costs 0.022 ns a posting and
+# the gather 8.1 ns, whatever the number of postings, so the two meet near
+# 11,900 ids (PERF.md §6, PR 25).
+FOLD_IDS_BLOCK = 32
+FOLD_IDS_MAX = 8192
+
 
 def required_padding(n_postings: int, max_df: int) -> int:
     """Physical postings padding so any term slice start+Wt stays in bounds
@@ -135,8 +148,8 @@ def slot_budget(term_lens) -> int:
 @functools.partial(jax.jit,
                    static_argnames=("S", "CHUNK", "R", "k", "FR", "FT", "TV"))
 def bm25_serve_packed_filtered(packed_q: jax.Array, doc_ids: jax.Array,
-                               tf: jax.Array, dl: jax.Array, live: jax.Array,
-                               pad_doc: jax.Array, k1, b, avgdl, const,
+                               tf: jax.Array, dl: jax.Array,
+                               k1, b, avgdl, const,
                                fcols: jax.Array,
                                fr_col: jax.Array, fr_lo: jax.Array,
                                fr_hi: jax.Array, fr_neg: jax.Array,
@@ -157,11 +170,11 @@ def bm25_serve_packed_filtered(packed_q: jax.Array, doc_ids: jax.Array,
     Term slots (AND-ed; OR within a slot's TV targets): ft_col i32[Q, FT],
         ft_targets f64[Q, FT, TV] (NaN = unused target), ft_neg i32[Q, FT].
 
-    Filters gate `keep` exactly like liveness, so total_hits and top-k
+    Filters gate `keep` exactly like `min_match`, so total_hits and top-k
     honor them in the same single program — still 1 upload + 1 download.
     """
     return _serve_packed_impl(
-        packed_q, doc_ids, tf, dl, live, pad_doc, k1, b, avgdl, const,
+        packed_q, doc_ids, tf, dl, k1, b, avgdl, const,
         S=S, CHUNK=CHUNK, R=R, k=k,
         filters=(fcols, fr_col, fr_lo, fr_hi, fr_neg,
                  ft_col, ft_targets, ft_neg, FR, FT, TV))
@@ -169,8 +182,7 @@ def bm25_serve_packed_filtered(packed_q: jax.Array, doc_ids: jax.Array,
 
 @functools.partial(jax.jit, static_argnames=("S", "CHUNK", "R", "k"))
 def bm25_serve_packed(packed_q: jax.Array, doc_ids: jax.Array, tf: jax.Array,
-                      dl: jax.Array, live: jax.Array, pad_doc: jax.Array,
-                      k1, b, avgdl, const, *,
+                      dl: jax.Array, k1, b, avgdl, const, *,
                       S: int, CHUNK: int, R: int, k: int) -> jax.Array:
     """The serving kernel: ONE device program for a whole request batch
     over ALL shards/segments of an index, ONE packed input upload, ONE
@@ -197,10 +209,19 @@ def bm25_serve_packed(packed_q: jax.Array, doc_ids: jax.Array, tf: jax.Array,
     doc_ids i32[P], tf f32[P], dl f32[P]: postings packed across ALL
         segments (doc ids rebased to the global packed doc space), padded
         with >= CHUNK sentinel entries so any in-range slice stays in bounds.
-    live bool[Npad]: global liveness; index `pad_doc` (and any padding row)
-        MUST be False.
-    pad_doc i32 scalar: the PAD sentinel doc id — dynamic, so doc-space
-        growth does not recompile (only pow2 bucket changes do).
+    Liveness is the postings' own: the program takes no liveness row. A
+        posting whose document is not live (a tombstone, a nested row)
+        carries the doc id PACKED_PAD_DOC, put there by `packed_fold_live`
+        / `packed_fold_ids` when liveness changes, never per request, and
+        reads here as an unused lane: `valid` false, contribution 0,
+        count 0. PACKED_PAD_DOC is also what pads `doc_ids` and what fills
+        the unused lanes of a slot. It is int32's largest value, a constant
+        and not the view's document count: a view extended by a refresh
+        reaches every id below its own count, the old view's count among
+        them, and a folded posting lies INSIDE its slot's `valid` lanes, so
+        a sentinel that a later view can reach would come back as a hit.
+        No view reaches this one; it sorts after every real id, and the
+        filter columns' `take(..., mode="clip")` reads a padding row for it.
     R: max distinct query terms — the run-length bound of the windowed
         segment-sum. A doc appears at most once per term (chunks of one term
         are disjoint doc ranges), so runs are <= R regardless of S.
@@ -212,12 +233,12 @@ def bm25_serve_packed(packed_q: jax.Array, doc_ids: jax.Array, tf: jax.Array,
     (search/query/QueryPhase.java:91-168) with one batched program; the
     2-phase contract (ids only, fetch later) is unchanged.
     """
-    return _serve_packed_impl(packed_q, doc_ids, tf, dl, live, pad_doc,
+    return _serve_packed_impl(packed_q, doc_ids, tf, dl,
                               k1, b, avgdl, const,
                               S=S, CHUNK=CHUNK, R=R, k=k, filters=None)
 
 
-def _serve_packed_impl(packed_q, doc_ids, tf, dl, live, pad_doc,
+def _serve_packed_impl(packed_q, doc_ids, tf, dl,
                        k1, b, avgdl, const, *, S, CHUNK, R, k, filters):
     # each phase is a `jax.named_scope`: metadata only (same program, same
     # outputs), so a profile's operations group under stable names
@@ -227,7 +248,7 @@ def _serve_packed_impl(packed_q, doc_ids, tf, dl, live, pad_doc,
     weights = jax.lax.bitcast_convert_type(packed_q[:, 2 * S:3 * S],
                                            jnp.float32)
     min_match = packed_q[:, 3 * S]
-    PAD = pad_doc.astype(jnp.int32)
+    PAD = jnp.int32(PACKED_PAD_DOC)
 
     def slice_slot(s, ln):
         d = jax.lax.dynamic_slice(doc_ids, (s,), (CHUNK,))
@@ -241,6 +262,7 @@ def _serve_packed_impl(packed_q, doc_ids, tf, dl, live, pad_doc,
 
     W = S * CHUNK
     with jax.named_scope("packed.score"):
+        valid = valid & (d != PAD)      # a folded posting: not live
         norm = k1 * (1.0 - b + b * l / avgdl)
         impact = t / (t + norm)
         contrib = jnp.where(valid, weights[:, :, None] * impact, 0.0)
@@ -262,13 +284,11 @@ def _serve_packed_impl(packed_q, doc_ids, tf, dl, live, pad_doc,
                                       0.0)
             count = count + jnp.where(same, jnp.roll(cnt, j, axis=1), 0.0)
 
-    with jax.named_scope("packed.live_mask"):
+    with jax.named_scope("packed.keep"):
         is_real = d != PAD
         ends = jnp.concatenate(
             [d[:, :-1] != d[:, 1:], jnp.ones((Q, 1), bool)], axis=1) & is_real
-        accepted = live.take(d, mode="clip")
-        keep = ends & accepted \
-            & (count >= min_match[:, None].astype(jnp.float32))
+        keep = ends & (count >= min_match[:, None].astype(jnp.float32))
 
     if filters is not None:
         (fcols, fr_col, fr_lo, fr_hi, fr_neg,
@@ -317,6 +337,45 @@ def _serve_packed_impl(packed_q, doc_ids, tf, dl, live, pad_doc,
         return jnp.concatenate(
             [jax.lax.bitcast_convert_type(top, jnp.int32), top_docs,
              total_hits[:, None]], axis=1)
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def packed_fold_live(doc_ids: jax.Array, live: jax.Array) -> jax.Array:
+    """Fold liveness into packed postings, in full: every posting whose
+    document is not live becomes PACKED_PAD_DOC. One gather over P — for a
+    newly packed field and for long lists of new tombstones.
+
+    doc_ids i32[P] is DONATED (the folded ids take its place: no second
+    per-posting array). live bool[Npad]: global liveness; its last row MUST
+    be False (an id folded earlier clips to it and stays folded).
+    """
+    return jnp.where(live.take(doc_ids, mode="clip"), doc_ids,
+                     jnp.int32(PACKED_PAD_DOC))
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def packed_fold_ids(doc_ids: jax.Array, dead: jax.Array,
+                    n_blocks: jax.Array) -> jax.Array:
+    """The same fold for a SHORT list of documents that died since the last
+    one: a streaming compare of the postings against the list, FOLD_IDS_BLOCK
+    ids a pass, no gather. Equal, array for array, to packed_fold_live with
+    the liveness row those deaths give.
+
+    doc_ids i32[P] is DONATED. dead i32[FOLD_IDS_MAX]: the global doc ids,
+    padded with PACKED_PAD_DOC. n_blocks i32: ceil(len / FOLD_IDS_BLOCK) —
+    dynamic, so one compile serves every length.
+    """
+    PAD = jnp.int32(PACKED_PAD_DOC)
+
+    def one_pass(i, ids):
+        blk = jax.lax.dynamic_slice(dead, (i * FOLD_IDS_BLOCK,),
+                                    (FOLD_IDS_BLOCK,))
+        hit = ids == blk[0]
+        for u in range(1, FOLD_IDS_BLOCK):
+            hit = hit | (ids == blk[u])
+        return jnp.where(hit, PAD, ids)
+
+    return jax.lax.fori_loop(0, n_blocks, one_pass, doc_ids)
 
 
 @functools.partial(jax.jit, static_argnames=("Wt", "k", "n_docs"))
@@ -370,3 +429,5 @@ bm25_topk_sparse_masked = _instrument(
 bm25_serve_packed = _instrument("ops:bm25_serve_packed", bm25_serve_packed)
 bm25_serve_packed_filtered = _instrument(
     "ops:bm25_serve_packed_filtered", bm25_serve_packed_filtered)
+packed_fold_live = _instrument("ops:packed_fold_live", packed_fold_live)
+packed_fold_ids = _instrument("ops:packed_fold_ids", packed_fold_ids)

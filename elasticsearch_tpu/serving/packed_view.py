@@ -18,15 +18,20 @@ reduce). Term statistics are naturally index-global — equivalent to running
 the DFS phase (search/dfs/DfsPhase.java:57-81) on every request, which is
 *better* scoring parity than per-shard IDF.
 
-The view is immutable w.r.t. the segment set; deletes only refresh the packed
-liveness row (Segment.live_gen tracks that). IndexService caches the view
-keyed by segment set and rebuilds liveness on tombstone changes.
+The view is immutable w.r.t. the segment set. Liveness lives in the packed
+postings themselves: when a segment's tombstones change (Segment.live_gen
+tracks that) the next search folds them into each field's `doc_ids` — the
+postings of a document that is no longer live become PACKED_PAD_DOC — once a
+change, on the device, and the program gathers no liveness per request.
+IndexService caches the view keyed by segment set.
 """
 
 from __future__ import annotations
 
+import contextlib
 import math
 import re
+import threading
 from dataclasses import dataclass
 
 import numpy as np
@@ -36,7 +41,9 @@ import jax.numpy as jnp
 from ..common import tracing
 from ..common.metrics import device_fetch, note_h2d
 from ..index.segment import Segment, next_pow2
-from ..ops.bm25_sparse import bm25_serve_packed, bm25_serve_packed_filtered
+from ..ops.bm25_sparse import (FOLD_IDS_BLOCK, FOLD_IDS_MAX, PACKED_PAD_DOC,
+                               bm25_serve_packed, bm25_serve_packed_filtered,
+                               packed_fold_ids, packed_fold_live)
 
 # Fixed postings chunk: compile-cache keys depend on (Q, S) pow2 buckets only,
 # never on the corpus' df distribution.
@@ -81,11 +88,20 @@ class PackedFilterColumn:
 
 
 class PackedField:
-    """One text field's postings packed across every segment of the index."""
+    """One text field's postings packed across every segment of the index.
+
+    `doc_ids` carries liveness (module docstring): `folded_live` is the
+    liveness it reflects and `live_key` the segments' `live_gen` at that
+    fold. That state is the field's, not the view's: an extended view shares
+    the field when no segment it appends has postings in it. The fold DONATES
+    `doc_ids`, so every reader takes them through
+    `PackedIndexView._folded_ids`, which counts the dispatches in flight
+    (`in_use`, under `cv`); a fold waits until there are none."""
 
     def __init__(self, doc_ids: jax.Array, tf: jax.Array, dl: jax.Array,
                  terms: np.ndarray, starts: np.ndarray, lens: np.ndarray,
-                 sum_dl: float, total_p: int = 0):
+                 sum_dl: float, total_p: int, folded_live: np.ndarray,
+                 n_entries: int):
         self.doc_ids = doc_ids          # i32[P_pad] device, PAD-padded
         self.tf = tf                    # f32[P_pad]
         self.dl = dl                    # f32[P_pad]
@@ -95,6 +111,11 @@ class PackedField:
         self.df = lens.sum(axis=1)      # i64[V] global df
         self.sum_dl = sum_dl
         self.total_p = total_p          # real postings (un-padded)
+        self.folded_live = folded_live  # bool[N] host, over global doc ids
+        self.n_entries = n_entries      # leading view entries N spans
+        self.live_key: tuple = (None,) * n_entries   # no segment folded yet
+        self.cv = threading.Condition()
+        self.in_use = 0
 
     def term_ids(self, terms: list[str]) -> np.ndarray:
         """Vectorized term lookup; -1 for absent terms."""
@@ -130,7 +151,6 @@ class PackedIndexView:
         self.n_total = int(self.bases[-1])
         self.n_pad_total = next_pow2(self.n_total + 1, floor=8)
         self.doc_count = sum(s.n_docs for _, s in segments)
-        self.pad_doc = self.n_total      # global PAD sentinel (never live)
 
         # host columns for vectorized fetch: _id / _type per global doc id
         max_id = max((max((len(i) for i in s.ids), default=1)
@@ -157,8 +177,6 @@ class PackedIndexView:
         self._refused: set[str] = set()   # breaker-refused (≠ absent) fields
         self._filter_cols: dict[str, PackedFilterColumn | None] = {}
         self._filter_stacks: dict[tuple, jax.Array] = {}
-        self._live_key: tuple | None = None
-        self._live_dev: jax.Array | None = None
         self.device_calls = 0           # serving counters (observability)
         self.memory_bytes = 0
         self.extended_from_base = False
@@ -207,9 +225,9 @@ class PackedIndexView:
                 else np.asarray(fx.doc_ids)[:fx.n_postings]
             per_seg.append((ei, fx, host_ids[:fx.n_postings]))
         if not per_seg:
-            # stale PAD sentinels inside the old buffer are masked by the
-            # kernel's per-slot valid lanes, so the arrays are reusable —
-            # but the old view's charge was released by IndexService, so the
+            # the arrays are reusable as they are (the PAD sentinel is no
+            # view's doc id, and the fold's state is the field's own) — but
+            # the old view's charge was released by IndexService, so the
             # still-resident buffers must be re-charged into THIS view
             # (check=False: memory already exists) or repeated NRT refreshes
             # progressively undercount the request breaker (advisor r4).
@@ -224,7 +242,7 @@ class PackedIndexView:
         p_pad = next_pow2(base_p + total_new + CHUNK, floor=CHUNK * 2)
         if self.breaker is not None:
             self.breaker.add_estimate(p_pad * 12)
-        tail_docs = np.full(p_pad - base_p, self.pad_doc, np.int32)
+        tail_docs = np.full(p_pad - base_p, PACKED_PAD_DOC, np.int32)
         tail_tf = np.zeros(p_pad - base_p, np.float32)
         tail_dl = np.ones(p_pad - base_p, np.float32)
 
@@ -256,14 +274,21 @@ class PackedIndexView:
             sum_dl += fx.sum_dl
             off += P
 
-        doc_ids = jnp.concatenate([pf.doc_ids[:base_p],
-                                   jnp.asarray(tail_docs)])
+        # the old postings come over as they are folded; nothing of the
+        # appended segments is: the first search folds what is dead there
+        with base._folded_ids(pf) as head:
+            doc_ids = jnp.concatenate([head[:base_p],
+                                       jnp.asarray(tail_docs)])
+            folded_live = np.concatenate(
+                [pf.folded_live, self._all_docs_live(pf.n_entries)])
         tf = jnp.concatenate([pf.tf[:base_p], jnp.asarray(tail_tf)])
         dl = jnp.concatenate([pf.dl[:base_p], jnp.asarray(tail_dl)])
         self.memory_bytes += p_pad * 12
         return PackedField(doc_ids=doc_ids, tf=tf, dl=dl, terms=all_terms,
                            starts=starts, lens=lens, sum_dl=sum_dl,
-                           total_p=base_p + total_new)
+                           total_p=base_p + total_new,
+                           folded_live=folded_live,
+                           n_entries=len(self.entries))
 
     def _extend_filter_col(self, name: str, base: "PackedIndexView",
                            col: PackedFilterColumn) -> PackedFilterColumn:
@@ -315,23 +340,91 @@ class PackedIndexView:
         self.memory_bytes += self.n_pad_total * 8
         return PackedFilterColumn("keyword", vals, vocab=vocab)
 
-    # -- liveness (rebuilt on tombstone changes only) ----------------------
+    # -- liveness (folded into the postings when tombstones change) --------
 
-    def _live_gen_key(self) -> tuple:
-        return tuple(s.live_gen for _, s in self.entries)
+    def _all_docs_live(self, first_entry: int) -> np.ndarray:
+        """bool over the global ids of entries[first_entry:]: what postings
+        nobody has folded yet reflect — every document live, padding not."""
+        lo = int(self.bases[first_entry])
+        rows = np.zeros(self.n_total - lo, bool)
+        for ei in range(first_entry, len(self.entries)):
+            at = int(self.bases[ei]) - lo
+            rows[at:at + self.entries[ei][1].n_docs] = True
+        return rows
 
-    @property
-    def live_dev(self) -> jax.Array:
-        key = self._live_gen_key()
-        if self._live_dev is None or key != self._live_key:
-            live = np.zeros(self.n_pad_total, bool)
-            for ei, (_, seg) in enumerate(self.entries):
-                live[self.bases[ei]:self.bases[ei] + seg.n_pad] = \
-                    seg.root_live_host   # nested rows never serve as hits
-            live[self.n_total:] = False
-            self._live_dev = jnp.asarray(live)
-            self._live_key = key
-        return self._live_dev
+    def _died_since_fold(self, pf: PackedField):
+        """(live_gen key, global ids no longer live that `pf.doc_ids` still
+        carries), or None when the ids are current. Caller holds pf.cv."""
+        # the generations BEFORE the rows: a delete that lands in between is
+        # folded now and looked at once more by the next search, never lost
+        key = tuple(seg.live_gen for _, seg in self.entries[:pf.n_entries])
+        if key == pf.live_key:
+            return None
+        died = []
+        for ei, gen in enumerate(key):
+            if pf.live_key[ei] == gen:
+                continue
+            seg = self.entries[ei][1]
+            lo = int(self.bases[ei])
+            was = pf.folded_live[lo:lo + seg.n_pad]
+            # nested rows never serve as hits: root liveness
+            died.append(lo + np.flatnonzero(was & ~seg.root_live_host))
+        died = np.concatenate(died)
+        if not len(died):
+            pf.live_key = key
+            return None
+        return key, died
+
+    def _fold(self, pf: PackedField, died: np.ndarray, by_list: bool) -> None:
+        """Make the postings of `died` unused lanes, on the device, in place.
+        Tombstones are never undone inside a segment, so the folded ids take
+        `pf.doc_ids`' place. `by_list` streams the postings past the list,
+        which a long list makes dearer than the one gather over all of them
+        that the other program is. Caller holds pf.cv with no dispatch in
+        flight."""
+        if by_list:
+            dead = np.full(FOLD_IDS_MAX, PACKED_PAD_DOC, np.int32)
+            dead[:len(died)] = died
+            pf.doc_ids = packed_fold_ids(
+                pf.doc_ids, jnp.asarray(dead),
+                jnp.int32(-(-len(died) // FOLD_IDS_BLOCK)))
+        else:
+            n = len(pf.folded_live)
+            live = np.zeros(next_pow2(n + 1, floor=8), bool)
+            live[:n] = pf.folded_live
+            live[died] = False
+            pf.doc_ids = packed_fold_live(pf.doc_ids, jnp.asarray(live))
+        pf.folded_live[died] = False
+
+    @contextlib.contextmanager
+    def _folded_ids(self, pf: PackedField):
+        """`pf.doc_ids` with this moment's tombstones folded in, held for the
+        dispatches made inside the block: no refresh is needed for a delete
+        to be seen, and no fold donates the buffer under a thread that is
+        about to dispatch it (the batcher's leaders and the `_msearch` pool
+        threads share one field). Dispatches of several threads overlap; a
+        fold waits for those in flight, and whoever comes meanwhile waits
+        for the fold."""
+        with pf.cv:
+            while (due := self._died_since_fold(pf)) is not None:
+                if pf.in_use:
+                    pf.cv.wait()
+                    continue
+                key, died = due
+                by_list = len(died) <= FOLD_IDS_MAX
+                with tracing.span("packed.live_fold", tombstones=len(died),
+                                  kind="incremental" if by_list else "full"):
+                    self._fold(pf, died, by_list)
+                pf.live_key = key
+            pf.in_use += 1
+            doc_ids = pf.doc_ids
+        try:
+            yield doc_ids
+        finally:
+            with pf.cv:
+                pf.in_use -= 1
+                if not pf.in_use:
+                    pf.cv.notify_all()
 
     # -- field packing (lazy, cached) --------------------------------------
 
@@ -366,7 +459,7 @@ class PackedIndexView:
 
         total_p = sum(len(h) for _, _, h in per_seg)
         p_pad = next_pow2(total_p + CHUNK, floor=CHUNK * 2)
-        doc_ids = np.full(p_pad, self.pad_doc, np.int32)
+        doc_ids = np.full(p_pad, PACKED_PAD_DOC, np.int32)
         tf = np.zeros(p_pad, np.float32)
         dl = np.ones(p_pad, np.float32)
 
@@ -408,7 +501,8 @@ class PackedIndexView:
         return PackedField(
             doc_ids=jnp.asarray(doc_ids), tf=jnp.asarray(tf),
             dl=jnp.asarray(dl), terms=all_terms, starts=starts,
-            lens=lens.astype(np.int64), sum_dl=sum_dl, total_p=total_p)
+            lens=lens.astype(np.int64), sum_dl=sum_dl, total_p=total_p,
+            folded_live=self._all_docs_live(0), n_entries=len(self.entries))
 
     # -- stats (parity with query_dsl.CollectionStats) ---------------------
 
@@ -447,21 +541,21 @@ class PackedIndexView:
                 host += descriptors
                 stack = self._filter_stack(fields)
             dev = [jnp.asarray(a) for a in host]
-            scalars = (jnp.int32(self.pad_doc), jnp.float32(k1),
-                       jnp.float32(b), jnp.float32(self.avgdl(field)),
-                       jnp.float32(0.0))
+            scalars = (jnp.float32(k1), jnp.float32(b),
+                       jnp.float32(self.avgdl(field)), jnp.float32(0.0))
             prep.attrs["h2d_bytes"] = \
                 sum(a.nbytes for a in host) + 4 * len(scalars)
             note_h2d(prep.attrs["h2d_bytes"])
-        if stack is not None:
-            out = bm25_serve_packed_filtered(
-                dev[0], pf.doc_ids, pf.tf, pf.dl, self.live_dev, *scalars,
-                stack, *dev[1:], S=S, CHUNK=CHUNK, R=R, k=k_pad,
-                FR=F_RANGE, FT=F_TERM, TV=F_TERM_VALS)
-        else:
-            out = bm25_serve_packed(
-                dev[0], pf.doc_ids, pf.tf, pf.dl, self.live_dev, *scalars,
-                S=S, CHUNK=CHUNK, R=R, k=k_pad)
+        with self._folded_ids(pf) as doc_ids:
+            if stack is not None:
+                out = bm25_serve_packed_filtered(
+                    dev[0], doc_ids, pf.tf, pf.dl, *scalars,
+                    stack, *dev[1:], S=S, CHUNK=CHUNK, R=R, k=k_pad,
+                    FR=F_RANGE, FT=F_TERM, TV=F_TERM_VALS)
+            else:
+                out = bm25_serve_packed(
+                    dev[0], doc_ids, pf.tf, pf.dl, *scalars,
+                    S=S, CHUNK=CHUNK, R=R, k=k_pad)
         self.device_calls += 1
         fetch = tracing.span("packed.d2h")
         with fetch:
@@ -734,30 +828,35 @@ class PackedIndexView:
         multi-second XLA compile (p99 guard): Q in {1, 32} covers every solo
         and dynamically-batched request (the Q/S buckets in _build_slots
         steer traffic onto exactly these), for both the plain and the
-        filtered kernel. The persistent compile cache makes this a one-time
-        cost per machine."""
+        filtered kernel, and the two folds of liveness (empty ones). The
+        persistent compile cache makes this a one-time cost per machine."""
         pf = self._fields.get(field)
         if pf is None:
             return
-        common = (pf.doc_ids, pf.tf, pf.dl, self.live_dev,
-                  jnp.int32(self.pad_doc), jnp.float32(1.2),
-                  jnp.float32(0.75), jnp.float32(1.0), jnp.float32(0.0))
-        for (q, s, k) in shapes:
-            packed = np.zeros((q, 3 * s + 1), np.int32)
-            packed[:, 3 * s] = 1
-            bm25_serve_packed(jnp.asarray(packed), *common,
-                              S=s, CHUNK=CHUNK, R=4, k=k)
-        for (q, s, k) in filtered_shapes:
-            packed = np.zeros((q, 3 * s + 1), np.int32)
-            packed[:, 3 * s] = 1
-            bm25_serve_packed_filtered(
-                jnp.asarray(packed), *common,
-                jnp.zeros((1, self.n_pad_total), jnp.float64),
-                jnp.full((q, F_RANGE), -1, jnp.int32),
-                jnp.zeros((q, F_RANGE)), jnp.zeros((q, F_RANGE)),
-                jnp.zeros((q, F_RANGE), jnp.int32),
-                jnp.full((q, F_TERM), -1, jnp.int32),
-                jnp.full((q, F_TERM, F_TERM_VALS), jnp.nan),
-                jnp.zeros((q, F_TERM), jnp.int32),
-                S=s, CHUNK=CHUNK, R=4, k=k,
-                FR=F_RANGE, FT=F_TERM, TV=F_TERM_VALS)
+        with pf.cv:     # both folds: a first delete must not compile
+            while pf.in_use:
+                pf.cv.wait()
+            for by_list in (True, False):
+                self._fold(pf, np.empty(0, np.int64), by_list)
+        with self._folded_ids(pf) as doc_ids:
+            common = (doc_ids, pf.tf, pf.dl, jnp.float32(1.2),
+                      jnp.float32(0.75), jnp.float32(1.0), jnp.float32(0.0))
+            for (q, s, k) in shapes:
+                packed = np.zeros((q, 3 * s + 1), np.int32)
+                packed[:, 3 * s] = 1
+                bm25_serve_packed(jnp.asarray(packed), *common,
+                                  S=s, CHUNK=CHUNK, R=4, k=k)
+            for (q, s, k) in filtered_shapes:
+                packed = np.zeros((q, 3 * s + 1), np.int32)
+                packed[:, 3 * s] = 1
+                bm25_serve_packed_filtered(
+                    jnp.asarray(packed), *common,
+                    jnp.zeros((1, self.n_pad_total), jnp.float64),
+                    jnp.full((q, F_RANGE), -1, jnp.int32),
+                    jnp.zeros((q, F_RANGE)), jnp.zeros((q, F_RANGE)),
+                    jnp.zeros((q, F_RANGE), jnp.int32),
+                    jnp.full((q, F_TERM), -1, jnp.int32),
+                    jnp.full((q, F_TERM, F_TERM_VALS), jnp.nan),
+                    jnp.zeros((q, F_TERM), jnp.int32),
+                    S=s, CHUNK=CHUNK, R=4, k=k,
+                    FR=F_RANGE, FT=F_TERM, TV=F_TERM_VALS)

@@ -351,3 +351,343 @@ class TestReviewRegressions:
         finally:
             srv.stop()
             node.close()
+
+
+# -- the liveness fold (ISSUE 25) --------------------------------------------
+# Tombstones and nested rows are folded into the packed postings when they
+# change; the program takes no liveness row.
+
+WORDS = ["fox", "dog", "quick", "brown", "lazy", "red", "story", "jumps"]
+
+
+def fold_node(tmp_path, n_shards, segments, n=60):
+    """n documents; "fox" in two of three, "dog" in half, ranks 0..n-1."""
+    node = NodeService(str(tmp_path / "fold"))
+    node.create_index("idx", settings={"number_of_shards": n_shards},
+                      mappings={"_doc": {"properties": {
+                          "title": {"type": "text"},
+                          "tag": {"type": "keyword"},
+                          "rank": {"type": "long"}}}})
+    per_seg = -(-n // segments)
+    for i in range(n):
+        text = " ".join(w for j, w in enumerate(WORDS)
+                        if (i + j) % 3 != 0 or (i * j) % 5 == 1)
+        text += " fox" * (i % 4)
+        node.index_doc("idx", str(i), {"title": text, "tag": f"t{i % 3}",
+                                       "rank": i})
+        if (i + 1) % per_seg == 0:
+            node.refresh("idx")
+    node.refresh("idx")
+    return node
+
+
+def hits_of(out):
+    return [(h["_id"], h["_score"]) for h in out["hits"]["hits"]]
+
+
+def fold_spans():
+    from elasticsearch_tpu.common import tracing
+    return tracing.AGGREGATE.stats().get("packed.live_fold",
+                                         {"total": 0})["total"]
+
+
+def unit_view(n, text=lambda i: "common word%d" % (i % 7)):
+    from elasticsearch_tpu.index.segment import SegmentBuilder
+    from elasticsearch_tpu.mapping.mapper import MapperService
+    mapper = MapperService().document_mapper("_doc")
+    b = SegmentBuilder(seg_id=1)
+    for i in range(n):
+        b.add(mapper.parse({"t": text(i)}, doc_id=str(i)), "_doc")
+    seg = b.build()
+    return seg, PackedIndexView([(0, seg)])
+
+
+class TestLivenessFold:
+    @pytest.mark.parametrize("n_shards,segments",
+                             [(1, 1), (1, 3), (5, 1), (5, 3)])
+    def test_deletes_inside_the_top_k(self, tmp_path, n_shards, segments):
+        node = fold_node(tmp_path, n_shards, segments)
+        body = {"query": {"match": {"title": "quick fox"}}}
+        before = node.search("idx", body, size=10)
+        top = hits_of(before)
+        gone = [top[0][0], top[3][0], top[7][0]]
+        for doc_id in gone:
+            node.delete_doc("idx", doc_id)
+        view = node.indices["idx"].packed_view()
+        node.refresh("idx")         # the engine applies its pending deletes
+        assert node.indices["idx"].packed_view() is view
+        packed = node.search("idx", body, size=60)
+        general = general_path(node, "idx", body, size=60)
+        assert packed["hits"]["total"] == general["hits"]["total"] \
+            == before["hits"]["total"] - len(gone)
+        got = dict(hits_of(packed))
+        assert set(got) == {i for i, _ in hits_of(general)}
+        assert not set(got) & set(gone)
+        # term statistics still count the deleted documents (as Lucene's do
+        # until a merge): whoever is left scores what it scored
+        for doc_id, score in top:
+            if doc_id not in gone:
+                assert got[doc_id] == pytest.approx(score, abs=1e-6)
+        if n_shards == 1:           # else the general lane's IDF is per shard
+            assert [i for i, _ in hits_of(packed)] \
+                == [i for i, _ in hits_of(general)]
+            for (_, sp), (_, sg) in zip(hits_of(packed), hits_of(general)):
+                assert sp == pytest.approx(sg, abs=1e-6)
+        node.close()
+
+    def test_tombstone_is_seen_by_the_next_search(self):
+        """No refresh and no new view: the segment's `live_gen` moved."""
+        seg, view = unit_view(40)
+        q = [PackedQuery(terms=["word3"])]
+        _, docs, hits = view.search("t", q, k=16)
+        assert int(hits[0]) == 6 and 3 in docs[0]
+        assert seg.delete_local(3)
+        _, docs, hits = view.search("t", q, k=16)
+        assert int(hits[0]) == 5 and 3 not in docs[0]
+
+    @pytest.mark.parametrize("order", ["fold_then_extend",
+                                       "extend_then_fold"])
+    def test_extended_view_returns_the_old_pad_doc(self, tmp_path, order):
+        """The first document a refresh appends takes the global id that was
+        the base view's document count. A posting folded to THAT would come
+        back as this document."""
+        node = fold_node(tmp_path, 1, 1, n=20)
+        body = {"query": {"match": {"title": "fox"}}}
+        first = node.search("idx", body, size=40)
+        base = node.indices["idx"].packed_view()
+        gone = [h[0] for h in hits_of(first)[:3]]
+        for doc_id in gone:
+            node.delete_doc("idx", doc_id)
+        if order == "fold_then_extend":
+            node.refresh("idx")
+            node.search("idx", body)            # folds into the base's ids
+            assert node.indices["idx"].packed_view() is base
+        for i in range(100, 104):
+            node.index_doc("idx", str(i), {"title": "fox fox", "rank": i})
+        node.refresh("idx")
+        view = node.indices["idx"].packed_view()
+        assert view is not base and view.extended_from_base
+        assert view.ids_packed[base.n_total] == "100"
+        out = node.search("idx", body, size=40)
+        ids = [i for i, _ in hits_of(out)]
+        assert {"100", "101", "102", "103"} <= set(ids)
+        assert not set(ids) & set(gone)
+        assert out["hits"]["total"] == first["hits"]["total"] - 3 + 4 \
+            == general_path(node, "idx", body, size=40)["hits"]["total"]
+        assert len(ids) == len(set(ids)) == out["hits"]["total"]
+        node.close()
+
+    def test_nested_rows_are_never_hits(self, tmp_path):
+        node = NodeService(str(tmp_path / "nested"))
+        node.create_index("blog", mappings={"_doc": {"properties": {
+            "title": {"type": "string"},
+            "comments": {"type": "nested", "properties": {
+                "text": {"type": "string"}}}}}})
+        for i in range(6):
+            node.index_doc("blog", str(i), {
+                "title": "great post" if i % 2 else "dull post",
+                "comments": [{"text": "great post"}, {"text": "post"}]})
+        node.refresh("blog")
+        view = node.indices["blog"].packed_view()
+        assert view.n_total >= 18           # the nested rows are in the space
+        _, docs, hits = view.search("title", [PackedQuery(terms=["post"])],
+                                    k=32)
+        assert int(hits[0]) == 6
+        assert {view.ids_packed[d] for d in docs[0] if d >= 0} \
+            == {str(i) for i in range(6)}
+        # every posting of the nested field belongs to a nested row
+        _, docs, hits = view.search(
+            "comments.text", [PackedQuery(terms=["post"])], k=32)
+        assert int(hits[0]) == 0 and (docs[0] == -1).all()
+        node.delete_doc("blog", "1")        # cascades to its nested rows
+        node.refresh("blog")
+        out = node.search("blog", {"query": {"match": {"title": "great"}}})
+        assert {h["_id"] for h in out["hits"]["hits"]} == {"3", "5"}
+        node.close()
+
+    @pytest.mark.parametrize("flt", [
+        {"term": {"tag": "t1"}}, {"range": {"rank": {"gte": 10, "lt": 40}}}])
+    def test_filtered_program_with_tombstones(self, tmp_path, flt):
+        node = fold_node(tmp_path, 2, 2)
+        body = {"query": {"bool": {"must": [{"match": {"title": "fox dog"}}],
+                                   "filter": [flt]}}}
+        before = node.search("idx", body, size=60)
+        gone = [h[0] for h in hits_of(before)[:4]]
+        for doc_id in gone:
+            node.delete_doc("idx", doc_id)
+        node.refresh("idx")
+        packed = node.search("idx", body, size=60)
+        general = general_path(node, "idx", body, size=60)
+        assert packed["hits"]["total"] == general["hits"]["total"] \
+            == before["hits"]["total"] - 4
+        assert {i for i, _ in hits_of(packed)} \
+            == {i for i, _ in hits_of(general)} \
+            == {i for i, _ in hits_of(before)} - set(gone)
+        node.close()
+
+    @pytest.mark.parametrize("d", ["1", "switch", "switch+1", "tenth"])
+    def test_incremental_fold_equals_full_fold(self, d):
+        """Array for array, either side of the length that switches the
+        programs; the reference is the gather written in numpy."""
+        from elasticsearch_tpu.ops import bm25_sparse as K
+        n = K.FOLD_IDS_MAX + 600
+        d = {"1": 1, "switch": K.FOLD_IDS_MAX, "switch+1": K.FOLD_IDS_MAX + 1,
+             "tenth": n // 10}[d]
+        seg, view = unit_view(n)
+        q = [PackedQuery(terms=["common", "word2"])]
+        view.search("t", q, k=8)
+        pf = view.field("t")
+        ids0 = np.asarray(pf.doc_ids)
+        assert (ids0[:pf.total_p] < n).all() \
+            and (ids0[pf.total_p:] == K.PACKED_PAD_DOC).all()
+        ran = {p: p.record.invocations
+               for p in (K.packed_fold_ids, K.packed_fold_live)}
+        dead = np.random.default_rng(d).permutation(n)[:d]
+        for local in dead:
+            seg.delete_local(int(local))
+        _, _, hits = view.search("t", q, k=8)
+        assert int(hits[0]) == n - d
+        live = np.ones(n + 1, bool)
+        live[dead] = False
+        live[n] = False
+        want = np.where(live[np.minimum(ids0, n)], ids0, K.PACKED_PAD_DOC)
+        np.testing.assert_array_equal(np.asarray(pf.doc_ids), want)
+        np.testing.assert_array_equal(pf.folded_live[:n], live[:n])
+        ran = {p.record.name: p.record.invocations - n0
+               for p, n0 in ran.items()}
+        short = d <= K.FOLD_IDS_MAX
+        assert ran == {"ops:packed_fold_ids": int(short),
+                       "ops:packed_fold_live": int(not short)}
+        # and the two programs against each other on the same input
+        pad = np.full(-(-d // K.FOLD_IDS_BLOCK) * K.FOLD_IDS_BLOCK,
+                      K.PACKED_PAD_DOC, np.int32)
+        pad[:d] = dead
+        import jax.numpy as jnp
+        by_list = K.packed_fold_ids(
+            jnp.asarray(ids0), jnp.asarray(pad),
+            jnp.int32(len(pad) // K.FOLD_IDS_BLOCK))
+        by_row = K.packed_fold_live(jnp.asarray(ids0), jnp.asarray(live))
+        np.testing.assert_array_equal(np.asarray(by_list),
+                                      np.asarray(by_row))
+
+    def test_searches_race_a_deleter(self):
+        """Two threads search while a third deletes: the fold donates the
+        postings' buffer, and no search may dispatch the old one; a document
+        whose delete had returned before a search began is not in it."""
+        import sys
+        import threading
+        import time
+        n = 160
+        seg, view = unit_view(n)
+        q = [PackedQuery(terms=["common"])]
+        view.search("t", q, k=256)                  # packed and warm
+        done_at: dict[int, float] = {}
+        errors: list = []
+        searches = [0]
+        stop = threading.Event()
+
+        def searcher():
+            try:
+                while not stop.is_set():
+                    began = time.perf_counter()
+                    dead = {i for i, t in list(done_at.items()) if t < began}
+                    _, docs, hits = view.search("t", q, k=256)
+                    got = {int(x) for x in docs[0] if x >= 0}
+                    assert not got & dead, sorted(got & dead)
+                    assert int(hits[0]) == len(got) <= n - len(dead)
+                    searches[0] += 1
+            except BaseException as e:      # noqa: BLE001 — reported below
+                errors.append(e)
+                stop.set()
+
+        def deleter():
+            try:
+                for i in range(0, n, 2):
+                    if stop.is_set():
+                        return
+                    seg.delete_local(i)
+                    done_at[i] = time.perf_counter()
+                    time.sleep(0.002)
+            except BaseException as e:      # noqa: BLE001
+                errors.append(e)
+            finally:
+                stop.set()
+
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        threads = [threading.Thread(target=f)
+                   for f in (searcher, searcher, deleter)]
+        try:
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=120)
+        finally:
+            stop.set()
+            sys.setswitchinterval(interval)
+        assert not any(t.is_alive() for t in threads)
+        assert not errors, errors
+        assert len(done_at) == n // 2 and searches[0] > 0
+        _, docs, hits = view.search("t", q, k=256)
+        assert int(hits[0]) == n // 2
+        assert {int(x) for x in docs[0] if x >= 0} == set(range(1, n, 2))
+        pf = view.field("t")
+        assert pf.in_use == 0
+
+    def test_one_fold_for_a_delete_and_two_searches(self, tmp_path):
+        node = make_node(tmp_path)
+        body = {"query": {"match": {"title": "fox"}}}
+        node.search("idx", body)
+        node.delete_doc("idx", "5")
+        node.refresh("idx")
+        before = fold_spans()
+        a = node.search("idx", body)
+        b = node.search("idx", body)
+        assert fold_spans() == before + 1
+        assert hits_of(a) == hits_of(b) and "5" not in dict(hits_of(a))
+        node.close()
+
+    @pytest.mark.parametrize("program", ["plain", "filtered"])
+    def test_program_gathers_no_liveness(self, program):
+        """At the shape of `wiki.rerank-top1000`: no operand of the program
+        is a liveness row, and nothing is gathered element by element at the
+        Q x S x CHUNK candidate slots but the filter columns."""
+        import re
+        import jax
+        import jax.numpy as jnp
+        from elasticsearch_tpu.ops import bm25_sparse as K
+        from elasticsearch_tpu.serving.packed_view import (
+            CHUNK, F_RANGE, F_TERM, F_TERM_VALS)
+        Q, S, P, N = 256, 256, 1 << 24, 262144
+        sd = jax.ShapeDtypeStruct
+        args = [sd((Q, 3 * S + 1), jnp.int32), sd((P,), jnp.int32),
+                sd((P,), jnp.float32), sd((P,), jnp.float32)] \
+            + [sd((), jnp.float32)] * 4
+        if program == "plain":
+            low = K.bm25_serve_packed.jit.lower(
+                *args, S=S, CHUNK=CHUNK, R=8, k=1024)
+        else:
+            low = K.bm25_serve_packed_filtered.jit.lower(
+                *args, sd((1, N), jnp.float64),
+                sd((Q, F_RANGE), jnp.int32), sd((Q, F_RANGE), jnp.float64),
+                sd((Q, F_RANGE), jnp.float64), sd((Q, F_RANGE), jnp.int32),
+                sd((Q, F_TERM), jnp.int32),
+                sd((Q, F_TERM, F_TERM_VALS), jnp.float64),
+                sd((Q, F_TERM), jnp.int32), S=S, CHUNK=CHUNK, R=8, k=1024,
+                FR=F_RANGE, FT=F_TERM, TV=F_TERM_VALS)
+        text = low.as_text()
+        params = re.search(r"func\.func public @main\((.*?)\)\s*->", text,
+                           re.S).group(1)
+        assert "xi1>" not in params and f"tensor<{N}x" not in \
+            params.replace(f"tensor<1x{N}xf64>", "")
+        per_slot = []           # (operand type, result type) of such gathers
+        for m in re.finditer(
+                r'"stablehlo\.gather"\(.*?slice_sizes = array<i64: ([\d, ]+)>'
+                r'.*?:\s*\((tensor<[^>]+>), tensor<[^>]+>\)\s*->\s*'
+                r'tensor<([\dx]+)x(\w+)>', text):
+            sizes, operand, dims, dtype = m.groups()
+            elements = int(np.prod([int(x) for x in dims.split("x")]))
+            if elements >= Q * S * CHUNK and set(sizes.split(", ")) == {"1"}:
+                per_slot.append((operand, dtype))
+        assert per_slot == ([] if program == "plain"
+                            else [(f"tensor<1x{N}xf64>", "f64")])

@@ -313,11 +313,11 @@ def test_packed_program_names_every_scope():
     Q, S, CHUNK, P, N = 2, 4, 8, 64, 32
     common = (jnp.zeros((Q, 3 * S + 1), jnp.int32),
               jnp.zeros(P, jnp.int32), jnp.ones(P, jnp.float32),
-              jnp.ones(P, jnp.float32), jnp.zeros(N, bool), jnp.int32(N - 1),
+              jnp.ones(P, jnp.float32),
               jnp.float32(1.2), jnp.float32(0.75), jnp.float32(1.0),
               jnp.float32(0.0))
     scopes = {"packed.gather", "packed.score", "packed.sort",
-              "packed.combine_runs", "packed.live_mask", "packed.topk",
+              "packed.combine_runs", "packed.keep", "packed.topk",
               "packed.pack_out"}
 
     def named(lowered) -> set:

@@ -860,6 +860,19 @@ class NodeService:
                                        (stay.end_ns - tns0) / 1e6, compiles0)
                     return out
 
+        # a dashboard panel (`plan.panel`, search/aggs/panels.py): `size: 0`,
+        # a range and one of the lane's three shapes. Alone, behind a
+        # leader or after a follower's wait ran out it runs the lane's
+        # closed set of programs; None only where the index's segments are
+        # not the lane's, and the body then keeps the path below.
+        if plan.panel is not None:
+            out = self._serve_panel(names[0], body, plan.panel, tns0,
+                                    compiles0)
+            if out is not None:
+                if cache_key is not None:
+                    self.caches.request_cache.put(cache_key, names, out)
+                return out
+
         # coalesced general lane (serving/batcher.py, ISSUE 9): bodies the
         # packed kernel can't serve but the batched executor can (plan-
         # shaped queries, aggs, knn, rescore) coalesce behind a leader.
@@ -869,8 +882,12 @@ class NodeService:
         # served as ONE Q>1 batched program riding the stacked/blockwise/
         # mesh replica axis, bitwise-identical to solo execution
         # (tests/test_qos.py parity matrix). Cacheable bodies skip the
-        # lane so the request cache keeps filling.
-        if (len(names) == 1 and cache_key is None
+        # lane so the request cache keeps filling. A body WITH a packed
+        # spec never comes here: when its stay in the packed batcher ended
+        # on None (time-out, strand, view refusal) it is served solo by
+        # the general driver below, so a stalled packed window cannot
+        # reach this lane's programs.
+        if (len(names) == 1 and plan.spec is None and cache_key is None
                 and not body.get("profile") and self.qos.enabled()):
             from .common.metrics import current_profiler as _cur_prof
             bkey = self._msearch_batch_key(names[0], body) \
@@ -893,7 +910,7 @@ class NodeService:
                                        (tracing.now_ns() - tns0) / 1e6,
                                        compiles0)
                     return got
-                # timeout/strand/unservable batch: serve solo below
+                # time-out or strand: serve solo below
         return self._search_general(index, names, body, size, from_, sort,
                                     alias_flt, cache_key, tns0, compiles0)
 
@@ -913,7 +930,8 @@ class NodeService:
                      scrolling: bool) -> "_SearchPlan":
         """Everything `_search_exec` decides before a lane runs: the
         rendered body and page, the indices, the request cache's answer or
-        key, alias filters, the sort and the packed lane's spec."""
+        key, alias filters, the sort, the packed lane's spec and, for a
+        body without one, its row of the panel lane."""
         if "template" in body and "query" not in body:
             # body-level search template (ref RestSearchTemplateAction when
             # the template arrives inside a plain _search body)
@@ -986,15 +1004,17 @@ class NodeService:
         from .search.sort import parse_sort
         sort = parse_sort(body.get("sort"),
                           [self.indices[n].mappers for n in names])
-        spec = None
+        spec = panel = None
         if len(names) == 1:
             from .search.query_parser import QueryParser
             from .serving.executor import packed_spec_of
             spec = packed_spec_of(
                 QueryParser(self.indices[names[0]].mappers), body)
+            if spec is None and size == 0 and from_ == 0:
+                panel = self._panel_row(names[0], body)
         return _SearchPlan(body, size, from_, names=names, sort=sort,
                            alias_flt=alias_flt, cache_key=cache_key,
-                           spec=spec)
+                           spec=spec, panel=panel)
 
     def _search_general(self, index, names, body, size, from_, sort,
                         alias_flt, cache_key, tns0, compiles0):
@@ -2229,14 +2249,133 @@ class NodeService:
         except Exception:  # noqa: BLE001
             return None
 
+    # -- dashboard panels (search/aggs/panels.py) ---------------------------
+
+    _PANEL_KEYS = frozenset({"size", "from", "query", "aggs", "aggregations"})
+
+    def _panel_row(self, name: str, body: dict):
+        """-> the body (its page already known to be `size: 0`, `from: 0`)
+        as a row of the panel lane, or None where it is not one of the
+        lane's three shapes. Read from the body and the mapping alone."""
+        from .search.aggs import panels
+        if not self._PANEL_KEYS.issuperset(body):
+            return None
+        try:
+            aggs = parse_aggs(body.get("aggs") or body.get("aggregations"))
+            node = self._parse_cached(
+                name, body.get("query") or {"match_all": {}})
+        except Exception:  # noqa: BLE001 — the general path reports it
+            return None
+        return panels.row_of(node, aggs)
+
+    def _serve_panel(self, name: str, body: dict, row, tns0: int,
+                     compiles0: int) -> dict | None:
+        """One dashboard panel. It always runs the lane's programs; under
+        QoS it also coalesces (serving/batcher.py): the first LEADS with
+        the Q = 1 programs, panels of its shape that arrive meanwhile are
+        drained as Q > 1 batches, and a follower whose wait ran out or
+        whose leader left runs alone like a leader. None where the lane
+        cannot serve the index's segments (`_search_panels`)."""
+        from .common.device_stats import lane_chosen
+        from .common.metrics import current_profiler
+        if self.qos.enabled() and current_profiler() is None:
+            from .serving.batcher import LEAD
+            bkey = ("panels", name, *row.shape)
+            got = self._batcher.join_batched(bkey, body, row)
+            if got is LEAD:
+                try:
+                    return self._panel_solo(name, body, row, tns0, compiles0)
+                finally:
+                    self._batcher.drain_batched(bkey, name)
+            if got is not None:
+                lane_chosen("serve", "batched")
+                self._served_total(name, body,
+                                   (tracing.now_ns() - tns0) / 1e6, compiles0)
+                return got
+        return self._panel_solo(name, body, row, tns0, compiles0)
+
+    def _panel_solo(self, name: str, body: dict, row, tns0: int,
+                    compiles0: int) -> dict | None:
+        """One panel alone (a leader, a follower whose wait ran out, or no
+        coalescing at all): the lane's Q = 1 programs."""
+        from .common.device_stats import lane_chosen
+        outs = self._search_panels(name, [row], tns0)
+        if outs is None:
+            return None
+        lane_chosen("serve", "panels")
+        self._served_total(name, body, (tracing.now_ns() - tns0) / 1e6,
+                           compiles0)
+        return outs[0]
+
+    def _search_panels(self, name: str, rows: list,
+                       t0_ns: int) -> list[dict] | None:
+        """Answer rows of one shape (`_panel_row`) from the panel lane's
+        programs over the index's segments as they stand, in batches of
+        `SearchBatcher.MAX_BATCH` at most: exact totals and bucket counts,
+        merged and rendered by the aggregation framework. None where the
+        segments are not the lane's (`panels.servable`: a column that is
+        not i64, more distinct values than `terms` counts): the caller
+        keeps the path it had. A program that raises is the members'
+        error."""
+        from .search.aggs import panels
+        svc = self.indices[name]
+        shards = [list(s.segments) for s in svc.searchers()]
+        segments = [seg for segs in shards for seg in segs]
+        if not panels.servable(rows, segments):
+            return None
+        panels.ensure_warm(segments)
+        outs: list[dict] = []
+        step = self._batcher.MAX_BATCH
+        for lo in range(0, len(rows), step):
+            chunk = rows[lo:lo + step]
+            totals, partials = panels.execute(chunk, shards)
+            render = tracing.span("aggs.render", rows=len(chunk))
+            with render:
+                took = (render.start_ns - t0_ns) // 1_000_000
+                for qi, row in enumerate(chunk):
+                    out = {"took": took, "timed_out": False,
+                           "_shards": {"total": len(shards),
+                                       "successful": len(shards),
+                                       "failed": 0},
+                           "hits": {"total": int(totals[qi].sum()),
+                                    "max_score": None, "hits": []}}
+                    if row.agg is not None:
+                        out["aggregations"] = render_aggs(
+                            [row.agg], merge_shard_partials(
+                                [row.agg], partials[qi]))
+                    outs.append(out)
+        # counted after the answers are whole, as the other fast lanes do
+        self.meters["search"].mark(len(rows))
+        svc.query_total += len(rows)
+        svc.search_stats["panels"] = \
+            svc.search_stats.get("panels", 0) + len(rows)
+        svc.meters["search"].mark(len(rows))
+        return outs
+
     def _search_batched(self, metas: list[tuple[str, dict]]) -> list[dict]:
-        """Execute same-shaped requests as one batched query phase per shard;
-        per-row reduce + fetch mirrors the single-search flow."""
+        """Execute same-shaped requests (one `_msearch_batch_key`: an
+        `_msearch` group, or the followers a coalescing leader drains) as
+        one batch. An `_msearch` group of dashboard panels (`_panel_row`:
+        `size: 0`, a range with a `date_histogram`, a `terms` under a
+        one-term `match`, or a bare filtered count) runs the panel lane's
+        compiled programs, as solo panels do (`_serve_panel`, which hands
+        its followers' rows straight to `_search_panels`). Every other
+        group runs one batched query phase per shard as eager operations
+        shaped by the exact Q (knn, rescore, scored pages, other
+        aggregation trees); per-row reduce + fetch mirrors the
+        single-search flow."""
         t0 = time.perf_counter()
         index, first_body = metas[0]
+        names = self._resolve(index)
         size = int(first_body.get("size", 10))
         from_ = int(first_body.get("from", 0))
-        names = self._resolve(index)
+        if len(names) == 1 and size == 0 and from_ == 0:
+            t0_ns = tracing.now_ns()
+            rows = [self._panel_row(names[0], b) for _, b in metas]
+            if None not in rows:
+                outs = self._search_panels(names[0], rows, t0_ns)
+                if outs is not None:
+                    return outs
         searchers: list[ShardSearcher] = []
         index_of: list[str] = []
         for n in names:
@@ -3342,6 +3481,7 @@ class _SearchPlan(NamedTuple):
     alias_flt: dict | None = None
     cache_key: tuple | None = None
     spec: tuple | None = None       # the packed lane's, or None
+    panel: Any = None               # panels.PanelRow of a dashboard panel
 
 
 class _ShardJob:

@@ -19,14 +19,19 @@ Two lanes share the leader/follower core (ISSUE 9):
   * the PACKED lane (`submit`) — packed-spec-eligible bodies ride the
     packed view kernel as before;
   * the COALESCED GENERAL lane (`join_batched`/`drain_batched`) — bodies
-    the packed kernel can't serve but `_search_batched` can (plan-shaped
+    WITHOUT a packed spec that `_search_batched` can serve (plan-shaped
     queries, aggs, knn, rescore) coalesce onto the stacked/blockwise/mesh
     Q>1 replica axis. The first request LEADS by running the ordinary
     solo path (idle-path latency stays zero and solo responses are
     byte-identical to the pre-QoS engine); requests arriving while it
     runs queue as followers, and the leader drains them as Q>1
     `_search_batched` batches — results bitwise-identical to solo
-    execution (tests/test_qos.py parity matrix).
+    execution (tests/test_qos.py parity matrix). Dashboard panels
+    (search/aggs/panels.py) ride this lane under a key of their own:
+    leader, followers and a follower whose wait ran out all run the
+    panel lane's closed set of programs (Q buckets 1 | 4 | 32). A body
+    with a packed spec never joins: when its packed stay returns None the
+    node serves it solo through the general driver.
 
 Followers wait under a DEADLINE-AWARE timeout (QosController.
 follower_wait_s — a multiple of the EWMA device latency, never the old
@@ -59,7 +64,7 @@ class _Entry:
 
     def __init__(self, body, spec, t0: int | None = None):
         self.body = body
-        self.spec = spec
+        self.spec = spec         # packed lane: its spec; a panel: its row
         self.event = threading.Event()
         self.out = None          # response dict, or None -> general path
         self.err = None
@@ -230,28 +235,34 @@ class SearchBatcher:
 
     # -- the coalesced general lane (ISSUE 9) ------------------------------
 
-    def join_batched(self, key: tuple, body: dict):
-        """The coalesced general lane's entry point. Returns the LEAD
-        sentinel when the caller acquired leadership — it must execute
-        the ordinary solo path for itself and call `drain_batched(key,
-        index)` when done (a try/finally at the call site). Otherwise the
-        caller is a follower: blocks until the leader serves it and
-        returns the response dict, or None when it must fall to the
-        general path (timeout / strand / unservable batch)."""
+    def join_batched(self, key: tuple, body: dict, row=None):
+        """The coalesced general lane's entry point (bodies without a
+        packed spec only: `NodeService._search_exec`; `row` is a dashboard
+        panel's `PanelRow`, which its batch runs in the body's place).
+        Returns the LEAD sentinel when the caller acquired leadership — it
+        must execute its solo path for itself (the general driver, or a
+        panel's Q = 1 program) and call `drain_batched(key, index)` when
+        done (a try/finally at the call site). Otherwise the caller is a
+        follower: blocks until the leader serves it and returns the
+        response dict, or None when its wait ran out, the leader left it
+        stranded or the panel lane could not serve its batch; the caller
+        then serves it solo the same way. A batch that raises is re-raised
+        here: it is the follower's error."""
         key = ("gen", *key)
         with self._lock:
             if key not in self._busy:
                 self._busy.add(key)
                 return LEAD
-            e = _Entry(body, None)
+            e = _Entry(body, row)
             self._queues.setdefault(key, []).append(e)
         return self._wait(e)
 
     def drain_batched(self, key: tuple, index: str) -> None:
         """Leader epilogue: serve every follower that queued behind this
-        leader's solo execution as Q>1 `_search_batched` batches, then
-        release leadership. Never raises — a failing batch is its
-        members' error (each follower re-raises it)."""
+        leader's solo execution as Q>1 batches (`_search_batched`; panels'
+        rows through `_search_panels`), then release leadership. Never
+        raises — a failing batch is its members' error (each follower
+        re-raises it)."""
         key = ("gen", *key)
         try:
             while True:
@@ -271,8 +282,12 @@ class SearchBatcher:
     def _run_batched(self, index: str, batch: list[_Entry]) -> None:
         self._take(batch)
         try:
-            outs = self.node._search_batched(
-                [(index, x.body) for x in batch])
+            if batch[0].spec is not None:    # a key of panels: their rows
+                outs = self.node._search_panels(
+                    index, [x.spec for x in batch], batch[0].t_taken)
+            else:
+                outs = self.node._search_batched(
+                    [(index, x.body) for x in batch])
         except Exception as ex:  # noqa: BLE001 — every member's error
             self._record_error(ex)
             for x in batch:
@@ -280,8 +295,8 @@ class SearchBatcher:
                 x.event.set()
             return
         self._book(batch)
-        for x, out in zip(batch, outs):
-            x.out = out
+        for i, x in enumerate(batch):
+            x.out = None if outs is None else outs[i]
             x.event.set()
 
     # -- accounting --------------------------------------------------------

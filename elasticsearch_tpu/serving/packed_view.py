@@ -613,7 +613,9 @@ class PackedIndexView:
         # one avoided compile shape saves seconds of cold p99
         R = next_pow2(max_terms, floor=4)
         if not qi_l:
-            S = 4
+            # no term of the batch is in the index: the batch's floor of S
+            # (as below), not a shape of its own that nothing warms
+            S = 32 if Q_pad <= 32 else 4
             packed = np.zeros((Q_pad, 3 * S + 1), np.int32)
             packed[:, 3 * S] = min_match
             return packed, S, R

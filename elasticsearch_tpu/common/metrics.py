@@ -317,6 +317,23 @@ def bulk_docs_histogram() -> dict[int, int]:
         return dict(_BULK_DOCS_HIST)
 
 
+# the packed lane's dispatches by the form of the program's slot gather
+# (ops/bm25_sparse.packed_gather_form): es_packed_gather_dispatches_total
+# {form=}. A run on the chip that fell back to "sliced" shows here.
+_PACKED_GATHER = {"blocked": 0, "sliced": 0}
+
+
+def record_packed_gather(form: str) -> None:
+    with _DEVICE_LOCK:
+        _PACKED_GATHER[form] += 1
+
+
+def packed_gather_snapshot() -> dict:
+    with _DEVICE_LOCK:
+        return {form: {"dispatches_total": n}
+                for form, n in _PACKED_GATHER.items()}
+
+
 def transfer_snapshot() -> dict:
     """Process-wide host↔device transfer counters (every device_fetch /
     note_h2d call accounts here, profiler active or not) — the scrape's

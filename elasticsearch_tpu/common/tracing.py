@@ -242,10 +242,11 @@ class _ThreadState:
     request reached it or it last dispatched a program (whichever is later:
     nothing older can lie in a gap this thread ends)."""
 
-    __slots__ = ("stack", "trail", "request_start_ns")
+    __slots__ = ("stack", "trail", "request_start_ns", "program_attrs")
 
     def __init__(self):
         self.stack: list[_SpanCtx] = []
+        self.program_attrs: dict = {}   # see program_attrs()
         # (name, start_ns, end_ns); bounded: a thread that never dispatches
         # only ever loses attribution it would not have been asked for
         self.trail: deque = deque(maxlen=64)
@@ -465,7 +466,22 @@ class _FlightCtx(_SpanCtx):
 def flight(site: str) -> _SpanCtx:
     """The `program` span: one blocking device dispatch of the program at
     `site`, dispatch to ready. Also a flight of the gap ledger."""
-    return _FlightCtx("program", None, {"site": site})
+    return _FlightCtx("program", None,
+                      {"site": site, **_thread_state().program_attrs})
+
+
+@contextlib.contextmanager
+def program_attrs(**attrs):
+    """Attributes for the `program` spans this thread opens inside the
+    block: what the caller of an instrumented program knows about the
+    dispatch and the wrapper cannot."""
+    st = _thread_state()
+    outer = st.program_attrs
+    st.program_attrs = {**outer, **attrs}
+    try:
+        yield
+    finally:
+        st.program_attrs = outer
 
 
 def _tree_span(name: str, start_ns: int, end_ns: int, attrs: dict) -> None:

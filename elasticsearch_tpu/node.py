@@ -3082,7 +3082,9 @@ class NodeService:
         adding one entry here — labeled registries (pools, breakers,
         timers, indices) pick up new entries automatically."""
         from .common import device_stats, monitor
-        from .common.metrics import device_events_snapshot, transfer_snapshot
+        from .common.metrics import (device_events_snapshot,
+                                     packed_gather_snapshot,
+                                     transfer_snapshot)
         batcher = self._batcher.stats()
         occupancy = batcher.pop("occupancy", {})
         per_index = {}
@@ -3284,6 +3286,9 @@ class NodeService:
             "search_lane": (("lane", "reason"),
                             device_stats.lane_decision_metrics()),
             "transfer": (None, transfer_snapshot()),
+            # es_packed_gather_dispatches_total{form=}: packed dispatches
+            # by how the program copied its slots (blocked | sliced)
+            "packed_gather": ("form", packed_gather_snapshot()),
             "tasks": (None, self.tasks.stats()),
             # span tracer: started/retained/sampled-out trace counters,
             # ring-eviction + span-cap drop counters, live gauges

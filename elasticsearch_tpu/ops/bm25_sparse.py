@@ -1,10 +1,10 @@
-"""Sort-reduce BM25 top-k: the gather/scatter-free TPU hot kernel.
+"""Sort-reduce BM25 top-k: the scatter-free TPU hot kernel.
 
 Why not the dense formulation (ops/bm25.py)? On TPU, arbitrary gathers
 (doc_ids[idx]) and scatter-adds into a [Q, N] score matrix serialize into
 dynamic-slice loops — measured ~25x slower than this kernel at 1M docs.
-This kernel touches postings ONLY through contiguous `dynamic_slice` DMAs
-and never materializes per-doc state:
+This kernel reads postings ONLY as contiguous blocks (a term's block, or a
+fixed-size slot of it) and never materializes per-doc state:
 
   1. slice    — each (query, term) loads its postings block [Wt] with three
                 contiguous slices (doc ids, tf, per-posting dl). Per-posting
@@ -26,6 +26,15 @@ and never materializes per-doc state:
                 (SURVEY.md §5.7), with doc-id-ascending tie-break like
                 Lucene's priority queue.
 
+What the trace showed of step 1 (PERF.md §5-§6, PR 25 and PR 28): a block
+read written as `vmap(dynamic_slice)` is no gather to XLA, but the TPU runs
+it as one `while` loop a stream with one iteration a block, each copy
+waiting for the one before — 2 us a block, 75-79 % of the packed program.
+The packed program (`bm25_serve_packed`) therefore copies its slots with
+ops/slot_gather.py on the TPU: one Pallas kernel, a block of 32 slots x 3
+streams of DMAs in flight at once. `_sorted_runs` below (the sparse lane)
+still has the `vmap(dynamic_slice)` form, with Wt in CHUNK's place.
+
 The per-term slot budget Wt is a static pow2 bucket >= the largest df among
 the query batch's terms; compile cache stays small, padding is masked.
 """
@@ -36,6 +45,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
+
+from .slot_gather import gather_slots
 
 # The packed lane's one sentinel doc id (bm25_serve_packed's docstring says
 # why it is a constant): padding of `doc_ids`, the unused lanes of a slot, and
@@ -175,7 +186,7 @@ def bm25_serve_packed_filtered(packed_q: jax.Array, doc_ids: jax.Array,
     """
     return _serve_packed_impl(
         packed_q, doc_ids, tf, dl, k1, b, avgdl, const,
-        S=S, CHUNK=CHUNK, R=R, k=k,
+        S=S, CHUNK=CHUNK, R=R, k=k, gather=packed_gather_form(),
         filters=(fcols, fr_col, fr_lo, fr_hi, fr_neg,
                  ft_col, ft_targets, ft_neg, FR, FT, TV))
 
@@ -225,6 +236,12 @@ def bm25_serve_packed(packed_q: jax.Array, doc_ids: jax.Array, tf: jax.Array,
     R: max distinct query terms — the run-length bound of the windowed
         segment-sum. A doc appears at most once per term (chunks of one term
         are disjoint doc ranges), so runs are <= R regardless of S.
+    The slots' postings are copied (`packed.gather`) by the blocked kernel
+        of ops/slot_gather.py on the TPU and by `vmap(dynamic_slice)` on
+        any other backend (`packed_gather_form`): the same [Q, S, CHUNK]
+        blocks bit for bit, so the same answer. On the chip the sliced form
+        was three serial loops of Q x S copies, 386 of the 517 ms of a
+        256 x 256-slot program; the kernel takes 21 ms there (PERF.md §6).
 
     Returns ONE i32[Q, 2k+1]: [scores f32-bitcast | top docs | total_hits]
     — a single D2H transfer; host splits and bitcasts back.
@@ -235,11 +252,20 @@ def bm25_serve_packed(packed_q: jax.Array, doc_ids: jax.Array, tf: jax.Array,
     """
     return _serve_packed_impl(packed_q, doc_ids, tf, dl,
                               k1, b, avgdl, const,
-                              S=S, CHUNK=CHUNK, R=R, k=k, filters=None)
+                              S=S, CHUNK=CHUNK, R=R, k=k, filters=None,
+                              gather=packed_gather_form())
 
 
-def _serve_packed_impl(packed_q, doc_ids, tf, dl,
-                       k1, b, avgdl, const, *, S, CHUNK, R, k, filters):
+def packed_gather_form() -> str:
+    """How the packed program copies its slots' postings on this backend:
+    "blocked" (ops/slot_gather.py, the Pallas kernel) on the TPU, "sliced"
+    (`vmap(dynamic_slice)`) elsewhere. The backend is all that chooses: no
+    setting does. `PackedIndexView.search` counts its dispatches by it."""
+    return "blocked" if jax.default_backend() == "tpu" else "sliced"
+
+
+def _serve_packed_impl(packed_q, doc_ids, tf, dl, k1, b, avgdl, const, *,
+                       S, CHUNK, R, k, filters, gather):
     # each phase is a `jax.named_scope`: metadata only (same program, same
     # outputs), so a profile's operations group under stable names
     Q = packed_q.shape[0]
@@ -250,15 +276,18 @@ def _serve_packed_impl(packed_q, doc_ids, tf, dl,
     min_match = packed_q[:, 3 * S]
     PAD = jnp.int32(PACKED_PAD_DOC)
 
-    def slice_slot(s, ln):
-        d = jax.lax.dynamic_slice(doc_ids, (s,), (CHUNK,))
-        t = jax.lax.dynamic_slice(tf, (s,), (CHUNK,))
-        l = jax.lax.dynamic_slice(dl, (s,), (CHUNK,))
-        valid = jnp.arange(CHUNK, dtype=jnp.int32) < ln
-        return jnp.where(valid, d, PAD), t, l, valid
-
     with jax.named_scope("packed.gather"):
-        d, t, l, valid = jax.vmap(jax.vmap(slice_slot))(starts, lens)
+        # a copy either way: the same [Q, S, CHUNK] blocks, bit for bit
+        if gather == "blocked":
+            d, t, l = (x.reshape(Q, S, CHUNK) for x in gather_slots(
+                starts.reshape(-1), (doc_ids, tf, dl), chunk=CHUNK,
+                interpret=jax.default_backend() != "tpu"))
+        else:
+            d, t, l = jax.vmap(jax.vmap(lambda s: tuple(
+                jax.lax.dynamic_slice(x, (s,), (CHUNK,))
+                for x in (doc_ids, tf, dl))))(starts)
+        valid = jnp.arange(CHUNK, dtype=jnp.int32) < lens[:, :, None]
+        d = jnp.where(valid, d, PAD)
 
     W = S * CHUNK
     with jax.named_scope("packed.score"):

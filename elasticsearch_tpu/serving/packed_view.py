@@ -39,11 +39,12 @@ import jax
 import jax.numpy as jnp
 
 from ..common import tracing
-from ..common.metrics import device_fetch, note_h2d
+from ..common.metrics import device_fetch, note_h2d, record_packed_gather
 from ..index.segment import Segment, next_pow2
 from ..ops.bm25_sparse import (FOLD_IDS_BLOCK, FOLD_IDS_MAX, PACKED_PAD_DOC,
                                bm25_serve_packed, bm25_serve_packed_filtered,
-                               packed_fold_ids, packed_fold_live)
+                               packed_fold_ids, packed_fold_live,
+                               packed_gather_form)
 
 # Fixed postings chunk: compile-cache keys depend on (Q, S) pow2 buckets only,
 # never on the corpus' df distribution.
@@ -546,7 +547,11 @@ class PackedIndexView:
             prep.attrs["h2d_bytes"] = \
                 sum(a.nbytes for a in host) + 4 * len(scalars)
             note_h2d(prep.attrs["h2d_bytes"])
-        with self._folded_ids(pf) as doc_ids:
+        # the form of the program's slot gather rides its `program` span and
+        # /_metrics: a chip run that fell back to "sliced" shows there
+        form = packed_gather_form()
+        with self._folded_ids(pf) as doc_ids, \
+                tracing.program_attrs(gather=form):
             if stack is not None:
                 out = bm25_serve_packed_filtered(
                     dev[0], doc_ids, pf.tf, pf.dl, *scalars,
@@ -557,6 +562,7 @@ class PackedIndexView:
                     dev[0], doc_ids, pf.tf, pf.dl, *scalars,
                     S=S, CHUNK=CHUNK, R=R, k=k_pad)
         self.device_calls += 1
+        record_packed_gather(form)
         fetch = tracing.span("packed.d2h")
         with fetch:
             arr = device_fetch(out)          # the ONE D2H transfer

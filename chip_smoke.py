@@ -34,7 +34,7 @@ import numpy as np
 DOCS = 1_000_000          # BASELINE.json configs[1]
 SHARDS = 5
 BULK_DOCS = 4_000         # documents per `_bulk` request
-VOCAB = 30_000            # bench.py's corpus shape: Zipf 1.3 over 30k terms,
+VOCAB = 30_000            # the corpus shape: Zipf 1.3 over 30k terms,
 MEAN_TOKENS = 20          # mean 20 tokens per document
 N_STATUS = 20
 Q_BATCH = 256             # bodies per `_msearch`

@@ -150,13 +150,23 @@ class TestCluster:
     def ensure_yellow_or_green(self, timeout: float = 15.0) -> None:
         self._ensure("yellow", timeout)
 
-    def _ensure(self, at_least: str, timeout: float) -> None:
+    def ensure_settled(self, timeout: float = 15.0) -> None:
+        """Green AND still: no copy relocating. A relocation's target is
+        surplus, so a cluster reads green while one streams; the handoff
+        then swaps a copy under whoever compares two searches (the new
+        copy has not seen the last refresh)."""
+        self._ensure("green", timeout, still=True)
+
+    def _ensure(self, at_least: str, timeout: float,
+                still: bool = False) -> None:
         ok = {"green"} if at_least == "green" else {"green", "yellow"}
         deadline = time.monotonic() + timeout
         while time.monotonic() < deadline:
             client = self.client()
             h = client.health()
-            if h["status"] in ok and h["master_node"] is not None:
+            if h["status"] in ok and h["master_node"] is not None \
+                    and not (still and (h["relocating_shards"]
+                                        or h["initializing_shards"])):
                 # every live node must have applied a state at this version
                 # or later with the same master
                 versions = [n.cluster.current().version

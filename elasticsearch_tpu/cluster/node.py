@@ -101,7 +101,7 @@ class _ShardHolder:
         self.engine: Engine | None = None
         self.lock = threading.RLock()
         self.recovering = False
-        self.recovery_aid = None       # allocation id of the in-flight pull
+        self.recovery_aid = None       # allocation id of the latest pull
         self.reinit_pending = False    # a newer era waits for the old pull
         self.cancel_recovery = False   # newer state unassigned this copy
         self.pending: list[dict] = []     # ops buffered during recovery
@@ -458,7 +458,7 @@ class ClusterNode:
             # peer-recovery stream counters (ISSUE 15):
             # es_recovery_bytes_total, es_recovery_throttle_waits_total...
             # process-wide (cluster/recovery.py) — every node scrapes the
-            # same truth the bench's throttle-compliance check reads
+            # same truth the tests' throttle-compliance check reads
             "recovery": (None, dict(_recovery_snapshot())),
             # per-decider allocation vetoes:
             # es_allocation_decider_vetoes_total{decider=}
@@ -1030,6 +1030,20 @@ class ClusterNode:
         # EXISTING local engine is stale by definition — this copy was
         # unassigned (e.g. after a failed replication hop) and must re-sync,
         # or it would come back STARTED while missing acked writes.
+        aid = copy_.get("aid")
+        with holder.lock:
+            pulled = (aid is not None and holder.recovery_aid == aid
+                      and not holder.recovering and holder.engine is not None)
+        if pulled:
+            # THIS era's pull is complete and its engine is the live copy:
+            # a state published before the master took our report still
+            # shows the copy INITIALIZING. Report again (the first report
+            # may be the one that was lost) and pull nothing: a second
+            # pull closes a live engine, and a relocation target's source
+            # is gone once the handoff is published — that pull fails and
+            # leaves a STARTED copy, a primary even, with no engine
+            self._report_started(index, sid, aid)
+            return
         source_node = copy_.get("recover_from")
         if source_node is None:
             primary = state.primary_of(index, sid)
@@ -1037,7 +1051,6 @@ class ClusterNode:
                     or primary["state"] not in (STARTED, RELOCATING):
                 return      # allocator shouldn't have scheduled this; wait
             source_node = primary["node"]
-        aid = copy_.get("aid")
         with holder.lock:
             if holder.recovering:
                 if holder.recovery_aid == aid:

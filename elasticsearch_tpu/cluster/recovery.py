@@ -9,7 +9,7 @@ budget and a relocation wave cannot starve serving traffic of I/O.
 
 Counters live module-level (the qos.record_hedge pattern): one source
 of truth feeding /_metrics (`es_recovery_*`), the sampler ring, and the
-bench's throttle-compliance check, readable from both the cluster
+tests' throttle-compliance check, readable from both the cluster
 ClusterNode and the single-node NodeService without plumbing.
 """
 

@@ -385,7 +385,7 @@ class LocalTransport:
         if target is None:
             raise ConnectTransportException(to_id, action)
         # per-class byte accounting: the recovery class's counter is how
-        # the bench/tests verify throttle compliance on the wire itself
+        # the tests verify throttle compliance on the wire itself
         cls_st = self._class_stats[self._class_for(from_id, to_id, action)]
         wire = json.dumps(_encode(payload))
         with self._lock:

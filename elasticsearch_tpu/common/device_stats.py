@@ -4,9 +4,8 @@ and the lane-decision flight recorder (ISSUE 16).
 Three concerns the serving stack had no eyes on:
 
 **Program registry** — every compiled program dispatched from host code
-(the plan-signature caches in search/blockwise, parallel/mesh_exec and
-parallel/distributed_search, plus the module-level jitted kernels in
-ops/) records invocation count, cumulative dispatch wall time and
+(the plan-signature caches in search/blockwise and parallel/mesh_exec,
+plus the module-level jitted kernels in ops/) records invocation count, cumulative dispatch wall time and
 compile-event attribution. Cost analysis (flops / bytes accessed) is
 computed LAZILY at scrape time by re-lowering against the captured
 argument avals — `Lowered.cost_analysis()` runs no backend compile and
@@ -291,7 +290,7 @@ def hbm_poll() -> dict[str, dict]:
 
 
 def hbm_peak_bytes() -> int:
-    """Max high-water across devices (the bench headline gauge)."""
+    """Max high-water across devices (`_nodes/device_stats`' headline)."""
     polled = hbm_poll()
     return max((v["high_water_bytes"] for v in polled.values()), default=0)
 
@@ -389,7 +388,7 @@ def lane_decline(component: str, lane: str, reason: str) -> None:
 
 
 def lane_decisions_snapshot() -> dict[str, int]:
-    """Flat `lane:reason -> count` view (bench headline / tests)."""
+    """Flat `lane:reason -> count` view (`_nodes/device_stats` / tests)."""
     with _LOCK:
         return {f"{lane}:{reason}": n
                 for (lane, reason), n in sorted(_LANE_DECISIONS.items())}

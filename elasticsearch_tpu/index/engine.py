@@ -154,8 +154,8 @@ class Engine:
     MERGE_SEGMENT_COUNT = 8          # merge trigger (TieredMergePolicy-ish)
     # doc-count refresh trigger (indexing buffer analog) — a backstop; the
     # real bound is the node-wide BYTE budget (check_indexing_memory /
-    # indices.memory.index_buffer_size), so this sits above the 100k-doc
-    # bench tier: one bulk ingest freezes into ONE segment instead of
+    # indices.memory.index_buffer_size), so this sits above a 100k-doc
+    # bulk: one bulk ingest freezes into ONE segment instead of
     # paying a mid-request refresh plus a 2-segment force-merge
     MAX_BUFFER_DOCS = 131072
 

@@ -53,7 +53,7 @@ class IndexService:
         self.mappers.similarity = SimilarityService(self.settings)
         # the vectorized bulk-ingest lane (index/bulk_ingest.py) is on
         # unless the index opts out (`index.bulk.vectorized.enable: false`
-        # — the equivalence suite and bench use it to pin the per-doc path)
+        # — the equivalence suite uses it to pin the per-doc path)
         raw_vec = get("bulk.vectorized.enable", True)
         self._bulk_vectorized = str(raw_vec).strip().lower() \
             not in ("false", "0", "no")
@@ -73,15 +73,15 @@ class IndexService:
         self.search_stats = {"sparse": 0, "dense": 0, "packed": 0,
                              "stacked": 0, "mesh": 0}
         # the stacked dense lane is on unless the index opts out
-        # (`index.search.stacked.enable: false` — bench uses it to measure
-        # the per-segment loop it replaces)
+        # (`index.search.stacked.enable: false` — the equivalence tests and
+        # the chaos oracle use it to pin the per-segment loop it replaces)
         raw_stacked = get("search.stacked.enable", True)
         self._stacked_enabled = str(raw_stacked).strip().lower() \
             not in ("false", "0", "no")
         # the mesh-sharded query lane (parallel/mesh_exec) engages for
         # multi-shard unsorted queries unless the index opts out
-        # (`index.search.mesh.enable: false` — bench uses it to measure
-        # the thread-pool fan-out it replaces)
+        # (`index.search.mesh.enable: false` — the equivalence tests use it
+        # to pin the thread-pool fan-out it replaces)
         raw_mesh = get("search.mesh.enable", True)
         self._mesh_enabled = str(raw_mesh).strip().lower() \
             not in ("false", "0", "no")
@@ -90,7 +90,7 @@ class IndexService:
         # tree per pow2 doc block under a running on-device top-k — peak
         # score memory O(Q × block) instead of O(Q × n_pad). Opt out with
         # `index.search.blockwise.enable: false` (the equivalence suite and
-        # bench use it to pin the materializing executor).
+        # the chaos oracle use it to pin the materializing executor).
         raw_blk = get("search.blockwise.enable", True)
         self._blockwise_enabled = str(raw_blk).strip().lower() \
             not in ("false", "0", "no")
@@ -203,8 +203,7 @@ class IndexService:
             if op.routing is None and op.parent is not None:
                 op.routing = op.parent  # _parent doubles as routing
         if self.n_shards == 1:
-            # single-shard indices (the bench shape) skip the per-op
-            # routing hash entirely
+            # single-shard indices skip the per-op routing hash entirely
             results = self.shards[0].index_batch(ops, sync=False)
         else:
             by_shard: dict[int, tuple[list[int], list]] = {}

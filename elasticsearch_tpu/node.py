@@ -210,7 +210,7 @@ class NodeService:
         from .common.watcher import ResourceWatcherService
         self.watcher = ResourceWatcherService()
         from .serving.batcher import SearchBatcher
-        self._batcher = SearchBatcher(self)
+        self._batcher = SearchBatcher(self.qos, self.metrics)
         tpl_path = os.path.join(data_path, "_templates.json")
         if os.path.exists(tpl_path):
             import json
@@ -837,7 +837,7 @@ class NodeService:
                 lane_decline("serve", "packed", "plan_shape")
             else:
                 spec = plan.spec
-                key = (names[0], size, from_, spec[1], spec[2], spec[3])
+                key = ("packed", names[0], size, from_, *spec[1:4])
                 stay = tracing.span("packed_batch", index=names[0])
                 with stay:
                     # queue wait + the shared device program of the
@@ -845,8 +845,12 @@ class NodeService:
                     # covers this request's whole stay in the lane. A
                     # program that raises is this request's error — a
                     # device failure is never served by a slower lane.
-                    out = self._batcher.submit(key, names[0], body,
-                                               spec, size, from_, tns0)
+                    out, _ = self._batcher.coalesce(
+                        key, (body, spec, tns0),
+                        lambda items, _t: self._packed_search(
+                            names[0], [b for b, _, _ in items], size=size,
+                            from_=from_, t0=[t for _, _, t in items],
+                            specs=[sp for _, sp, _ in items]))
                 if out is None:
                     lane_decline("serve", "packed", "batcher_declined")
                 else:
@@ -873,46 +877,44 @@ class NodeService:
                     self.caches.request_cache.put(cache_key, names, out)
                 return out
 
-        # coalesced general lane (serving/batcher.py, ISSUE 9): bodies the
-        # packed kernel can't serve but the batched executor can (plan-
-        # shaped queries, aggs, knn, rescore) coalesce behind a leader.
-        # The LEADER runs the ordinary solo path below — idle-path latency
-        # and solo responses are exactly the pre-QoS engine's — while
-        # requests arriving during its run queue as followers and are
-        # served as ONE Q>1 batched program riding the stacked/blockwise/
-        # mesh replica axis, bitwise-identical to solo execution
-        # (tests/test_qos.py parity matrix). Cacheable bodies skip the
-        # lane so the request cache keeps filling. A body WITH a packed
-        # spec never comes here: when its stay in the packed batcher ended
-        # on None (time-out, strand, view refusal) it is served solo by
-        # the general driver below, so a stalled packed window cannot
-        # reach this lane's programs.
+        # coalesced general lane (serving/batcher.py): bodies the packed
+        # kernel can't serve but the batched executor can (plan-shaped
+        # queries, aggs, knn, rescore). The leader, and a follower left
+        # without an answer, run the ordinary solo path; followers are
+        # served as ONE Q>1 batched program, bitwise-identical to solo
+        # execution (tests/test_qos.py parity matrix). Cacheable bodies
+        # skip the lane so the request cache keeps filling. A body WITH a
+        # packed spec never comes here: when its stay in the packed
+        # batcher ended on None (time-out, strand, view refusal) it is
+        # served solo below, so a stalled packed window cannot reach this
+        # lane's programs.
         if (len(names) == 1 and plan.spec is None and cache_key is None
                 and not body.get("profile") and self.qos.enabled()):
             from .common.metrics import current_profiler as _cur_prof
             bkey = self._msearch_batch_key(names[0], body) \
                 if _cur_prof() is None else None
             if bkey is not None:
-                from .serving.batcher import LEAD
-                got = self._batcher.join_batched(bkey, body)
-                if got is LEAD:
-                    try:
-                        return self._search_general(
-                            index, names, body, size, from_, sort,
-                            alias_flt, cache_key, tns0, compiles0)
-                    finally:
-                        self._batcher.drain_batched(bkey, names[0])
-                if got is not None:
-                    # follower served from the shared batch: only TOTAL is
-                    # honest (wall time includes queue wait + shared work)
-                    lane_chosen("serve", "batched")
-                    self._served_total(names[0], body,
-                                       (tracing.now_ns() - tns0) / 1e6,
-                                       compiles0)
-                    return got
-                # time-out or strand: serve solo below
+                out, shared = self._batcher.coalesce(
+                    ("gen", *bkey), body,
+                    lambda items, _t: self._search_batched(
+                        [(names[0], b) for b in items]),
+                    lead=lambda: self._search_general(
+                        index, names, body, size, from_, sort, alias_flt,
+                        cache_key, tns0, compiles0))
+                if shared:
+                    self._served_shared(names[0], body, tns0, compiles0)
+                return out
         return self._search_general(index, names, body, size, from_, sort,
                                     alias_flt, cache_key, tns0, compiles0)
+
+    def _served_shared(self, name: str, body: dict, tns0: int,
+                       compiles0: int) -> None:
+        """A follower was served from a shared batch: only TOTAL is honest
+        (its wall time includes queue wait and shared work)."""
+        from .common.device_stats import lane_chosen
+        lane_chosen("serve", "batched")
+        self._served_total(name, body, (tracing.now_ns() - tns0) / 1e6,
+                           compiles0)
 
     def _served_total(self, name: str, body: dict, took_ms: float,
                       compiles0: int) -> None:
@@ -1084,7 +1086,7 @@ class NodeService:
             knn_exact = bool(knn.get("exact", False))
             # per-request quantization override (ISSUE 12): pin the int8/
             # pq scan or force the f32 IVF lane regardless of the index
-            # default — the bench measures all three on ONE index this way
+            # default — one index can be measured all three ways
             knn_quant = knn.get("quantization")
             if knn_quant is not None and str(knn_quant).strip().lower() \
                     not in ("none", "int8", "pq"):
@@ -2276,22 +2278,17 @@ class NodeService:
         drained as Q > 1 batches, and a follower whose wait ran out or
         whose leader left runs alone like a leader. None where the lane
         cannot serve the index's segments (`_search_panels`)."""
-        from .common.device_stats import lane_chosen
         from .common.metrics import current_profiler
         if self.qos.enabled() and current_profiler() is None:
-            from .serving.batcher import LEAD
-            bkey = ("panels", name, *row.shape)
-            got = self._batcher.join_batched(bkey, body, row)
-            if got is LEAD:
-                try:
-                    return self._panel_solo(name, body, row, tns0, compiles0)
-                finally:
-                    self._batcher.drain_batched(bkey, name)
-            if got is not None:
-                lane_chosen("serve", "batched")
-                self._served_total(name, body,
-                                   (tracing.now_ns() - tns0) / 1e6, compiles0)
-                return got
+            out, shared = self._batcher.coalesce(
+                ("gen", "panels", name, *row.shape), row,
+                lambda rows, t_taken: self._search_panels(name, rows,
+                                                          t_taken),
+                lead=lambda: self._panel_solo(name, body, row, tns0,
+                                              compiles0))
+            if shared:
+                self._served_shared(name, body, tns0, compiles0)
+            return out
         return self._panel_solo(name, body, row, tns0, compiles0)
 
     def _panel_solo(self, name: str, body: dict, row, tns0: int,

@@ -5,7 +5,7 @@ ref: /root/reference/src/main/java/org/elasticsearch/search/controller/SearchPha
 (coordinator-side merge of per-shard top-k) — here both the per-segment top-k
 and the cross-segment/cross-shard merge are `lax.top_k` programs so they can
 run on device and, across chips, over ICI collectives
-(see parallel/distributed_search.py).
+(see parallel/mesh_exec.py).
 """
 
 from __future__ import annotations

@@ -212,12 +212,3 @@ def index_sharding(mesh: Mesh) -> NamedSharding:
     """Index tensors: leading shard axis split over "shard", replicated over
     "replica" (every replica group holds a full copy — the R-copies model)."""
     return NamedSharding(mesh, P(SHARD_AXIS))
-
-
-def query_sharding(mesh: Mesh) -> NamedSharding:
-    """Per-shard query tensors [S, Q, ...]: S over "shard", Q over "replica"."""
-    return NamedSharding(mesh, P(SHARD_AXIS, REPLICA_AXIS))
-
-
-def replicated(mesh: Mesh) -> NamedSharding:
-    return NamedSharding(mesh, P())

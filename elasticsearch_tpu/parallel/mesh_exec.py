@@ -6,10 +6,9 @@ and one fetch, but the coordinator still merged per-shard results in host
 Python over the thread-pool fan-out — S device fetches and a host-side
 sort per multi-shard query. This module packs the shards' segment stacks
 one level up onto a `[S_pad, G_pad, N_pad, ...]` mesh stack sharded over
-the `"shard"` axis (parallel/mesh.index_sharding), generalizes the
-shard_map query step of parallel/distributed_search.py from BM25-only to
-the stacked DSL executor of search/stacked.py, and fuses the cross-shard
-reduce on device:
+the `"shard"` axis (parallel/mesh.index_sharding), runs the stacked DSL
+executor of search/stacked.py as the shard_map query step, and fuses the
+cross-shard reduce on device:
 
     per-shard stacked execution  (exactly search/stacked.py's math, per
                                   device block — bitwise-equal scores)
@@ -85,7 +84,7 @@ _MESH_MEMO: dict[tuple[int, int], jax.sharding.Mesh] = {}
 # EXEC_LOCK is the legacy lock of the SHARED pool (all of jax.devices())
 # — the fallback when no ownership is configured. All dispatch sites go
 # through exec_guard() below, which also counts acquisitions/waits per
-# path (the bench's exec_lock_waits + the no-retrace tripwire).
+# path (the sampler ring's `exec_lock_waits` + the no-retrace tripwire).
 EXEC_LOCK = SHARED_EXEC_LOCK
 
 _EXEC_STATS_LOCK = threading.Lock()
@@ -130,8 +129,8 @@ def _mesh_devkey(mesh) -> tuple:
     nodes with different device subsets must never share a program."""
     return tuple(int(d.id) for d in mesh.devices.flat)
 
-# compiled shard_map programs keyed by plan signature — the jit analog of
-# DistributedSearcher's step memo, bounded on the common Cache core
+# compiled shard_map programs keyed by plan signature, bounded on the
+# common Cache core
 _PROGRAMS = Cache("mesh_programs", max_entries=256)
 
 # score-materialization mode of the LAST mesh execution: "blockwise"

@@ -4,8 +4,8 @@ Analog of /root/reference/src/main/java/org/elasticsearch/search/controller/
 SearchPhaseController.java — sortDocs (:147,233) merges per-shard top-k,
 merge (:282-399) combines hits + aggregation reduce into the final response.
 
-On a packed mesh the same reduce runs on-device as collectives
-(parallel/distributed_search.py); this host-side controller serves the
+On the mesh lane the same reduce runs on-device as collectives
+(parallel/mesh_exec.py); this host-side controller serves the
 engine-per-shard path (local multi-shard node, and later the DCN
 coordinator between pods).
 """

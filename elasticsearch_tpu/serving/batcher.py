@@ -1,5 +1,5 @@
 """Dynamic request batcher: concurrent solo `_search` requests coalesce
-into ONE packed device program.
+into ONE device program.
 
 The reference gets its QPS from thread-pool concurrency (one Lucene search
 per thread, search/SearchService + the SEARCH thread pool); a TPU gets it
@@ -14,31 +14,27 @@ takes the whole accumulated queue as the next batch. Under load, batch
 size self-tunes to (arrival rate x device latency) — exactly the dynamic
 batching window, without a sleep on the idle path.
 
-Two lanes share the leader/follower core (ISSUE 9):
+One loop serves every lane, and it knows none of them (`coalesce`): the
+caller hands it a key, its own `item` (opaque here) and `run`, the lane's
+batch runner. What differs between lanes is who the leader is:
 
-  * the PACKED lane (`submit`) — packed-spec-eligible bodies ride the
-    packed view kernel as before;
-  * the COALESCED GENERAL lane (`join_batched`/`drain_batched`) — bodies
-    WITHOUT a packed spec that `_search_batched` can serve (plan-shaped
-    queries, aggs, knn, rescore) coalesce onto the stacked/blockwise/mesh
-    Q>1 replica axis. The first request LEADS by running the ordinary
-    solo path (idle-path latency stays zero and solo responses are
-    byte-identical to the pre-QoS engine); requests arriving while it
-    runs queue as followers, and the leader drains them as Q>1
-    `_search_batched` batches — results bitwise-identical to solo
-    execution (tests/test_qos.py parity matrix). Dashboard panels
-    (search/aggs/panels.py) ride this lane under a key of their own:
-    leader, followers and a follower whose wait ran out all run the
-    panel lane's closed set of programs (Q buckets 1 | 4 | 32). A body
-    with a packed spec never joins: when its packed stay returns None the
-    node serves it solo through the general driver.
+  * without `lead` the leader is a MEMBER of the first batch it runs (the
+    node's packed lane: packed-spec-eligible bodies ride the packed view
+    kernel, the leader's own among them);
+  * with `lead` the leader holds no entry: it answers itself with
+    `lead()` (the node's general driver, or a dashboard panel's Q = 1
+    programs — idle-path latency stays zero and solo responses are
+    byte-identical to the pre-QoS engine) while requests of its key queue
+    behind it, then drains them through `run` as Q > 1 batches — results
+    bitwise-identical to solo execution (tests/test_qos.py parity
+    matrix). A follower that gets no answer (its wait ran out, its leader
+    left it, `run` gave None) answers itself with `lead()` too.
 
 Followers wait under a DEADLINE-AWARE timeout (QosController.
-follower_wait_s — a multiple of the EWMA device latency, never the old
-silent hard-coded 30 s); timeouts and leader-exit strandings are counted
-and surfaced on `/_metrics` (`es_search_batcher_wait_timeouts_total`,
-`es_search_batcher_stranded_total`), and batch-execution errors are
-recorded (`run_errors_total` + `last_error`), not discarded.
+follower_wait_s — a multiple of the EWMA device latency); timeouts and
+leader-exit strandings are counted and surfaced on `/_metrics`
+(`es_search_batcher_wait_timeouts_total`, `..._stranded_total`), and
+batch-execution errors are recorded (`run_errors_total` + `last_error`).
 
 ref: the role of org.elasticsearch.threadpool.ThreadPool's SEARCH pool —
 but the unit of concurrency is a device batch, not a thread.
@@ -53,36 +49,34 @@ from ..common import tracing
 
 logger = logging.getLogger("elasticsearch_tpu.serving.batcher")
 
-#: sentinel returned by `join_batched` when the caller holds leadership —
-#: it must run the solo path itself, then call `drain_batched`.
-LEAD = object()
-
 
 class _Entry:
-    __slots__ = ("body", "spec", "event", "out", "err", "t0", "t_submit",
-                 "t_taken", "abandoned")
+    __slots__ = ("item", "event", "out", "err", "t_submit", "t_taken",
+                 "abandoned")
 
-    def __init__(self, body, spec, t0: int | None = None):
-        self.body = body
-        self.spec = spec         # packed lane: its spec; a panel: its row
+    def __init__(self, item):
+        self.item = item         # the caller's own; `run` gets it back
         self.event = threading.Event()
-        self.out = None          # response dict, or None -> general path
+        self.out = None          # its slot of `run`'s answer, or None
         self.err = None
-        self.t0 = t0             # ns: the request's own start, for `took`
         self.t_submit = tracing.now_ns()
         self.t_taken = None      # ns: a batch took the entry off the queue
         self.abandoned = False   # follower timed out; don't spend a row
 
 
 class SearchBatcher:
-    """Per-node coalescer for packed-eligible solo searches."""
+    """Per-node coalescer of concurrent solo searches."""
 
     MAX_BATCH = 32               # one device batch == one warm Q bucket
 
     _log_budget = 10             # rate-limited anomaly logging (per class)
 
-    def __init__(self, node):
-        self.node = node
+    def __init__(self, qos, metrics):
+        # `qos.batch_window`: MAX_BATCH when healthy, shrunk under degrade
+        # pressure (smaller batches = lower per-batch latency) before any
+        # request sheds; `qos.follower_wait_s`; `metrics.record`
+        self.qos = qos
+        self.metrics = metrics
         self._lock = threading.Lock()
         self._queues: dict[tuple, list[_Entry]] = {}
         self._busy: set[tuple] = set()
@@ -93,80 +87,112 @@ class SearchBatcher:
         # (occupancy 1 = no coalescing happened; near MAX_BATCH = the
         # arrival rate saturates the device latency window)
         self.occupancy: dict[int, int] = {}
-        # ISSUE 9 satellite: the silent failure paths are now counted —
-        # stranded followers (leader exited with entries still queued),
-        # follower wait timeouts (the old hard 30 s fell through with no
-        # signal), and batch-execution errors (the swallowed `ex`)
+        # the failure paths are counted, not silent: stranded followers
+        # (leader exited with entries still queued), follower wait
+        # timeouts, batch-execution errors
         self.stranded = 0
         self.wait_timeouts = 0
         self.run_errors = 0
         self.last_error: str | None = None
 
-    # -- shared plumbing ---------------------------------------------------
+    def coalesce(self, key: tuple, item, run, lead=None):
+        """Answer one request of the compatibility group `key`, alone or
+        in a batch with the requests that queue while the group's leader
+        is busy. -> (answer, shared): `shared` says the answer is a slot
+        of a `run` batch (the caller books it as such; `lead()` books its
+        own).
 
-    def _window(self) -> int:
-        """Coalescing window: MAX_BATCH when healthy; the QoS controller
-        shrinks it under degrade pressure (smaller batches = lower
-        per-batch latency) before any request sheds."""
-        qos = getattr(self.node, "qos", None)
-        if qos is not None:
-            return qos.batch_window(self.MAX_BATCH)
-        return self.MAX_BATCH
+        `run(items, t_taken) -> list | None` answers a batch of queued
+        items, taken off the queue at `t_taken` (ns), one answer each in
+        their order. None is None for every member; an exception is every
+        member's exception, re-raised in each.
 
-    def _wait_timeout(self) -> float:
-        qos = getattr(self.node, "qos", None)
-        if qos is not None:
-            return qos.follower_wait_s()
-        return 30.0
+        `lead is None`: `item` is queued, and the first request of an idle
+        key leads: it runs batches, its own item in the first, until the
+        queue is empty. An answer of None (also: the wait ran out, the
+        leader left) is the caller's to serve some other way.
 
-    @classmethod
-    def _log_anomaly(cls, msg: str, *args, exc_info: bool = False) -> None:
-        if cls._log_budget > 0:
-            cls._log_budget -= 1
-            logger.warning(msg, *args, exc_info=exc_info)
-
-    def _wait(self, e: _Entry):
-        """Follower wait with the deadline-aware timeout; a timeout falls
-        to the general path, counted and logged instead of silent."""
-        with tracing.span("batcher.follow"):
-            served = e.event.wait(timeout=self._wait_timeout())
+        `lead` given: the first request of an idle key holds no entry,
+        answers itself with `lead()`, then runs what queued meanwhile and
+        releases the key, whatever `lead()` did. A follower with no answer
+        answers itself with `lead()` as well."""
+        # a request that is queued whoever leads stamps its entry before
+        # it waits for the lock: its queue wait counts from here
+        e = _Entry(item) if lead is None else None
+        with self._lock:
+            leader = key not in self._busy
+            if leader:
+                self._busy.add(key)
+            elif e is None:
+                e = _Entry(item)
+            if e is not None:
+                self._queues.setdefault(key, []).append(e)
+        if leader:
+            try:
+                own = lead() if lead is not None else None
+            finally:
+                self._drain(key, run)
+            if e is None:
+                return own, False
+        served = leader or self._wait(e)
         self._note_wait(e)
-        if not served:
-            e.abandoned = True
-            with self._lock:
-                self.wait_timeouts += 1
-            self._log_anomaly(
-                "batcher follower timed out after %.1fs waiting for its "
-                "leader; serving via the general path",
-                self._wait_timeout())
-            return None
-        if e.err is not None:
+        if served and e.err is not None:
             raise e.err
-        return e.out
+        out = e.out if served else None  # a wait that ran out reads nothing
+        if out is None and lead is not None:
+            return lead(), False
+        return out, out is not None
 
-    def _take(self, batch: list[_Entry]) -> None:
-        """The batch leaves the queue for the device: the end of each
-        member's queue wait (leader ≈ 0; followers accrue while the
-        previous batch runs) — the admission-latency half of batcher cost,
-        invisible to the device timers because it happens on the host."""
+    # -- the one loop --------------------------------------------------------
+
+    def _drain(self, key: tuple, run) -> None:
+        """The leader's loop: serve the key's queue in arrival order, a
+        window at a time, until it is empty; then release the key. Never
+        raises — a failing batch is its members' error."""
+        try:
+            while True:
+                with self._lock:
+                    batch = self._queues.pop(key, [])
+                    batch = [x for x in batch if not x.abandoned]
+                    if not batch:
+                        break
+                    window = self.qos.batch_window(self.MAX_BATCH)
+                    if len(batch) > window:
+                        self._queues[key] = batch[window:]
+                        batch = batch[:window]
+                self._run(run, batch)
+        finally:
+            self._release(key)
+
+    def _run(self, run, batch: list[_Entry]) -> None:
+        # the batch leaves the queue for the device: the end of each
+        # member's queue wait (a leader's own ≈ 0; followers accrue while
+        # the previous batch runs), which no device timer sees
         now = tracing.now_ns()
         for x in batch:
             x.t_taken = now
-
-    def _note_wait(self, e: _Entry) -> None:
-        """Each member books its own `batcher.queue_wait`, on its own
-        thread, so the span lands in its own request's tree."""
-        if e.t_taken is None:
-            return               # never taken: timed out or stranded
-        tracing.add_span("batcher.queue_wait", e.t_submit, e.t_taken)
-        metrics = getattr(self.node, "metrics", None)
-        if metrics is not None:
-            metrics.record("batcher.queue_wait",
-                           (e.t_taken - e.t_submit) / 1e6)
+        try:
+            outs = run([x.item for x in batch], now)
+        except Exception as ex:  # noqa: BLE001 — every member's error
+            with self._lock:
+                self.run_errors += 1
+                self.last_error = f"{type(ex).__name__}: {ex}"
+            for x in batch:
+                x.err = ex
+                x.event.set()
+            return
+        with self._lock:
+            self.batches += 1
+            self.batched_requests += len(batch)
+            self.occupancy[len(batch)] = \
+                self.occupancy.get(len(batch), 0) + 1
+        for i, x in enumerate(batch):
+            x.out = None if outs is None else outs[i]
+            x.event.set()
 
     def _release(self, key: tuple) -> None:
         """Leader exit: release leadership and unblock any leftover
-        followers (they serve themselves on the general path) — counted,
+        followers (they serve themselves some other way) — counted,
         because a nonzero rate means the leader loop exited abnormally."""
         with self._lock:
             self._busy.discard(key)
@@ -178,140 +204,39 @@ class SearchBatcher:
         if leftover:
             self._log_anomaly(
                 "batcher leader exited with %d followers still queued; "
-                "they fall to the general path", len(leftover))
+                "they serve themselves", len(leftover))
 
-    # -- the packed lane ---------------------------------------------------
+    # -- a member's side -----------------------------------------------------
 
-    def submit(self, key: tuple, name: str, body: dict, spec,
-               size: int, from_: int, t0: int):
-        """Execute (or join) a packed batch for this request, which began
-        at `t0` (ns). Returns the response dict, or None when the request
-        must take the general path (unservable batch / view refusal)."""
-        key = ("packed", *key)
-        e = _Entry(body, spec, t0)
-        with self._lock:
-            self._queues.setdefault(key, []).append(e)
-            leader = key not in self._busy
-            if leader:
-                self._busy.add(key)
-        if not leader:
-            return self._wait(e)
+    def _wait(self, e: _Entry) -> bool:
+        """Follower wait with the deadline-aware timeout -> served? A
+        timeout is counted and logged instead of silent."""
+        timeout = self.qos.follower_wait_s()
+        with tracing.span("batcher.follow"):
+            served = e.event.wait(timeout=timeout)
+        if not served:
+            e.abandoned = True
+            with self._lock:
+                self.wait_timeouts += 1
+            self._log_anomaly(
+                "batcher follower timed out after %.1fs waiting for its "
+                "leader; it serves itself", timeout)
+        return served
 
-        try:
-            while True:
-                with self._lock:
-                    batch = self._queues.pop(key, [])
-                    batch = [x for x in batch if not x.abandoned]
-                    if not batch:
-                        break
-                    window = self._window()
-                    if len(batch) > window:
-                        self._queues[key] = batch[window:]
-                        batch = batch[:window]
-                self._run(name, batch, size, from_)
-        finally:
-            self._release(key)
-        self._note_wait(e)
-        if e.err is not None:
-            raise e.err
-        return e.out
+    def _note_wait(self, e: _Entry) -> None:
+        """Each member books its own `batcher.queue_wait`, on its own
+        thread, so the span lands in its own request's tree."""
+        if e.t_taken is None:
+            return               # never taken: timed out or stranded
+        tracing.add_span("batcher.queue_wait", e.t_submit, e.t_taken)
+        self.metrics.record("batcher.queue_wait",
+                            (e.t_taken - e.t_submit) / 1e6)
 
-    def _run(self, name, batch, size, from_):
-        self._take(batch)
-        try:
-            outs = self.node._packed_search(
-                name, [x.body for x in batch], size=size, from_=from_,
-                t0=[x.t0 for x in batch], specs=[x.spec for x in batch])
-        except Exception as ex:  # noqa: BLE001 — every member's error
-            self._record_error(ex)
-            for x in batch:
-                x.err = ex
-                x.event.set()
-            return
-        self._book(batch)
-        for i, x in enumerate(batch):
-            x.out = None if outs is None else outs[i]
-            x.event.set()
-
-    # -- the coalesced general lane (ISSUE 9) ------------------------------
-
-    def join_batched(self, key: tuple, body: dict, row=None):
-        """The coalesced general lane's entry point (bodies without a
-        packed spec only: `NodeService._search_exec`; `row` is a dashboard
-        panel's `PanelRow`, which its batch runs in the body's place).
-        Returns the LEAD sentinel when the caller acquired leadership — it
-        must execute its solo path for itself (the general driver, or a
-        panel's Q = 1 program) and call `drain_batched(key, index)` when
-        done (a try/finally at the call site). Otherwise the caller is a
-        follower: blocks until the leader serves it and returns the
-        response dict, or None when its wait ran out, the leader left it
-        stranded or the panel lane could not serve its batch; the caller
-        then serves it solo the same way. A batch that raises is re-raised
-        here: it is the follower's error."""
-        key = ("gen", *key)
-        with self._lock:
-            if key not in self._busy:
-                self._busy.add(key)
-                return LEAD
-            e = _Entry(body, row)
-            self._queues.setdefault(key, []).append(e)
-        return self._wait(e)
-
-    def drain_batched(self, key: tuple, index: str) -> None:
-        """Leader epilogue: serve every follower that queued behind this
-        leader's solo execution as Q>1 batches (`_search_batched`; panels'
-        rows through `_search_panels`), then release leadership. Never
-        raises — a failing batch is its members' error (each follower
-        re-raises it)."""
-        key = ("gen", *key)
-        try:
-            while True:
-                with self._lock:
-                    batch = self._queues.pop(key, [])
-                    batch = [x for x in batch if not x.abandoned]
-                    if not batch:
-                        break
-                    window = self._window()
-                    if len(batch) > window:
-                        self._queues[key] = batch[window:]
-                        batch = batch[:window]
-                self._run_batched(index, batch)
-        finally:
-            self._release(key)
-
-    def _run_batched(self, index: str, batch: list[_Entry]) -> None:
-        self._take(batch)
-        try:
-            if batch[0].spec is not None:    # a key of panels: their rows
-                outs = self.node._search_panels(
-                    index, [x.spec for x in batch], batch[0].t_taken)
-            else:
-                outs = self.node._search_batched(
-                    [(index, x.body) for x in batch])
-        except Exception as ex:  # noqa: BLE001 — every member's error
-            self._record_error(ex)
-            for x in batch:
-                x.err = ex
-                x.event.set()
-            return
-        self._book(batch)
-        for i, x in enumerate(batch):
-            x.out = None if outs is None else outs[i]
-            x.event.set()
-
-    # -- accounting --------------------------------------------------------
-
-    def _book(self, batch: list[_Entry]) -> None:
-        with self._lock:
-            self.batches += 1
-            self.batched_requests += len(batch)
-            self.occupancy[len(batch)] = \
-                self.occupancy.get(len(batch), 0) + 1
-
-    def _record_error(self, ex: BaseException) -> None:
-        with self._lock:
-            self.run_errors += 1
-            self.last_error = f"{type(ex).__name__}: {ex}"
+    @classmethod
+    def _log_anomaly(cls, msg: str, *args) -> None:
+        if cls._log_budget > 0:
+            cls._log_budget -= 1
+            logger.warning(msg, *args)
 
     def stats(self) -> dict:
         with self._lock:

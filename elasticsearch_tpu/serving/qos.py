@@ -19,8 +19,8 @@ On a TPU the same goals map onto inference-serving staples:
     timer with (cluster/node.py `_query_with_hedge`).
   * module-level hedge counters — the cluster coordinator records
     fired/win/cancel outcomes here so the single exposition
-    (`es_search_hedged_total{outcome=}`), the sampler ring and bench.py
-    all read one source.
+    (`es_search_hedged_total{outcome=}`) and the sampler ring read one
+    source.
 
 Traffic classes mirror the reference's five connection types
 (recovery/bulk/reg/state/ping); the REST edge maps request classes onto
@@ -356,7 +356,7 @@ class _Admission:
 
 # ---------------------------------------------------------------------------
 # hedged-read accounting: the cluster coordinator records outcomes here so
-# /_metrics, the sampler ring and bench.py read one process-wide source.
+# /_metrics and the sampler ring read one process-wide source.
 # ---------------------------------------------------------------------------
 
 HEDGE_OUTCOMES = ("fired", "win_primary", "win_backup", "canceled",
@@ -400,7 +400,7 @@ def hedge_rate(window: int = 60) -> float:
 # arm the hedge deadline — a slow DCN link must not inflate the ICI
 # deadline for co-hosted copies (and vice versa). One Ewma per class,
 # same alpha/deviations math as the hedge tier, surfaced by
-# transport_latency_snapshot() for the metrics scrape and the bench.
+# transport_latency_snapshot() for the metrics scrape.
 
 _transport_lat_lock = threading.Lock()
 _transport_lat: dict[str, Ewma] = {}

@@ -66,8 +66,7 @@ class ChaosOptions:
         self.rounds = rounds
         self.docs_per_round = docs_per_round
         self.dims = dims
-        # 0 disables the cluster half (the cheap single-node-only mode
-        # the bench leg uses)
+        # 0 disables the cluster half (the cheap single-node-only mode)
         self.cluster_nodes = cluster_nodes
         self.shards = shards
         self.replicas = replicas
@@ -501,9 +500,10 @@ class ChaosRunner:
         cluster setting."""
         client = self._client()
         # recoveries stream on background threads: wait for every copy
-        # to be STARTED before refreshing, or a replica can come up
-        # BETWEEN the two compared searches serving a pre-refresh view
-        self.cluster.ensure_green(20.0)
+        # to be STARTED, and for the rebalancer's moves to hand over,
+        # before refreshing, or a copy can come up BETWEEN the two
+        # compared searches serving a pre-refresh view
+        self.cluster.ensure_settled(20.0)
         client.refresh("docs")
         bodies = self.cluster_work.text_queries(4)
         bodies.append({"size": 5, "knn": {

@@ -25,10 +25,9 @@ ALLOWLIST = {
 
 # an assignment like `self._foo_cache = {}` / `x_cache: dict = dict()` /
 # `bar_cache = OrderedDict()`. `_steps`/`_memo` names join the pattern:
-# ISSUE 6 migrated `DistributedSearcher._steps` (a dict-as-cache of
-# compiled programs under elasticsearch_tpu/parallel/ that the `_cache`
-# suffix alone never caught) onto the Cache core — dict memos by another
-# name are still unbounded caches
+# ISSUE 6 found a dict-as-cache of compiled programs under
+# elasticsearch_tpu/parallel/ (`_steps`) that the `_cache` suffix alone
+# never caught — dict memos by another name are still unbounded caches
 _DICT_CACHE_RX = re.compile(
     r"(?:self\.)?(\w*(?:_cache|_steps|_memo))\s*(?::\s*[^=]+)?=\s*"
     r"(?:\{\}|dict\(|collections\.OrderedDict\(|OrderedDict\()")

@@ -1,18 +1,14 @@
 """Vector search tests: exact kNN, filters, rescore pipeline, hybrid
-BM25->dense, distributed mesh kNN (BASELINE configs #4/#5 workload shapes)."""
+BM25->dense (BASELINE configs #4/#5 workload shapes). The mesh kNN lane is
+pinned in tests/test_mesh.py::TestMeshKnn."""
 
 import numpy as np
 import pytest
 
-import jax
-
 from elasticsearch_tpu.mapping.mapper import MapperService
 from elasticsearch_tpu.index.engine import Engine
-from elasticsearch_tpu.index.segment import SegmentBuilder
 from elasticsearch_tpu.search.shard_searcher import ShardSearcher
 from elasticsearch_tpu.node import NodeService
-from elasticsearch_tpu.parallel import (
-    make_mesh, shard_id, PackedIndex, DistributedSearcher)
 
 DIMS = 8
 
@@ -155,30 +151,3 @@ class TestNodeKnnApi:
         ids = [int(h["_id"]) for h in out["hits"]["hits"]]
         assert all(i % 4 == 2 for i in ids)
         node.close()
-
-
-class TestDistributedKnn:
-    def test_mesh_knn_matches_single(self):
-        rng = np.random.default_rng(3)
-        ms = MapperService(mappings=MAPPING)
-        mapper = ms.document_mapper("_doc")
-        builders = [SegmentBuilder(seg_id=i) for i in range(4)]
-        vecs = {}
-        for i in range(48):
-            v = unit(rng.normal(0, 1, DIMS))
-            vecs[str(i)] = v
-            builders[shard_id(str(i), 4)].add(
-                mapper.parse({"vec": v, "title": "x"}, doc_id=str(i)), "_doc")
-        segs = [b.build() for b in builders]
-        mesh = make_mesh(n_shards=4, n_replicas=2)
-        ds = DistributedSearcher(index=PackedIndex.from_segments(segs),
-                                 mesh=mesh).place()
-        q = np.asarray([vecs["7"]], np.float32)   # query = doc 7's vector
-        scores, keys = ds.search_knn("vec", q, k=5)
-        top_ids = [ds.index.fetch(int(k))[0] for k in keys[0] if k >= 0]
-        assert top_ids[0] == "7"                  # self-match first
-        assert abs(scores[0][0] - 1.0) < 5e-3
-        # parity with brute force
-        sims = {d: float(np.dot(q[0], v)) for d, v in vecs.items()}
-        expect = sorted(sims, key=lambda d: -sims[d])[:5]
-        assert set(top_ids) == set(expect)

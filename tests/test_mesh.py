@@ -1,6 +1,6 @@
 """Mesh-sharded query lane (ISSUE 6): equivalence vs the fan-out,
-single-fetch/zero-host-merge counters, the mesh-stack cache lifecycle,
-the fallback ladder, and the distributed-search satellites.
+single-fetch/zero-host-merge counters, the mesh-stack cache lifecycle
+and the fallback ladder.
 
 The mesh lane replaces the coordinator's thread-pool fan-out (S device
 fetches + a host-side cross-shard merge per multi-shard query) with ONE
@@ -61,6 +61,10 @@ MESH_QUERIES = [
     {"bool": {"should": [{"match": {"body": "quick"}},
                          {"match": {"body": "river"}}],
               "minimum_should_match": 2}},
+    # one term, and a term no shard holds: every shard's candidates are
+    # padding and the cross-shard reduce must still agree (total 0, no hit)
+    {"bool": {"should": [{"match": {"body": "quick"}}]}},
+    {"bool": {"should": [{"match": {"body": "zzzabsent"}}]}},
 ]
 
 DENSE_Q = {"size": 5, "query": {"bool": {
@@ -424,60 +428,23 @@ class TestMeshMetrics:
         assert node.stats()["caches"]["mesh_stack"]["entries"] == 1
 
 
-# -- distributed-search satellites (ISSUE 6) --------------------------------
+def test_dryrun_multichip_rides_the_mesh_lane(capsys):
+    """The driver's multi-chip dry run (`__graft_entry__`, called from
+    outside this repo): 8 virtual CPU devices in a child process, a
+    2 x 4 mesh, `match` bodies answered by the mesh lane as the fan-out
+    answers them."""
+    import ast
+    import re
 
-class TestDistributedSatellites:
-    def test_knn_replica_padding_rows_masked(self):
-        """Q not divisible by n_replicas pads with all-zero query vectors;
-        pad rows must contribute -inf inside the step (never NaN through
-        cosine 0/0) and the [:Q] rows must come back NaN-free."""
-        import jax
-
-        from elasticsearch_tpu.index.segment import SegmentBuilder
-        from elasticsearch_tpu.mapping.mapper import MapperService
-        from elasticsearch_tpu.parallel import (DistributedSearcher,
-                                                PackedIndex, make_mesh,
-                                                shard_id)
-        rng = np.random.default_rng(7)
-        ms = MapperService(mappings={"_doc": {"properties": {
-            "v": {"type": "dense_vector", "dims": 8}}}})
-        mapper = ms.document_mapper("_doc")
-        builders = [SegmentBuilder(seg_id=i) for i in range(4)]
-        for i in range(24):
-            vec = rng.normal(0, 1, 8).astype(np.float32)
-            builders[shard_id(str(i), 4)].add(
-                mapper.parse({"v": [float(x) for x in vec]},
-                             doc_id=str(i)), "_doc")
-        shards = [b.build() for b in builders]
-        mesh = make_mesh(n_shards=4, n_replicas=2,
-                         devices=jax.devices()[:8])
-        ds = DistributedSearcher(index=PackedIndex.from_segments(shards),
-                                 mesh=mesh).place()
-        qv = rng.normal(0, 1, (3, 8)).astype(np.float32)   # pads to 4
-        scores, keys = ds.search_knn("v", qv, k=5, metric="cosine")
-        assert scores.shape == (3, 5)
-        assert not np.isnan(scores).any()
-        assert (keys >= 0).all()
-
-    def test_step_memo_is_bounded_cache(self):
-        """DistributedSearcher's compiled-step memo rides the common
-        Cache core (bounded, observable) and still memoizes."""
-        from elasticsearch_tpu.common.cache import Cache
-        from elasticsearch_tpu.index.segment import SegmentBuilder
-        from elasticsearch_tpu.mapping.mapper import MapperService
-        from elasticsearch_tpu.parallel import (DistributedSearcher,
-                                                PackedIndex, make_mesh)
-        ms = MapperService()
-        mapper = ms.document_mapper("_doc")
-        b = SegmentBuilder(seg_id=0)
-        b.add(mapper.parse({"body": "quick fox"}, doc_id="0"), "_doc")
-        ds = DistributedSearcher(
-            index=PackedIndex.from_segments([b.build()]),
-            mesh=make_mesh(n_shards=1, n_replicas=1))
-        assert isinstance(ds._step_cache, Cache)
-        s1 = ds.build_step(Wt=8, k=5)
-        assert ds.build_step(Wt=8, k=5) is s1
-        assert ds._step_cache.stats()["entries"] == 1
+    import __graft_entry__
+    __graft_entry__.dryrun_multichip(8)
+    out = capsys.readouterr().out
+    ok = re.search(r"^dryrun_multichip ok: mesh=(\{.*\}) totals=(\[.*\])$",
+                   out, re.M)
+    assert ok, out
+    assert ast.literal_eval(ok[1]) == {"replica": 2, "shard": 4}
+    totals = ast.literal_eval(ok[2])
+    assert len(totals) == 4 and all(t > 0 for t in totals)
 
 
 class TestMeshKnn:
@@ -557,3 +524,41 @@ class TestMeshKnn:
         assert g == w
         assert n.indices["vm"].search_stats.get(
             "mesh_ann_fallbacks", 0) == fb0 + 1
+
+    @pytest.mark.parametrize("n_queries", [1, 3, 5])
+    def test_batch_the_replica_axis_does_not_divide(self, knn_pair,
+                                                    n_queries):
+        """Q not divisible by the replica axis pads with all-zero query
+        vectors: the pad rows must stay inside the program (never NaN
+        through cosine 0/0) and the [:Q] rows come back NaN-free, with
+        real doc keys, each as its own solo search answers."""
+        from elasticsearch_tpu.parallel import mesh_knn
+        n = knn_pair
+        svc = n.indices["vm"]
+        searchers = svc.searchers()
+        vstack = n.caches.mesh_vector_stacks.get_or_build(
+            "vm", svc._incarnation, "vec",
+            [list(s.segments) for s in searchers],
+            breaker=n.breakers.breaker("fielddata"), pool=n.device_pool)
+        assert vstack.n_replicas == 2 and n_queries % 2 == 1
+        qv = np.random.RandomState(5).randn(n_queries, self.D) \
+            .astype(np.float32)
+
+        def run(vectors):
+            return mesh_knn.execute(
+                vstack, vectors, k=5, metric="cosine",
+                knn_opts=searchers[0].knn_opts, nprobe=None, exact=False,
+                acquire_ivf=lambda si, seg, vc: searchers[si]._acquire_ivf(
+                    seg, vc, "vec", None, False))
+
+        keys, shard_of, scores, totals, _mx, used_ivf, _q = run(qv)
+        assert used_ivf
+        assert keys.shape == shard_of.shape == scores.shape == (n_queries, 5)
+        assert totals.shape == (4, n_queries)
+        assert not np.isnan(scores).any()
+        assert (keys >= 0).all()
+        for qi in range(n_queries):
+            k1, s1, sc1, *_ = run(qv[qi])
+            assert np.array_equal(keys[qi], k1[0]), qi
+            assert np.array_equal(shard_of[qi], s1[0]), qi
+            assert np.array_equal(scores[qi], sc1[0]), qi

@@ -1,44 +1,8 @@
-"""Distributed (mesh) search tests on the virtual 8-device CPU mesh —
-the InternalTestCluster analog (SURVEY.md §4.2): multi-"node" in one process.
+"""Document routing (parallel/routing.py): the reference's DJB hash and
+floor-mod shard choice. The mesh lane that serves searches is pinned in
+tests/test_mesh.py."""
 
-Parity oracle: the distributed top-k over N shards must equal a single-shard
-search over the union corpus (global IDF via psum makes scores identical,
-mirroring the reference's DFS_QUERY_THEN_FETCH exactness guarantee)."""
-
-import numpy as np
-import pytest
-
-import jax
-
-from elasticsearch_tpu.mapping.mapper import MapperService
-from elasticsearch_tpu.index.segment import SegmentBuilder
-from elasticsearch_tpu.parallel import (
-    djb_hash, shard_id, make_mesh, PackedIndex, DistributedSearcher,
-)
-
-DOCS = [
-    ("0", "the quick brown fox jumps"),
-    ("1", "quick cats and lazy dogs"),
-    ("2", "the lazy dog sleeps"),
-    ("3", "python programming guide"),
-    ("4", "rust systems programming"),
-    ("5", "quick quick quick repetition"),
-    ("6", "brown bears eat fish"),
-    ("7", "dogs and cats and foxes"),
-    ("8", "a guide to foxes"),
-    ("9", "sleepy brown dog"),
-]
-
-
-def build_shards(n_shards: int):
-    """Route docs by DJB hash (reference parity) into per-shard segments."""
-    ms = MapperService()
-    mapper = ms.document_mapper("_doc")
-    builders = [SegmentBuilder(seg_id=i) for i in range(n_shards)]
-    for doc_id, text in DOCS:
-        s = shard_id(doc_id, n_shards)
-        builders[s].add(mapper.parse({"body": text}, doc_id=doc_id), "_doc")
-    return [b.build() for b in builders]
+from elasticsearch_tpu.parallel import djb_hash, shard_id
 
 
 class TestRouting:
@@ -56,62 +20,3 @@ class TestRouting:
     def test_routing_param_overrides_id(self):
         assert shard_id("whatever", 7, routing="user-1") == \
                shard_id("other", 7, routing="user-1")
-
-
-@pytest.fixture(scope="module")
-def dist_searcher():
-    shards = build_shards(4)
-    mesh = make_mesh(n_shards=4, n_replicas=2)
-    idx = PackedIndex.from_segments(shards)
-    return DistributedSearcher(index=idx, mesh=mesh).place()
-
-
-class TestDistributedSearch:
-    def test_mesh_shape(self, dist_searcher):
-        assert dist_searcher.mesh.shape == {"replica": 2, "shard": 4}
-
-    def test_term_search_finds_all_matches(self, dist_searcher):
-        scores, keys, total, mx = dist_searcher.search_terms(
-            "body", [["quick"]], k=10)
-        assert int(total[0]) == 3          # docs 0, 1, 5
-        got_ids = {dist_searcher.index.fetch(int(kk))[0]
-                   for kk in keys[0] if kk >= 0}
-        assert got_ids == {"0", "1", "5"}
-
-    def test_parity_with_single_shard(self, dist_searcher):
-        """Distributed scores == single-shard scores over the union corpus
-        (global-IDF psum ≙ one big shard)."""
-        ms = MapperService()
-        mapper = ms.document_mapper("_doc")
-        b = SegmentBuilder(seg_id=0)
-        for doc_id, text in DOCS:
-            b.add(mapper.parse({"body": text}, doc_id=doc_id), "_doc")
-        seg = b.build()
-        single = PackedIndex.from_segments([seg])
-        mesh1 = make_mesh(n_shards=1, n_replicas=1, devices=jax.devices()[:1])
-        ds1 = DistributedSearcher(index=single, mesh=mesh1).place()
-
-        for q in (["quick"], ["brown", "dog"], ["programming", "guide"]):
-            s_d, k_d, t_d, _ = dist_searcher.search_terms("body", [q], k=10)
-            s_1, k_1, t_1, _ = ds1.search_terms("body", [q], k=10)
-            assert int(t_d[0]) == int(t_1[0])
-            by_id_d = {dist_searcher.index.fetch(int(kk))[0]: s
-                       for kk, s in zip(k_d[0], s_d[0]) if kk >= 0}
-            by_id_1 = {ds1.index.fetch(int(kk))[0]: s
-                       for kk, s in zip(k_1[0], s_1[0]) if kk >= 0}
-            assert set(by_id_d) == set(by_id_1)
-            for did in by_id_d:
-                assert abs(by_id_d[did] - by_id_1[did]) < 1e-4, (q, did)
-
-    def test_batched_queries_sharded_over_replicas(self, dist_searcher):
-        qs = [["quick"], ["dog"], ["fox"], ["guide"]]
-        scores, keys, total, _ = dist_searcher.search_terms("body", qs, k=5)
-        assert scores.shape == (4, 5)
-        assert int(total[0]) == 3   # quick
-        assert int(total[3]) == 2   # guide: docs 3, 8
-
-    def test_zero_hit_query(self, dist_searcher):
-        scores, keys, total, mx = dist_searcher.search_terms(
-            "body", [["zzzabsent"]], k=5)
-        assert int(total[0]) == 0
-        assert all(kk < 0 for kk in keys[0])

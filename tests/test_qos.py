@@ -27,9 +27,9 @@ import pytest
 
 from elasticsearch_tpu.common.settings import Settings
 from elasticsearch_tpu.node import NodeService
-from elasticsearch_tpu.serving.batcher import LEAD, SearchBatcher
 from elasticsearch_tpu.serving.qos import (Ewma, QosController,
                                            QosShedException, hedge_snapshot)
+from tests.test_batcher import batcher_alone, queued, served
 
 WORDS = ["quick", "brown", "fox", "jumps", "lazy", "dog", "sleeps",
          "swift", "river", "stone"]
@@ -142,25 +142,34 @@ class TestCoalescedBatchParity:
         assert all(k is not None and k == keys[0] for k in keys), \
             "same-shape bodies must share one coalescing group"
 
-        got = node._batcher.join_batched(keys[0], bodies[0])
-        assert got is LEAD          # this thread now holds leadership
+        # this thread leads (its own answer is beside the point) until the
+        # three searches have queued behind it, then drains them
+        key = ("gen", *keys[0])
         results: dict[int, dict] = {}
         threads = [threading.Thread(
             target=lambda i=i: results.__setitem__(
                 i, _search(node, bodies[i])))
             for i in range(1, 4)]
         before = node._batcher.stats()
-        for t in threads:
-            t.start()
-        deadline = time.time() + 5
-        while time.time() < deadline:
-            with node._batcher._lock:
-                qd = len(node._batcher._queues.get(("gen", *keys[0]), []))
-            if qd == 3:
-                break
-            time.sleep(0.01)
-        assert qd == 3, "followers did not queue behind the leader"
-        node._batcher.drain_batched(keys[0], "q")
+
+        def lead():
+            for t in threads:
+                t.start()
+            deadline = time.time() + 5
+            while time.time() < deadline:
+                with node._batcher._lock:
+                    qd = len(node._batcher._queues.get(key, []))
+                if qd == 3:
+                    break
+                time.sleep(0.01)
+            return qd
+
+        qd, shared = node._batcher.coalesce(
+            key, bodies[0],
+            lambda items, _t: node._search_batched(
+                [("q", b) for b in items]), lead=lead)
+        assert (qd, shared) == (3, False), \
+            "followers did not queue behind the leader"
         for t in threads:
             t.join()
         after = node._batcher.stats()
@@ -566,89 +575,67 @@ class TestTrafficClasses:
 # ---------------------------------------------------------------------------
 
 
-class _StubQos:
-    def __init__(self, wait_s=0.05):
-        self._wait = wait_s
-
-    def batch_window(self, base):
-        return base
-
-    def follower_wait_s(self):
-        return self._wait
-
-
-class _StubNode:
-    def __init__(self, wait_s=0.05):
-        self.qos = _StubQos(wait_s)
-        self.metrics = None
-
-    def _search_batched(self, metas):
-        return [{"served": body} for _, body in metas]
-
-
 class TestBatcherAccounting:
+    """The anomaly counters, on the batcher alone (`batcher_alone`,
+    tests/test_batcher.py: plain callables, a stub qos, no node)."""
+
     def test_follower_wait_timeout_counted_and_falls_back(self):
-        node = _StubNode(wait_s=0.05)
-        b = SearchBatcher(node)
-        key = ("k",)
-        assert b.join_batched(key, {"q": 0}) is LEAD
+        b = batcher_alone(wait_s=0.05)
         got = []
-        th = threading.Thread(
-            target=lambda: got.append(b.join_batched(key, {"q": 1})))
-        th.start()
-        th.join(5)              # leader never drains: follower times out
-        assert got == [None], "timed-out follower must fall to general"
+
+        def lead():
+            th = threading.Thread(target=lambda: got.append(
+                b.coalesce(("k",), {"q": 1}, served)))
+            th.start()
+            th.join(5)          # the leader does not drain: it times out
+            return "led"
+
+        assert b.coalesce(("k",), {"q": 0}, served, lead=lead) \
+            == ("led", False)
+        assert got == [(None, False)], \
+            "a timed-out follower gets no answer from the batcher"
         assert b.stats()["wait_timeouts_total"] == 1
-        b.drain_batched(key, "i")   # abandoned entry must not be served
+        # the abandoned entry did not spend a row of the leader's drain
         assert b.stats()["batches"] == 0
 
     def test_stranded_followers_counted_and_released(self):
-        node = _StubNode(wait_s=5.0)
-        b = SearchBatcher(node)
-        key = ("k",)
-        assert b.join_batched(key, {"q": 0}) is LEAD
+        b = batcher_alone(wait_s=5.0)
         got = []
-        th = threading.Thread(
-            target=lambda: got.append(b.join_batched(key, {"q": 1})))
-        th.start()
-        deadline = time.time() + 5
-        while time.time() < deadline:
-            with b._lock:
-                if b._queues.get(("gen", "k")):
-                    break
-            time.sleep(0.01)
-        # leader exits WITHOUT draining (the leftover path): the follower
-        # must be released to the general path and counted as stranded
-        b._release(("gen", "k"))
-        th.join(5)
-        assert got == [None]
+
+        def lead():
+            th = threading.Thread(target=lambda: got.append(
+                b.coalesce(("k",), {"q": 1}, served)))
+            th.start()
+            assert queued(b, ("k",), 1)
+            # the leader exits WITHOUT draining (the leftover path): the
+            # follower is released to serve itself and counted as stranded
+            b._release(("k",))
+            th.join(5)
+            return "led"
+
+        b.coalesce(("k",), {"q": 0}, served, lead=lead)
+        assert got == [(None, False)]
         assert b.stats()["stranded_total"] == 1
 
     def test_run_error_recorded_not_discarded(self):
-        node = _StubNode(wait_s=5.0)
-
-        def boom(metas):
-            raise RuntimeError("device fell over")
-        node._search_batched = boom
-        b = SearchBatcher(node)
-        key = ("k",)
-        assert b.join_batched(key, {"q": 0}) is LEAD
+        b = batcher_alone(wait_s=5.0)
         got = []
+
+        def boom(items, t_taken):
+            raise RuntimeError("device fell over")
 
         def follower():
             try:
-                got.append(b.join_batched(key, {"q": 1}))
+                got.append(b.coalesce(("k",), {"q": 1}, boom))
             except RuntimeError as e:
                 got.append(e)
         th = threading.Thread(target=follower)
-        th.start()
-        deadline = time.time() + 5
-        while time.time() < deadline:
-            with b._lock:
-                if b._queues.get(("gen", "k")):
-                    break
-            time.sleep(0.01)
-        b.drain_batched(key, "i")
+
+        def lead():
+            th.start()
+            assert queued(b, ("k",), 1)
+
+        b.coalesce(("k",), {"q": 0}, boom, lead=lead)
         th.join(5)
         assert not th.is_alive()
         # a failing batch is its members' error, never a slower lane

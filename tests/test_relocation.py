@@ -697,3 +697,49 @@ class TestAllocationIdFence:
             assert cur["aid"] > old_aid
         finally:
             cluster.close()
+
+    def test_a_finished_pull_is_not_pulled_again_for_its_own_era(
+            self, tmp_path):
+        """A state published between a copy's finished pull and the
+        master's booking of its report still shows the copy INITIALIZING
+        under the SAME aid. Applying it must report again and pull
+        nothing. The node used to close the live engine and stream again;
+        for a relocation target the source is gone by then, so a STARTED
+        primary was left without an engine (the 3-node, 3-shard,
+        1-replica fixture of the distributed-search parity tests, once in
+        a few runs on a starved machine: `[docs][0] primary not on
+        [node-3]`)."""
+        from elasticsearch_tpu.cluster.recovery import snapshot
+        from elasticsearch_tpu.cluster.state import INITIALIZING
+        cluster = TestCluster(3, str(tmp_path))
+        try:
+            client = cluster.client()
+            # 3 primaries start one by one, each replica goes to the least
+            # loaded node that may hold it: loads end 3 / 2 / 1 and the
+            # rebalancer moves a started copy, under a new aid
+            client.create_index("docs", {"number_of_shards": 3,
+                                         "number_of_replicas": 1})
+            cluster.ensure_green()
+            st = _settle(cluster)
+            for i in range(12):
+                client.index_doc("docs", str(i), {"n": i})
+            pulls = snapshot()["completed_total"]
+            for sid, copies in enumerate(st.routing["docs"]):
+                for c in copies:
+                    if c["primary"] and c["aid"] <= 3:
+                        continue        # started empty: never pulled
+                    node = cluster.nodes[c["node"]]
+                    holder = node._shards[("docs", sid)]
+                    engine = holder.engine
+                    assert engine is not None, (sid, c)
+                    stale = {**c, "primary": False, "state": INITIALIZING,
+                             "recover_from": "node-gone"}
+                    node._init_shard(st, "docs", sid, stale)
+                    assert holder.engine is engine, (sid, c)
+                    assert not holder.recovering, (sid, c)
+            assert snapshot()["completed_total"] == pulls
+            cluster.ensure_green()
+            client.refresh("docs")
+            assert client.search("docs", {"size": 0})["hits"]["total"] == 12
+        finally:
+            cluster.close()

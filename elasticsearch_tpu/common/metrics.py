@@ -334,6 +334,25 @@ def packed_gather_snapshot() -> dict:
                 for form, n in _PACKED_GATHER.items()}
 
 
+# hits the packed lane rendered, by how (serving/executor.respond):
+# es_packed_render_hits_total{form=}. "vector" took the raw render's numpy
+# passes, "patched" its scalar `%.9g` (an exponent form, inf, nan), "dict"
+# were built as Python dicts (`_source`, a mixed-type index, an unsafe id).
+_PACKED_RENDER = {"vector": 0, "patched": 0, "dict": 0}
+
+
+def record_packed_render(**hits_by_form: int) -> None:
+    with _DEVICE_LOCK:
+        for form, n in hits_by_form.items():
+            _PACKED_RENDER[form] += n
+
+
+def packed_render_snapshot() -> dict:
+    with _DEVICE_LOCK:
+        return {form: {"hits_total": n}
+                for form, n in _PACKED_RENDER.items()}
+
+
 def transfer_snapshot() -> dict:
     """Process-wide host↔device transfer counters (every device_fetch /
     note_h2d call accounts here, profiler active or not) — the scrape's

@@ -1827,10 +1827,10 @@ class NodeService:
         ONE device program across all shards/segments, one upload, one
         download (serving/). `t0[i]` (ns) is when the request of body i
         began: each response's `took` is its own, whoever led the batch.
-        Returns per-body responses (dicts, or raw JSON strings when `raw`
+        Returns per-body responses (dicts, or the JSON's `bytes` when `raw`
         and `_source: false`), or None to fall back."""
-        from .serving.executor import (packed_spec_of, response_dict,
-                                       response_raw)
+        from .serving.executor import packed_spec_of
+        from .serving.executor import respond as respond_batch
         svc = self.indices[name]
         view = svc.packed_view()
         if view is None:
@@ -1855,23 +1855,23 @@ class NodeService:
             return None    # breaker refused a filter column: general path
         respond = tracing.span("packed.respond")
         with respond:
-            # `took` ends where the rendering starts (the span's own read)
-            out = []
-            for qi, body in enumerate(bodies):
-                took = (respond.start_ns - t0[qi]) // 1_000_000
-                src_spec = body.get("_source", True)
-                if raw and src_spec is False and view.ids_json_safe:
-                    out.append(response_raw(
-                        view, name, scores[qi], docs[qi], hits[qi],
-                        n_shards=svc.n_shards, took=took,
-                        from_=from_, size=size))
-                else:
-                    fn = (lambda s: _source_filter(s, src_spec)) \
-                        if src_spec not in (True, False) else None
-                    out.append(response_dict(
-                        view, name, scores[qi], docs[qi], hits[qi],
-                        n_shards=svc.n_shards, took=took, from_=from_,
-                        size=size, src_spec=src_spec, src_filter_fn=fn))
+            # `took` ends where the rendering starts (the span's own read).
+            # serving/executor.respond renders the batch: the `_source:
+            # false` bodies of a raw request as `bytes`, all their hits in
+            # one vector pass; else dicts. Nothing above this span may gain
+            # or lose a line: the packed programs' compile-cache keys hold
+            # the line of `view.search(` above and of `self._packed_search(`
+            # in `msearch` below (PERF.md §6, PR 29), which is why this
+            # block is as long as the loop it replaced.
+            tooks = [(respond.start_ns - t) // 1_000_000 for t in t0]
+            out, said = respond_batch(
+                view, name, bodies, scores, docs, hits, raw=raw,
+                n_shards=svc.n_shards, tooks=tooks, from_=from_, size=size,
+                source_filter=_source_filter)
+            # form=raw|dict, hits=, patched= (rows that took the scalar
+            # `%.9g`): on the span of `GET /_traces`; the counter
+            # es_packed_render_hits_total{form=} has the same by form
+            respond.attrs.update(said)
         # count AFTER successful response assembly — a failure above is the
         # request's error and must not be booked as a packed serve
         svc.search_stats["packed"] = \
@@ -2173,11 +2173,12 @@ class NodeService:
         if raw:
             # the raw lane serializes here, so the HTTP layer's own
             # `rest.serialize` finds bytes and has nothing left to do
+            # (the packed lane's items are bytes already; a dict came from
+            # another lane or is an item's error)
             with tracing.span("rest.serialize"):
-                payload = '{"responses":[' + ",".join(
-                    r if isinstance(r, str) else json.dumps(r)
-                    for r in responses) + ']}'
-                return payload.encode()
+                return b'{"responses":[' + b",".join(
+                    r if isinstance(r, bytes) else json.dumps(r).encode()
+                    for r in responses) + b']}'
         return {"responses": responses}
 
     def _msearch_one(self, index: str, body: dict) -> dict:
@@ -3081,6 +3082,7 @@ class NodeService:
         from .common import device_stats, monitor
         from .common.metrics import (device_events_snapshot,
                                      packed_gather_snapshot,
+                                     packed_render_snapshot,
                                      transfer_snapshot)
         batcher = self._batcher.stats()
         occupancy = batcher.pop("occupancy", {})
@@ -3286,6 +3288,9 @@ class NodeService:
             # es_packed_gather_dispatches_total{form=}: packed dispatches
             # by how the program copied its slots (blocked | sliced)
             "packed_gather": ("form", packed_gather_snapshot()),
+            # es_packed_render_hits_total{form=}: hits the packed lane
+            # rendered, by how (vector | patched | dict)
+            "packed_render": ("form", packed_render_snapshot()),
             "tasks": (None, self.tasks.stats()),
             # span tracer: started/retained/sampled-out trace counters,
             # ring-eviction + span-cap drop counters, live gauges

@@ -153,26 +153,26 @@ class PackedIndexView:
         self.n_pad_total = next_pow2(self.n_total + 1, floor=8)
         self.doc_count = sum(s.n_docs for _, s in segments)
 
-        # host columns for vectorized fetch: _id / _type per global doc id
+        # host columns, a row per global doc id: `ids_packed` the _id as text
+        # (tests and the raw render's reference twin read it), `ids_bytes`
+        # its UTF-8 bytes NUL-padded to one width, the column the raw render
+        # gathers its hits' ids from (serving/executor.response_raw)
         max_id = max((max((len(i) for i in s.ids), default=1)
                       for _, s in segments), default=1)
         self.ids_packed = np.full(self.n_pad_total, "", dtype=f"U{max_id}")
         types: set[str] = set()
         for ei, (_, seg) in enumerate(segments):
             if seg.n_docs:
-                self.ids_packed[self.bases[ei]:self.bases[ei] + seg.n_docs] = \
-                    seg.ids
+                b0 = self.bases[ei]
+                self.ids_packed[b0:b0 + seg.n_docs] = seg.ids
                 types.update(seg.types)
+        self.ids_bytes = _utf8_rows(self.ids_packed)
         self.single_type = types.pop() if len(types) == 1 else None
-        # raw-JSON hits need no escaping only if every id/type is clean;
-        # the "," separator is itself JSON-safe, so an id containing any
-        # unsafe char (incl. newline) is always caught. Mixed-type indexes
-        # use the dict lane (per-doc _type).
+        # raw hits need no escaping only if every id and the type are clean
+        # ("," is JSON-safe itself); a mixed-type index takes the dict lane
         joined = ",".join(",".join(s.ids) for _, s in segments)
-        self.ids_json_safe = (self.single_type is not None
-                              and _JSON_UNSAFE.search(joined) is None
-                              and _JSON_UNSAFE.search(self.single_type)
-                              is None)
+        self.ids_json_safe = self.single_type is not None and not (
+            _JSON_UNSAFE.search(joined) or _JSON_UNSAFE.search(self.single_type))
 
         self._fields: dict[str, PackedField | None] = {}
         self._refused: set[str] = set()   # breaker-refused (≠ absent) fields
@@ -868,3 +868,14 @@ class PackedIndexView:
                     jnp.zeros((q, F_TERM), jnp.int32),
                     S=s, CHUNK=CHUNK, R=4, k=k,
                     FR=F_RANGE, FT=F_TERM, TV=F_TERM_VALS)
+
+
+def _utf8_rows(ids: np.ndarray) -> np.ndarray:
+    """U[n] -> uint8[n, w]: every string's UTF-8 bytes, NUL-padded. (At the
+    end of the file: a line that moves above `search` or `warmup` gives
+    every packed program a new compile-cache key, PERF.md section 6, PR 29.)"""
+    points = ids.view(np.uint32).reshape(ids.shape[0], -1)
+    if points.max(initial=0) < 128:          # ASCII: a code point is a byte
+        return points.astype(np.uint8)
+    enc = np.char.encode(ids, "utf-8")
+    return enc.view(np.uint8).reshape(ids.shape[0], -1)

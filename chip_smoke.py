@@ -546,11 +546,15 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     shards, reduced = SHARDS, []
     if args.chips == 4:
-        # mesh_for pads the shard count to a power of two: 5 shards would
-        # need 8 devices (parallel/mesh_exec.py)
+        # the general mesh lane (`match`, dis_max, its aggregations: steps
+        # (d) and (e)) pads the shard count to a power of two, so 5 shards
+        # would need 8 devices (parallel/mesh_exec.py `mesh_for`). The limit
+        # is that lane's alone since PR 31: the panel lane's axis is the
+        # chips, and it runs 5 shards on 4 (search/aggs/panels.py; the
+        # benchmark's cell httplogs.dashboard-mesh)
         shards = 4
-        reduced.append("shards 5 -> 4: the mesh lane pads the shard axis "
-                       "to a power of two and the host has 4 chips")
+        reduced.append("shards 5 -> 4: the general mesh lane pads the shard "
+                       "axis to a power of two and the host has 4 chips")
     summary = run(DOCS, shards, "tpu", seed=args.seed, n_devices=args.chips,
                   reduced=reduced)
     print(json.dumps(summary))

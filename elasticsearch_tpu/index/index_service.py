@@ -11,6 +11,7 @@ from __future__ import annotations
 import itertools
 import os
 import shutil
+import threading
 from typing import Any
 
 # monotonic index-incarnation ids (request-cache keys include one)
@@ -140,6 +141,16 @@ class IndexService:
             removal_listener=self._on_packed_removed)
         if caches is not None:
             caches.register(f"packed_view[{name}]", self._packed_view_cache)
+        # the panel lane's operands a chip (search/aggs/panels.PanelView):
+        # a second copy of the columns it serves, charged to the fielddata
+        # breaker as it grows and released when the view leaves (a change
+        # of segments, close, delete: it goes with the incarnation)
+        self._panel_view_cache = Cache(
+            "panel_view", max_entries=1,
+            removal_listener=lambda _k, v, _why: v[1].release())
+        self._panel_view_lock = threading.Lock()
+        if caches is not None:
+            caches.register(f"panel_view[{name}]", self._panel_view_cache)
 
     def reader_generation(self) -> tuple:
         """Changes whenever a refresh/merge/delete changes what a searcher
@@ -285,6 +296,7 @@ class IndexService:
         for e in self.shards:
             e.close()
         self._packed_view_cache.clear()
+        self._panel_view_cache.clear()
         if self.caches is not None:
             self.caches.segment_stacks.clear([self.name])
             self.caches.mesh_stacks.clear([self.name])
@@ -369,6 +381,35 @@ class IndexService:
         view = PackedIndexView(entries, breaker=req, base=base)
         self._packed_view_cache.put("view", (key, view))
         return view
+
+    def panel_view(self, pool):
+        """The panel lane's view of this index's segments as they stand on
+        the chips of `pool` (search/aggs/panels.py), built anew when the
+        segments changed: what the last view had placed is placed again,
+        a chip whose segments stayed keeps its blocks. None where the
+        fielddata breaker refuses the copy: the caller keeps the path it
+        had."""
+        from ..common.breaker import CircuitBreakingException
+        from ..search.aggs.panels import PanelView
+        shards = [list(s.segments) for s in self.searchers()]
+        key = (pool.devkey, tuple(tuple(id(seg) for seg in segments)
+                                  for segments in shards))
+        with self._panel_view_lock:
+            cached = self._panel_view_cache.get("view")
+            if cached is not None and cached[0] == key:
+                return cached[1]
+            # the stale view's charge goes back before the new one builds;
+            # its blocks live on as `base` until the new view has them
+            self._panel_view_cache.invalidate("view")
+            try:
+                view = PanelView(
+                    shards, pool, base=cached[1] if cached else None,
+                    breaker=self.breakers.breaker("fielddata")
+                    if self.breakers is not None else None)
+            except CircuitBreakingException:
+                return None
+            self._panel_view_cache.put("view", (key, view))
+            return view
 
     # -- introspection -----------------------------------------------------
 

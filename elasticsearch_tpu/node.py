@@ -908,11 +908,11 @@ class NodeService:
                                     alias_flt, cache_key, tns0, compiles0)
 
     def _served_shared(self, name: str, body: dict, tns0: int,
-                       compiles0: int) -> None:
+                       compiles0: int, lane: str = "batched") -> None:
         """A follower was served from a shared batch: only TOTAL is honest
         (its wall time includes queue wait and shared work)."""
         from .common.device_stats import lane_chosen
-        lane_chosen("serve", "batched")
+        lane_chosen("serve", lane)
         self._served_total(name, body, (tracing.now_ns() - tns0) / 1e6,
                            compiles0)
 
@@ -2288,9 +2288,23 @@ class NodeService:
                 lead=lambda: self._panel_solo(name, body, row, tns0,
                                               compiles0))
             if shared:
-                self._served_shared(name, body, tns0, compiles0)
+                self._served_shared(name, body, tns0, compiles0,
+                                    self._panel_lane())
             return out
         return self._panel_solo(name, body, row, tns0, compiles0)
+
+    def _panel_pool(self):
+        """The chips the panel lane runs on: the node's own pool, or every
+        device of the process where none was carved out."""
+        from .parallel.mesh import shared_pool
+        return self.device_pool or shared_pool()
+
+    def _panel_lane(self) -> str:
+        """The lane's name in `lane_decisions`, by its form: the collective
+        over the chips the node owns, or the same program on its one chip.
+        Read from the pool alone; no setting chooses."""
+        return "panels_mesh" if len(self._panel_pool().devices) > 1 \
+            else "panels"
 
     def _panel_solo(self, name: str, body: dict, row, tns0: int,
                     compiles0: int) -> dict | None:
@@ -2300,7 +2314,7 @@ class NodeService:
         outs = self._search_panels(name, [row], tns0)
         if outs is None:
             return None
-        lane_chosen("serve", "panels")
+        lane_chosen("serve", self._panel_lane())
         self._served_total(name, body, (tracing.now_ns() - tns0) / 1e6,
                            compiles0)
         return outs[0]
@@ -2317,25 +2331,24 @@ class NodeService:
         error."""
         from .search.aggs import panels
         svc = self.indices[name]
-        shards = [list(s.segments) for s in svc.searchers()]
-        segments = [seg for segs in shards for seg in segs]
-        if not panels.servable(rows, segments):
+        view = svc.panel_view(self._panel_pool())
+        if view is None or not panels.servable(rows, view):
             return None
-        panels.ensure_warm(segments)
+        panels.ensure_warm(view)
         outs: list[dict] = []
         step = self._batcher.MAX_BATCH
         for lo in range(0, len(rows), step):
             chunk = rows[lo:lo + step]
-            totals, partials = panels.execute(chunk, shards)
+            totals, partials = panels.execute(chunk, view)
             render = tracing.span("aggs.render", rows=len(chunk))
             with render:
                 took = (render.start_ns - t0_ns) // 1_000_000
                 for qi, row in enumerate(chunk):
                     out = {"took": took, "timed_out": False,
-                           "_shards": {"total": len(shards),
-                                       "successful": len(shards),
+                           "_shards": {"total": view.n_shards,
+                                       "successful": view.n_shards,
                                        "failed": 0},
-                           "hits": {"total": int(totals[qi].sum()),
+                           "hits": {"total": int(totals[qi]),
                                     "max_score": None, "hits": []}}
                     if row.agg is not None:
                         out["aggregations"] = render_aggs(

@@ -35,6 +35,9 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 SHARD_AXIS = "shard"
 REPLICA_AXIS = "replica"
+# the panel lane's one axis (search/aggs/panels.py): the chips a node owns,
+# however many shards the index has
+CHIP_AXIS = "chip"
 
 # The legacy process-wide dispatch lock (PR-11): serializes shard_map
 # programs that run on the SHARED pool (all of jax.devices()). Per-node
@@ -67,6 +70,24 @@ class DevicePool:
     @property
     def is_shared(self) -> bool:
         return self.lock is SHARED_EXEC_LOCK
+
+    def chip_mesh(self) -> Mesh:
+        """Every chip of this pool on one axis, `CHIP_AXIS`: an axis of
+        chips, not of shards, so S shards fit N chips for any S >= 1 and
+        N >= 1 (5 over 4; 5 over 8, where three chips hold nothing and
+        still take part in the collectives)."""
+        with self._mesh_build_lock:
+            mesh = self._meshes.get(CHIP_AXIS)
+            if mesh is None:
+                mesh = self._meshes[CHIP_AXIS] = Mesh(
+                    np.asarray(self.devices), (CHIP_AXIS,))
+        return mesh
+
+    def home_of(self, shard: int) -> int:
+        """The chip (a position in `devices`) that holds a shard's
+        segments: shard number mod the chips owned (5 over 4: 0, 1, 2, 3,
+        0), a pure function of what the node observes."""
+        return shard % len(self.devices)
 
     def mesh_for(self, n_shards: int, n_replicas: int = 1):
         """Smallest (replicas x padded-shards) mesh over this pool that

@@ -3,7 +3,9 @@ date_histogram, terms under a one-term match, filtered count — exact against
 a plain numpy reference and against the general driver's per-segment loop,
 at every batch size and index layout, from a closed set of programs that
 compiles nothing once warm; and the time-out path that keeps packed-eligible
-bodies out of the lane."""
+bodies out of the lane. The node owns the suite's 8 virtual devices, so the
+lane runs its collective form (`panels_mesh`): one program a batch over the
+chip axis (`tests/test_panels_mesh.py` holds it to every other axis)."""
 
 import json
 import threading
@@ -20,6 +22,7 @@ from elasticsearch_tpu.search.aggs import panels
 HOUR = 3_600_000
 BASE = 893_964_617_000            # rally-tracks http_logs' first event
 N_DOCS = 600
+LANE = "panels_mesh"              # the lane's name on more than one chip
 WORDS = ["get", "images", "english", "french", "index", "html", "gif"]
 STATUS = [200, 200, 200, 200, 304, 304, 404, 500]
 
@@ -202,8 +205,7 @@ def test_panels_are_exact(nodes, kind, q, layout):
     for body, resp in zip(bodies, concurrently(node, index, bodies)):
         check(resp, body, live[index])
     moved = _moved(chosen0)
-    assert moved.get("panels", 0) + moved.get("batched", 0) == q
-    assert set(moved) <= {"panels", "batched"}, moved
+    assert moved == {LANE: q}       # leaders and followers alike
     # one batch of exactly q rows, and the first of them through the
     # general driver's per-segment loop
     outs = node._search_batched([(index, b) for b in bodies])
@@ -259,10 +261,10 @@ def test_padded_rows_are_never_rendered(nodes):
     index = layout_id((1, 4, False))
     bodies = bodies_of("hist", 2, seed=5)          # the Q = 4 program
     rows = [panel_of(node, index, b) for b in bodies]
-    shards = [list(s.segments) for s in node.indices[index].searchers()]
-    totals, partials = panels.execute(rows, shards)
-    assert totals.shape == (2, 1) and len(partials) == 2
-    for body, t in zip(bodies, totals.sum(axis=1)):
+    view = node.indices[index].panel_view(node._panel_pool())
+    totals, partials = panels.execute(rows, view)
+    assert totals.shape == (2,) and len(partials) == 2
+    for body, t in zip(bodies, totals):
         assert t == expected(body, live[index])[0]
 
 
@@ -302,9 +304,9 @@ def test_other_bodies_keep_their_path(nodes, name):
         assert node._search_panels(index, [row], 0) is None
     else:
         assert row is None
-    chosen0 = _chosen().get("panels", 0)
+    chosen0 = _chosen().get(LANE, 0)
     resp = node.search(index, json.loads(json.dumps(body)))
-    assert "hits" in resp and _chosen().get("panels", 0) == chosen0
+    assert "hits" in resp and _chosen().get(LANE, 0) == chosen0
 
 
 def test_more_distinct_values_than_the_program_counts(nodes):
@@ -320,7 +322,7 @@ def test_more_distinct_values_than_the_program_counts(nodes):
     chosen0 = _chosen()
     for resp in concurrently(node, index, [body] * 4):
         assert len(resp["aggregations"]["by_status"]["buckets"]) == 20
-    assert "panels" not in _moved(chosen0)
+    assert LANE not in _moved(chosen0)
 
 
 @pytest.mark.parametrize("kind", ["hist", "terms", "count"])
@@ -351,9 +353,7 @@ def test_a_panel_takes_the_lane_whatever_else_holds(nodes, monkeypatch, how,
     for body, resp in zip(bodies, outs):
         check(resp, body, live[index])
     moved = _moved(chosen0)
-    assert moved.get("panels", 0) + moved.get("batched", 0) == \
-        (0 if how == "msearch-group" else 3), moved
-    assert set(moved) <= {"panels", "batched"}, moved
+    assert moved == ({} if how == "msearch-group" else {LANE: 3})
     assert node.indices[index].search_stats["panels"] >= 3
 
 
@@ -365,13 +365,14 @@ def test_the_set_is_enumerable_and_nothing_compiles_once_warm(nodes):
     compile nothing."""
     node, live = nodes
     index = layout_id((5, 4, True))
-    segments = [seg for s in node.indices[index].searchers()
-                for seg in s.segments]
-    members = panels.program_set(segments)
-    buckets = {m[2] for m in members}
-    assert len(members) >= 3 * len(panels.Q_BUCKETS) * len(buckets)
+    view = node.indices[index].panel_view(node._panel_pool())
+    members = panels.program_set(view)
+    # one (row bucket, segments-a-chip bucket) a view: the segment loop is
+    # inside the program
+    assert {m[2:4] for m in members} == {(view.n_pad, view.G)}
+    assert len(members) >= 3 * len(panels.Q_BUCKETS)
     assert {m[0] for m in members} == {"hist", "terms", "count"}
-    panels.ensure_warm(segments)
+    panels.ensure_warm(view)
     compiles0 = device_events_snapshot()[0]
     rng = np.random.default_rng(200)
     sent = 0
@@ -395,13 +396,12 @@ def test_a_new_segment_of_a_known_bucket_compiles_nothing(nodes):
                                     "request": "get html gif",
                                     "status": 200, "size": 1})
     node.refresh(index)
-    sig = {panels._signature(seg)
-           for s in node.indices[index].searchers() for seg in s.segments}
+    sig = node.indices[index].panel_view(node._panel_pool()).signature()
     compiles0 = device_events_snapshot()[0]
-    new = sig - panels._WARM
+    new = sig not in panels._WARM
     assert node.search(index, body)["hits"]["total"] == before + 1
-    assert sig <= panels._WARM
-    if not new:     # same row and postings buckets: nothing to compile
+    assert sig in panels._WARM
+    if not new:     # same row, segment and postings buckets
         assert device_events_snapshot()[0] == compiles0
     node.delete_doc(index, "extra")
     node.refresh(index)
@@ -477,7 +477,7 @@ def test_timed_out_packed_followers_never_enter_the_coalesced_lane(
         return after.get(key, 0) - before.get(key, 0)
     assert moved("packed:batcher_declined") == 15
     assert moved("packed:chosen") == 1
-    assert moved("batched:chosen") == 0 and moved("panels:chosen") == 0
+    assert moved("batched:chosen") == 0 and moved(LANE + ":chosen") == 0
     assert stats["wait_timeouts_total"] - stats0["wait_timeouts_total"] == 15
     assert stats["run_errors_total"] == stats0["run_errors_total"]
 
@@ -498,7 +498,8 @@ def test_timed_out_panels_run_their_own_program_alone(nodes, monkeypatch,
     for body, resp in zip(bodies, out):
         assert isinstance(resp, dict), resp
         check(resp, body, live[index])
-    assert after.get("panels:chosen", 0) - before.get("panels:chosen", 0) == 6
+    assert after.get(LANE + ":chosen", 0) \
+        - before.get(LANE + ":chosen", 0) == 6
     assert after.get("batched:chosen", 0) == before.get("batched:chosen", 0)
     assert stats["wait_timeouts_total"] - stats0["wait_timeouts_total"] == 5
     assert stats["run_errors_total"] == stats0["run_errors_total"]
@@ -515,7 +516,7 @@ def test_a_failing_panel_program_is_its_members_error(nodes, monkeypatch):
     chosen0 = _chosen()
     with pytest.raises(RuntimeError, match="device fell over"):
         node.search(index, body_of("hist", BASE, BASE + HOUR))
-    assert set(_moved(chosen0)) <= {"panels"}
+    assert set(_moved(chosen0)) <= {LANE}
 
 
 # -- the packed lane's absent-term body (serving/packed_view._build_slots) ---------

@@ -83,15 +83,19 @@ def test_block_divides_every_bucket_of_the_lane():
 # interpreter takes what the chip's compiler refuses, so compile for it here.
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
     os.environ.setdefault("TPU_LOG_DIR", "disabled")    # or it logs to /tmp
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:      # noqa: BLE001 — no TPU compiler here
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -112,6 +116,40 @@ def test_kernel_compiles_for_the_chip(one_chip, N, P):
     # 1-D stream into rows of 128
     assert not [ln for ln in text.splitlines()
                 if f"[{P // 128},128]" in ln and " copy(" in ln]
+
+
+@pytest.mark.parametrize("kind,Q", [("hist", 4), ("count", 1), ("terms", 1)])
+def test_panel_programs_compile_for_the_four_chips(topo, kind, Q):
+    """The panel lane's collective programs (search/aggs/panels.py; this
+    file holds every compile for a described chip, so that one worker loads
+    the TPU's library) at the benchmark's shapes: 5 shards over the 2x2's
+    chips, 4 segments a chip of 131,072 rows, which run `_onehot_counts`'
+    blocked scan inside the `shard_map` body as no small CPU test does."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from elasticsearch_tpu.parallel.mesh import CHIP_AXIS
+    from elasticsearch_tpu.search.aggs import panels
+    mesh = Mesh(np.asarray(topo.devices), (CHIP_AXIS,))
+    rows, n_pad = 4 * 4, 131_072
+
+    def sd(shape, dtype, *spec):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, P(*spec)))
+    col = sd((rows, n_pad), jnp.int64, CHIP_AXIS)
+    flag = sd((rows, n_pad), jnp.bool_, CHIP_AXIS)
+    bounds = sd((3, Q), jnp.int64)
+    args, kw = {
+        "hist": ((col, flag, flag, bounds), {}),
+        "count": ((col, flag, flag, col, flag, bounds), {}),
+        "terms": ((col, flag, flag, sd((rows, 1 << 20), jnp.int32, CHIP_AXIS),
+                   sd((rows, n_pad), jnp.int32, CHIP_AXIS),
+                   sd((rows,), jnp.int32, CHIP_AXIS), bounds,
+                   sd((2, rows, Q), jnp.int32, None, CHIP_AXIS)),
+                  {"W": 1 << 10})}[kind]
+    compiled = panels._build_program(kind, mesh, 5).lower(
+        *args, **kw).compile()
+    text = compiled.as_text()
+    assert "all-reduce" in text             # the counts meet on the chips
+    assert f"jit_panel_{kind}" in text      # the roofline reads it by name
 
 
 # -- the whole program ------------------------------------------------------

@@ -3094,6 +3094,7 @@ class NodeService:
         timers, indices) pick up new entries automatically."""
         from .common import device_stats, monitor
         from .common.metrics import (device_events_snapshot,
+                                     packed_consts_snapshot,
                                      packed_gather_snapshot,
                                      packed_render_snapshot,
                                      transfer_snapshot)
@@ -3304,6 +3305,9 @@ class NodeService:
             # es_packed_render_hits_total{form=}: hits the packed lane
             # rendered, by how (vector | patched | dict)
             "packed_render": ("form", packed_render_snapshot()),
+            # es_packed_consts_total{state=}: packed batches by whether
+            # their BM25 scalar operands were on the chip (reused | made)
+            "packed_consts": ("state", packed_consts_snapshot()),
             "tasks": (None, self.tasks.stats()),
             # span tracer: started/retained/sampled-out trace counters,
             # ring-eviction + span-cap drop counters, live gauges

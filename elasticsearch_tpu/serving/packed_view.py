@@ -7,7 +7,7 @@ out, whatever its size. Serving one kernel per segment and fetching three
 result arrays per kernel costs several of them per request and leaves the
 device idle in between. This view makes the whole request batch cost:
 
-    1 H2D (packed i32 slot table) + 1 program + 1 D2H (packed i32 results)
+    1 program, whose dispatch uploads the i32 slot table + 1 D2H (i32 results)
 
 It is the TPU analog of the reference's per-shard search fan-out collapsing
 into a single batched program: the scatter-gather of
@@ -18,12 +18,11 @@ reduce). Term statistics are naturally index-global — equivalent to running
 the DFS phase (search/dfs/DfsPhase.java:57-81) on every request, which is
 *better* scoring parity than per-shard IDF.
 
-The view is immutable w.r.t. the segment set. Liveness lives in the packed
-postings themselves: when a segment's tombstones change (Segment.live_gen
-tracks that) the next search folds them into each field's `doc_ids` — the
-postings of a document that is no longer live become PACKED_PAD_DOC — once a
-change, on the device, and the program gathers no liveness per request.
-IndexService caches the view keyed by segment set.
+The view is immutable w.r.t. the segment set (IndexService caches it keyed by
+that). Liveness lives in the packed postings: when a segment's tombstones
+change (Segment.live_gen) the next search folds them into each field's
+`doc_ids` — a dead document's postings become PACKED_PAD_DOC — once a change,
+on the device, and the program gathers no liveness per request.
 """
 
 from __future__ import annotations
@@ -39,7 +38,8 @@ import jax
 import jax.numpy as jnp
 
 from ..common import tracing
-from ..common.metrics import device_fetch, note_h2d, record_packed_gather
+from ..common.metrics import (device_fetch, note_h2d, record_packed_consts,
+                              record_packed_gather)
 from ..index.segment import Segment, next_pow2
 from ..ops.bm25_sparse import (FOLD_IDS_BLOCK, FOLD_IDS_MAX, PACKED_PAD_DOC,
                                bm25_serve_packed, bm25_serve_packed_filtered,
@@ -154,9 +154,8 @@ class PackedIndexView:
         self.doc_count = sum(s.n_docs for _, s in segments)
 
         # host columns, a row per global doc id: `ids_packed` the _id as text
-        # (tests and the raw render's reference twin read it), `ids_bytes`
-        # its UTF-8 bytes NUL-padded to one width, the column the raw render
-        # gathers its hits' ids from (serving/executor.response_raw)
+        # (tests and the raw render's reference twin read it), `ids_bytes` its
+        # UTF-8 bytes NUL-padded to one width (executor.response_raw's gather)
         max_id = max((max((len(i) for i in s.ids), default=1)
                       for _, s in segments), default=1)
         self.ids_packed = np.full(self.n_pad_total, "", dtype=f"U{max_id}")
@@ -178,6 +177,7 @@ class PackedIndexView:
         self._refused: set[str] = set()   # breaker-refused (≠ absent) fields
         self._filter_cols: dict[str, PackedFilterColumn | None] = {}
         self._filter_stacks: dict[tuple, jax.Array] = {}
+        self._consts: dict[tuple, tuple] = {}   # (field, k1, b) -> _constants
         self.device_calls = 0           # serving counters (observability)
         self.memory_bytes = 0
         self.extended_from_base = False
@@ -528,24 +528,24 @@ class PackedIndexView:
                     np.full((Q, k), -1, np.int64), np.zeros(Q, np.int64))
 
         # everything the host does before the dispatch is one span: the slot
-        # table, the filter descriptors and their uploads (the packed view's
-        # own arrays are device-resident already)
+        # table and the filter descriptors. `dev` is what goes to the device:
+        # host arrays as they are, uploaded by the program's own dispatch
         prep = tracing.span("packed.build_slots")
         with prep:
             packed, S, R = self._build_slots(pf, queries, field, k1, b)
             k_pad = next_pow2(k, floor=8)
-            host = [packed]
+            dev = [packed]
             stack = None
             if any(q.filters for q in queries):
                 fields, *descriptors = \
                     self._filter_descriptors(queries, packed.shape[0])
-                host += descriptors
+                dev += descriptors
                 stack = self._filter_stack(fields)
-            dev = [jnp.asarray(a) for a in host]
-            scalars = (jnp.float32(k1), jnp.float32(b),
-                       jnp.float32(self.avgdl(field)), jnp.float32(0.0))
-            prep.attrs["h2d_bytes"] = \
-                sum(a.nbytes for a in host) + 4 * len(scalars)
+            scalars, state = self._constants(field, k1, b)
+            prep.attrs.update(
+                consts=state, operands=len(dev),
+                h2d_bytes=sum(a.nbytes for a in dev)
+                + (4 * len(scalars) if state == "made" else 0))
             note_h2d(prep.attrs["h2d_bytes"])
         # the form of the program's slot gather rides its `program` span and
         # /_metrics: a chip run that fell back to "sliced" shows there
@@ -579,11 +579,22 @@ class PackedIndexView:
             docs = np.where(scores > -np.inf, docs, -1)
         return scores, docs, hits
 
+    def _constants(self, field: str, k1: float, b: float):
+        """-> ((k1, b, avgdl, 0) as f32 scalars on the device, "reused" or
+        "made"): made once for the view, whose doc_count and sum_dl never
+        change (a refresh builds a new view, and new constants with it)."""
+        key = (field, k1, b)
+        state = "reused" if key in self._consts else "made"
+        if state == "made":
+            self._consts[key] = _device_scalars(k1, b, self.avgdl(field))
+        record_packed_consts(state)
+        return self._consts[key], state
+
     def _build_slots(self, pf: PackedField, queries: list[PackedQuery],
                      field: str, k1: float, b: float):
         """Vectorized slot-table construction: terms -> fixed-CHUNK postings
-        slots scattered into the packed i32[Q_pad, 3S+1] table (host side;
-        `search` uploads it)."""
+        slots scattered into the packed i32[Q_pad, 3S+1] table, a host array
+        that `search` hands to the program. One term lookup a batch."""
         Q = len(queries)
         # Q buckets are {1, 32, 64, 128, ...}: the dynamic batcher produces
         # arbitrary batch sizes, and a compile per pow2 bucket would stall
@@ -592,33 +603,17 @@ class PackedIndexView:
         Q_pad = 1 if Q == 1 else max(32, next_pow2(Q))
         nseg = pf.starts.shape[1]
 
-        qi_l: list[int] = []
-        tid_l: list[int] = []
-        w_l: list[float] = []
+        n_terms = [len(q.terms) for q in queries]
         min_match = np.ones(Q_pad, np.int32)
-        max_terms = 1
-        N = max(self.doc_count, 1)
-        for qi, q in enumerate(queries):
-            tids = pf.term_ids(q.terms) if q.terms else np.empty(0, np.int64)
-            n_terms = len(q.terms)
-            max_terms = max(max_terms, n_terms)
-            if q.operator == "and":
-                min_match[qi] = max(n_terms, 1)
-            else:
-                min_match[qi] = max(q.msm, 1)
-            for t, tid in zip(q.terms, tids):
-                if tid < 0:
-                    continue
-                df = int(pf.df[tid])
-                idf = math.log(1 + (N - df + 0.5) / (df + 0.5))
-                qi_l.append(qi)
-                tid_l.append(int(tid))
-                w_l.append(idf * (k1 + 1) * q.boost)
-
+        min_match[:Q] = [max(n, 1) if q.operator == "and" else max(q.msm, 1)
+                         for q, n in zip(queries, n_terms)]
+        terms = [t for q in queries for t in q.terms]
+        tids = pf.term_ids(terms) if terms else np.empty(0, np.int64)
+        found = tids >= 0
         # R floor matches warmup()'s shapes: two extra rolls cost ~nothing,
         # one avoided compile shape saves seconds of cold p99
-        R = next_pow2(max_terms, floor=4)
-        if not qi_l:
+        R = next_pow2(max(n_terms + [1]), floor=4)
+        if not found.any():
             # no term of the batch is in the index: the batch's floor of S
             # (as below), not a shape of its own that nothing warms
             S = 32 if Q_pad <= 32 else 4
@@ -626,9 +621,16 @@ class PackedIndexView:
             packed[:, 3 * S] = min_match
             return packed, S, R
 
-        qi_a = np.asarray(qi_l, np.int64)
-        tid_a = np.asarray(tid_l, np.int64)
-        w_a = np.asarray(w_l, np.float32)
+        # vectors over the batch's (query, term) pairs. The weight is float64
+        # arithmetic in this order rounded to float32, its log math.log's (a
+        # call a pair: np.log's last bit may differ, and the scores with it)
+        qi_a = np.repeat(np.arange(Q), n_terms)[found]
+        tid_a = tids[found]
+        N, df = max(self.doc_count, 1), pf.df[tid_a]
+        ratio = 1 + (N - df + 0.5) / (df + 0.5)
+        idf = np.array([math.log(x) for x in ratio.tolist()], np.float64)
+        boost = np.array([q.boost for q in queries], np.float64)
+        w_a = (idf * (k1 + 1) * boost[qi_a]).astype(np.float32)
 
         # expand (query, term) -> (query, term, segment), drop empty slices
         lens_e = pf.lens[tid_a]                       # [E, NSEG]
@@ -834,10 +836,9 @@ class PackedIndexView:
                filtered_shapes=((1, 32, 16), (32, 32, 16))) -> None:
         """Precompile the solo + batcher shapes so first queries don't eat a
         multi-second XLA compile (p99 guard): Q in {1, 32} covers every solo
-        and dynamically-batched request (the Q/S buckets in _build_slots
-        steer traffic onto exactly these), for both the plain and the
-        filtered kernel, and the two folds of liveness (empty ones). The
-        persistent compile cache makes this a one-time cost per machine."""
+        and dynamically-batched request (_build_slots' Q/S buckets steer
+        traffic onto these), plain and filtered, with operands of the kinds
+        `search` hands over, and the two folds of liveness (empty ones)."""
         pf = self._fields.get(field)
         if pf is None:
             return
@@ -847,25 +848,24 @@ class PackedIndexView:
             for by_list in (True, False):
                 self._fold(pf, np.empty(0, np.int64), by_list)
         with self._folded_ids(pf) as doc_ids:
-            common = (doc_ids, pf.tf, pf.dl, jnp.float32(1.2),
-                      jnp.float32(0.75), jnp.float32(1.0), jnp.float32(0.0))
+            common = doc_ids, pf.tf, pf.dl, *_device_scalars(1.2, 0.75, 1.0)
             for (q, s, k) in shapes:
                 packed = np.zeros((q, 3 * s + 1), np.int32)
                 packed[:, 3 * s] = 1
-                bm25_serve_packed(jnp.asarray(packed), *common,
+                bm25_serve_packed(packed, *common,
                                   S=s, CHUNK=CHUNK, R=4, k=k)
             for (q, s, k) in filtered_shapes:
                 packed = np.zeros((q, 3 * s + 1), np.int32)
                 packed[:, 3 * s] = 1
                 bm25_serve_packed_filtered(
-                    jnp.asarray(packed), *common,
+                    packed, *common,
                     jnp.zeros((1, self.n_pad_total), jnp.float64),
-                    jnp.full((q, F_RANGE), -1, jnp.int32),
-                    jnp.zeros((q, F_RANGE)), jnp.zeros((q, F_RANGE)),
-                    jnp.zeros((q, F_RANGE), jnp.int32),
-                    jnp.full((q, F_TERM), -1, jnp.int32),
-                    jnp.full((q, F_TERM, F_TERM_VALS), jnp.nan),
-                    jnp.zeros((q, F_TERM), jnp.int32),
+                    np.full((q, F_RANGE), -1, np.int32),
+                    np.zeros((q, F_RANGE)), np.zeros((q, F_RANGE)),
+                    np.zeros((q, F_RANGE), np.int32),
+                    np.full((q, F_TERM), -1, np.int32),
+                    np.full((q, F_TERM, F_TERM_VALS), np.nan),
+                    np.zeros((q, F_TERM), np.int32),
                     S=s, CHUNK=CHUNK, R=4, k=k,
                     FR=F_RANGE, FT=F_TERM, TV=F_TERM_VALS)
 
@@ -879,3 +879,10 @@ def _utf8_rows(ids: np.ndarray) -> np.ndarray:
         return points.astype(np.uint8)
     enc = np.char.encode(ids, "utf-8")
     return enc.view(np.uint8).reshape(ids.shape[0], -1)
+
+
+def _device_scalars(k1: float, b: float, avgdl: float) -> tuple:
+    """(k1, b, avgdl, 0) as the f32 scalar operands of the packed programs:
+    rounded on the host, one transfer, no operation on the device."""
+    return tuple(jax.device_put([np.float32(k1), np.float32(b),
+                                 np.float32(avgdl), np.float32(0.0)]))

@@ -691,3 +691,217 @@ class TestLivenessFold:
                 per_slot.append((operand, dtype))
         assert per_slot == ([] if program == "plain"
                             else [(f"tensor<1x{N}xf64>", "f64")])
+
+
+# -- a batch's operands ride the program's own dispatch (ISSUE 32) ------------
+# The slot table and the filter descriptors go to the jitted call as host
+# arrays, the four BM25 scalars stay on the chip for the view's life, and
+# nothing is made on the device one value at a time before take-off.
+
+def _slots_view():
+    """Three segments of 700 documents: "common" in every one (two CHUNKs a
+    segment), a Zipf-like tail so that df takes many values, tombstones in
+    the first and the last segment folded by a search."""
+    from elasticsearch_tpu.index.segment import SegmentBuilder
+    from elasticsearch_tpu.mapping.mapper import MapperService
+    mapper = MapperService().document_mapper("_doc")
+    rng = np.random.default_rng(32)
+    segs = []
+    for si in range(3):
+        b = SegmentBuilder(seg_id=si + 1)
+        for i in range(700):
+            tail = rng.zipf(1.3, size=int(rng.integers(1, 9))) % 400
+            text = "common " + " ".join(f"w{t}" for t in tail)
+            b.add(mapper.parse({"t": text, "rank": si * 700 + i},
+                               doc_id=f"{si}-{i}"), "_doc")
+        segs.append((si, b.build()))
+    view = PackedIndexView(segs)
+    for si, local in ((0, 3), (0, 44), (2, 699)):
+        segs[si][1].delete_local(local)
+    view.search("t", [PackedQuery(["common"])], k=8)      # folds them
+    return view
+
+
+@pytest.fixture(scope="module")
+def slots_view():
+    return _slots_view()
+
+
+def _random_bodies(n, seed):
+    rng = np.random.default_rng(seed)
+    out = []
+    for _ in range(n):
+        terms = [f"w{t}" for t in rng.zipf(1.3, size=int(rng.integers(2, 6)))
+                 % 420]                     # w400..w419 are not in the index
+        out.append(PackedQuery(terms, boost=float(rng.choice([1.0, 2.5, 0.3])),
+                               operator=str(rng.choice(["or", "and"])),
+                               msm=int(rng.integers(1, 3))))
+    return out
+
+
+SLOT_CASES = {
+    "absent-terms": [PackedQuery(["common", "zzz-absent", "w3", "w401"])],
+    "no-term-in-the-index": [PackedQuery(["nope", "nada"]),
+                             PackedQuery(["w9999"])],
+    "a-body-without-terms": [PackedQuery([]), PackedQuery(["w1"])],
+    "term-longer-than-the-widest": [PackedQuery(["common", "w1" + "x" * 60]),
+                                    PackedQuery(["w2"])],
+    "operator-and": [PackedQuery(["common", "w1", "w2"], operator="and"),
+                     PackedQuery(["w1", "absent"], operator="and")],
+    "minimum-should-match": [PackedQuery(["common", "w1", "w2"], msm=2),
+                             PackedQuery(["w5"], msm=3)],
+    "boosts": [PackedQuery(["common", "w1"], boost=2.5),
+               PackedQuery(["w1", "w7"], boost=0.3),
+               PackedQuery(["w2"], boost=2)],
+    "1-body": _random_bodies(1, 1), "2-bodies": _random_bodies(2, 2),
+    "33-bodies": _random_bodies(33, 33), "256-bodies": _random_bodies(256, 256),
+}
+
+
+@pytest.mark.parametrize("k1,b", [(1.2, 0.75), (0.9, 0.4)])
+@pytest.mark.parametrize("case", SLOT_CASES)
+def test_slot_table_is_the_reference_bit_for_bit(slots_view, case, k1, b):
+    """`_build_slots` (one term lookup a batch, vectors over its (query,
+    term) pairs) against the loop it replaced, kept in slots_reference.py."""
+    import slots_reference
+    queries = SLOT_CASES[case]
+    pf = slots_view.field("t")
+    assert pf.starts.shape[1] == 3 and int(pf.df.max()) == 2100
+    got, S, R = slots_view._build_slots(pf, queries, "t", k1, b)
+    want, S_ref, R_ref = slots_reference.build_slots(
+        slots_view, pf, queries, "t", k1, b)
+    assert (S, R) == (S_ref, R_ref)
+    assert got.dtype == want.dtype == np.int32 and got.shape == want.shape
+    np.testing.assert_array_equal(got, want)       # the weights' bits too
+
+
+def test_idf_is_math_logs_on_every_df(slots_view):
+    """Every df a term of the index has, alone in a body: the weight's bits
+    are `math.log`'s (what the per-segment lane and the reference print)."""
+    import math
+    pf = slots_view.field("t")
+    terms = [str(t) for t in pf.terms]
+    table, S, _ = slots_view._build_slots(
+        pf, [PackedQuery([t]) for t in terms], "t", 1.2, 0.75)
+    N = slots_view.doc_count
+    want = np.array([math.log(1 + (N - d + 0.5) / (d + 0.5)) * 2.2
+                     for d in pf.df.tolist()], np.float32)
+    np.testing.assert_array_equal(table[:len(terms), 2 * S].view(np.float32),
+                                  want)
+    assert len(set(pf.df.tolist())) > 40
+
+
+@pytest.fixture()
+def device_calls(monkeypatch):
+    """What a search asks of the device from Python besides its program:
+    eager operations (every primitive applied outside a jit is built by
+    `dispatch.xla_primitive_callable`: `apply_primitive`'s first line) and
+    transfers of its own (`jnp.asarray`, `jax.device_put`)."""
+    import jax
+    import jax.numpy as jnp
+    from jax._src import dispatch
+    seen = {"eager": [], "transfers": 0}
+    real = dispatch.xla_primitive_callable
+
+    def callable_of(prim, **params):
+        seen["eager"].append(prim.name)
+        return real(prim, **params)
+
+    def counted(fn):
+        def call(*a, **kw):
+            seen["transfers"] += 1
+            return fn(*a, **kw)
+        return call
+    monkeypatch.setattr(dispatch, "xla_primitive_callable", callable_of)
+    monkeypatch.setattr(jnp, "asarray", counted(jnp.asarray))
+    monkeypatch.setattr(jax, "device_put", counted(jax.device_put))
+    return seen
+
+
+def _traced_search(view, queries, k=10, field="t"):
+    """-> (the search's result, its `packed.build_slots` attributes, the
+    `program` spans it opened, the bytes it reported as uploaded)."""
+    from elasticsearch_tpu.common import tracing
+    with tracing.Tracer().request("test") as trace:
+        out = view.search(field, queries, k=k)
+    prep, = [s.attrs for s in trace.spans if s.name == "packed.build_slots"]
+    programs = [s.attrs["site"] for s in trace.spans if s.name == "program"]
+    return out, prep, programs, trace.h2d_bytes
+
+
+def _range_filter(lo, hi):
+    from elasticsearch_tpu.search.query_dsl import RangeNode
+    return ((False, RangeNode(field_name="rank",
+                              bounds_per_query=[(lo, hi, True, False)])),)
+
+
+WARM_CASES = {
+    "solo": ([PackedQuery(["common", "w1"])], 1, "ops:bm25_serve_packed"),
+    "five-in-the-32-row-bucket": (
+        [PackedQuery(["common", f"w{i}"]) for i in range(5)], 1,
+        "ops:bm25_serve_packed"),
+    "filtered": ([PackedQuery(["common"], filters=_range_filter(0, 900))],
+                 8, "ops:bm25_serve_packed_filtered"),
+}
+
+
+@pytest.mark.parametrize("case", WARM_CASES)
+def test_warm_search_is_one_program_and_nothing_else(case, device_calls):
+    queries, operands, site = WARM_CASES[case]
+    view = _slots_view()
+    first, prep, _, _ = _traced_search(view, queries)
+    # the fold's search made the constants; a filter column is built once
+    assert prep["consts"] == "reused" and prep["operands"] == operands
+    device_calls["eager"].clear()
+    device_calls["transfers"] = 0
+    calls0 = view.device_calls
+    again, prep, programs, uploaded = _traced_search(view, queries)
+    assert device_calls == {"eager": [], "transfers": 0}
+    assert programs == [site] and view.device_calls == calls0 + 1
+    assert prep["consts"] == "reused" and prep["operands"] == operands
+    Q_pad = 1 if len(queries) == 1 else 32
+    table = 4 * Q_pad * (3 * 32 + 1)
+    descriptors = Q_pad * (2 * (4 + 8 + 8 + 4) + 2 * (4 + 4 * 8 + 4))
+    assert prep["h2d_bytes"] == uploaded \
+        == table + (descriptors if operands == 8 else 0)
+    for a, b in zip(first, again):
+        np.testing.assert_array_equal(a, b)
+    if operands == 8:
+        assert int(again[2][0]) == 900 - 2      # two tombstones in range
+
+
+def test_constants_are_made_once_a_view_and_anew_after_a_refresh(tmp_path):
+    node = make_node(tmp_path, n_shards=2, segments=2)
+    svc = node.indices["idx"]
+    queries = [PackedQuery(["quick", "fox"])]
+
+    def search(view):
+        return _traced_search(view, queries, k=8, field="title")[:2]
+
+    old = svc.packed_view()
+    _, prep = search(old)
+    assert prep["consts"] == "made" and prep["operands"] == 1
+    table = 4 * (3 * 32 + 1)
+    assert prep["h2d_bytes"] == table + 16       # the four scalars, once
+    _, prep = search(old)
+    assert prep["consts"] == "reused" and prep["h2d_bytes"] == table
+    k1, b, avgdl, zero = old._consts[("title", 1.2, 0.75)]
+    assert [x.dtype for x in (k1, b, avgdl, zero)] == [np.float32] * 4
+    assert (float(k1), float(b), float(zero)) \
+        == (float(np.float32(1.2)), 0.75, 0.0)
+    assert float(avgdl) == float(np.float32(old.avgdl("title")))
+
+    node.index_doc("idx", "long", {"title": "fox " * 40, "rank": 99})
+    node.refresh("idx")
+    new = svc.packed_view()
+    assert new is not old and new.avgdl("title") > old.avgdl("title")
+    (scores, docs, hits), prep = search(new)
+    assert prep["consts"] == "made" and int(hits[0]) == 6
+    assert float(new._consts[("title", 1.2, 0.75)][2]) \
+        == float(np.float32(new.avgdl("title")))
+    assert old._consts[("title", 1.2, 0.75)][2] is avgdl   # the old view's own
+    # the scores are the per-segment lane's, which reads avgdl for itself
+    body = {"query": {"match": {"title": "quick fox"}}}
+    assert hits_of(node.search("idx", body)) \
+        == hits_of(general_path(node, "idx", body))
+    node.close()

@@ -60,15 +60,16 @@ def http(tmp_path_factory):
 
 def _scrape(req) -> dict:
     """`/_metrics` -> {family: {label value or "": number}} for the span,
-    gap, flight and transfer families."""
+    gap, flight, transfer and packed-constants families."""
     out: dict[str, dict] = {}
     for line in req("GET", "/_metrics").splitlines():
-        m = re.match(r'^(es_(?:span|device_gap|device_flight|transfer)\w*)'
-                     r'\{(.*)\} (\S+)$', line)
+        m = re.match(r'^(es_(?:span|device_gap|device_flight|transfer'
+                     r'|packed_consts)\w*)\{(.*)\} (\S+)$', line)
         if not m:
             continue
         labels = dict(p.split("=", 1) for p in m.group(2).split(","))
-        key = (labels.get("span") or labels.get("during") or '""').strip('"')
+        key = (labels.get("span") or labels.get("during")
+               or labels.get("state") or '""').strip('"')
         out.setdefault(m.group(1), {})[key] = float(m.group(3))
     return out
 
@@ -164,7 +165,15 @@ def test_transfer_counters_reach_the_packed_lane(http):
     spans = {s["name"]: s for s in
              req("GET", f"/_traces/{trace['trace_id']}?format=chrome")
              ["traceEvents"] if s.get("ph") == "X"}
-    assert spans["packed.build_slots"]["args"]["h2d_bytes"] == up
+    prep = spans["packed.build_slots"]["args"]
+    assert prep["h2d_bytes"] == up
+    # the table is the one host array the program's dispatch uploads; the
+    # BM25 scalars were made by the fixture's warming search and stay
+    assert (prep["operands"], prep["consts"]) == (1, "reused")
+    consts = {state: after["es_packed_consts_total"][state]
+              - before["es_packed_consts_total"][state]
+              for state in ("reused", "made")}
+    assert consts == {"reused": 1, "made": 0}
     assert spans["packed.d2h"]["args"]["d2h_bytes"] == down
     # the leader's tree: packed_batch is the parent of its whole stay
     stay = spans["packed_batch"]["args"]["span_id"]

@@ -402,21 +402,40 @@ class Smoke:
     # -- (b) 256 bool{must: match, filter: range on bytes}, bounds > 2^32 -------
 
     def step_b(self) -> None:
+        # every bound is the value of a document that matches the body's
+        # text (of one with a low half in 0..7 where there is one: others
+        # then sit one unit to either side). A body in four closes both
+        # ends, the others open one or both: an open end that is stepped by
+        # less than the device's float64 can hold reads as a closed one
+        # (`lt` as `lte` on the TPU, PR 33), and the total is then too high
         specs = []
-        for _ in range(Q_BATCH):
-            h = sorted(self.rng.integers(1, 256, 2).tolist())
-            specs.append((self.terms(), (h[0] << 32) + 3, (h[1] << 32) + 4))
+        for qi in range(Q_BATCH):
+            ts = self.terms()
+            v = self.corpus.bytes[self.ref.match(ts)[0]]
+            near = v[(v & 0xFFFFFFFF) < 8]
+            lo, hi = sorted(self.rng.choice(
+                near if len(near) else v if len(v) else self.corpus.bytes,
+                2).tolist())
+            specs.append((ts, ("gte", "gt")[qi % 2], lo,
+                          ("lte", "lt")[qi // 2 % 2], hi))
         out = self.request("b", "POST", "/_msearch", msearch_payload([
             {"query": {"bool": {
                 "must": [{"match": {"body": self.text(ts)}}],
-                "filter": [{"range": {"bytes": {"gte": lo, "lte": hi}}}]}},
+                "filter": [{"range": {"bytes": {lo_op: lo, hi_op: hi}}}]}},
              "size": TOP_K, "_source": False}
-            for ts, lo, hi in specs]))["responses"]
+            for ts, lo_op, lo, hi_op, hi in specs]))["responses"]
         check(len(out) == Q_BATCH, f"b: {len(out)} responses")
-        for qi, (resp, (ts, lo, hi)) in enumerate(zip(out, specs)):
+        on_open_end = 0
+        for qi, (resp, (ts, lo_op, lo, hi_op, hi)) in enumerate(
+                zip(out, specs)):
             d, s = self.ref.match(ts)
-            keep = (self.corpus.bytes[d] >= lo) & (self.corpus.bytes[d] <= hi)
+            v = self.corpus.bytes[d]
+            keep = ((v > lo) if lo_op == "gt" else (v >= lo)) \
+                & ((v < hi) if hi_op == "lt" else (v <= hi))
+            on_open_end += int(((v == lo) & (lo_op == "gt")).sum()
+                               + ((v == hi) & (hi_op == "lt")).sum())
             check_hits(f"b[{qi}]", resp, d[keep], s[keep], TOP_K)
+        check(on_open_end > 0, "b: no candidate sat on an open end")
 
     # -- (c) eight solo _search, size 10, with _source (fetch phase) -------------
 

@@ -323,15 +323,30 @@ def bulk_docs_histogram() -> dict[int, int]:
 _PACKED_GATHER = {"blocked": 0, "sliced": 0}
 
 
-def record_packed_gather(form: str) -> None:
+def record_packed_dispatch(form: str, program: str) -> None:
+    """One batch of the packed lane: by the form of its program's slot
+    gather and by which program it was (`_PACKED_BATCHES`, below)."""
     with _DEVICE_LOCK:
         _PACKED_GATHER[form] += 1
+        _PACKED_BATCHES[program] += 1
 
 
 def packed_gather_snapshot() -> dict:
     with _DEVICE_LOCK:
         return {form: {"dispatches_total": n}
                 for form, n in _PACKED_GATHER.items()}
+
+
+# packed batches by the program that answered them (PackedIndexView.search):
+# es_packed_batches_total{program=}. "filtered" carried columnar filters in
+# some body (ops/bm25_sparse.bm25_serve_packed_filtered), "plain" in none.
+_PACKED_BATCHES = {"plain": 0, "filtered": 0}
+
+
+def packed_batches_snapshot() -> dict:
+    with _DEVICE_LOCK:
+        return {program: {"total": n}
+                for program, n in _PACKED_BATCHES.items()}
 
 
 # hits the packed lane rendered, by how (serving/executor.respond):

@@ -3094,6 +3094,7 @@ class NodeService:
         timers, indices) pick up new entries automatically."""
         from .common import device_stats, monitor
         from .common.metrics import (device_events_snapshot,
+                                     packed_batches_snapshot,
                                      packed_consts_snapshot,
                                      packed_gather_snapshot,
                                      packed_render_snapshot,
@@ -3308,6 +3309,9 @@ class NodeService:
             # es_packed_consts_total{state=}: packed batches by whether
             # their BM25 scalar operands were on the chip (reused | made)
             "packed_consts": ("state", packed_consts_snapshot()),
+            # es_packed_batches_total{program=}: packed batches by the
+            # program that answered them (plain | filtered)
+            "packed_batches": ("program", packed_batches_snapshot()),
             "tasks": (None, self.tasks.stats()),
             # span tracer: started/retained/sampled-out trace counters,
             # ring-eviction + span-cap drop counters, live gauges

@@ -61,6 +61,19 @@ PACKED_PAD_DOC = int(jnp.iinfo(jnp.int32).max)
 FOLD_IDS_BLOCK = 32
 FOLD_IDS_MAX = 8192
 
+# Candidate rows (a block of candidate columns of every query) that the
+# filtered program's `packed.filters` gathers at a time. On the TPU the
+# gather's result comes out as [rows, NC] with the NC columns padded to a
+# tile's 128 lanes, 512 B a row and float32 half: 2 GB a block. All 256
+# queries x 256 slots at once are 16 GB, and the chip's compiler refused the
+# program (PERF.md §6, PR 33).
+FILTER_ROWS = 1 << 22
+
+# A range slot's `fr_how`: bits. An open end is compared strictly by the
+# program: a bound stepped one float64 ulp is not a value the TPU's float64
+# (a pair of float32, about 48 bits) holds.
+RANGE_NEGATED, RANGE_LO_OPEN, RANGE_HI_OPEN = 1, 2, 4
+
 
 def required_padding(n_postings: int, max_df: int) -> int:
     """Physical postings padding so any term slice start+Wt stays in bounds
@@ -163,7 +176,7 @@ def bm25_serve_packed_filtered(packed_q: jax.Array, doc_ids: jax.Array,
                                k1, b, avgdl, const,
                                fcols: jax.Array,
                                fr_col: jax.Array, fr_lo: jax.Array,
-                               fr_hi: jax.Array, fr_neg: jax.Array,
+                               fr_hi: jax.Array, fr_how: jax.Array,
                                ft_col: jax.Array, ft_targets: jax.Array,
                                ft_neg: jax.Array, *,
                                S: int, CHUNK: int, R: int, k: int,
@@ -177,7 +190,8 @@ def bm25_serve_packed_filtered(packed_q: jax.Array, doc_ids: jax.Array,
         ordinals in the view's union vocabulary (-1 = missing).
     Range slots (AND-ed): fr_col i32[Q, FR] (index into fcols; -1 = slot
         unused, -2 = active but the field has no column: matches nothing),
-        fr_lo/fr_hi f64[Q, FR] INCLUSIVE bounds, fr_neg i32[Q, FR].
+        fr_lo/fr_hi f64[Q, FR] bounds, fr_how i32[Q, FR]: RANGE_NEGATED |
+        RANGE_LO_OPEN | RANGE_HI_OPEN (an end is inclusive unless open).
     Term slots (AND-ed; OR within a slot's TV targets): ft_col i32[Q, FT],
         ft_targets f64[Q, FT, TV] (NaN = unused target), ft_neg i32[Q, FT].
 
@@ -187,7 +201,7 @@ def bm25_serve_packed_filtered(packed_q: jax.Array, doc_ids: jax.Array,
     return _serve_packed_impl(
         packed_q, doc_ids, tf, dl, k1, b, avgdl, const,
         S=S, CHUNK=CHUNK, R=R, k=k, gather=packed_gather_form(),
-        filters=(fcols, fr_col, fr_lo, fr_hi, fr_neg,
+        filters=(fcols, fr_col, fr_lo, fr_hi, fr_how,
                  ft_col, ft_targets, ft_neg, FR, FT, TV))
 
 
@@ -320,10 +334,10 @@ def _serve_packed_impl(packed_q, doc_ids, tf, dl, k1, b, avgdl, const, *,
         keep = ends & (count >= min_match[:, None].astype(jnp.float32))
 
     if filters is not None:
-        (fcols, fr_col, fr_lo, fr_hi, fr_neg,
+        (fcols, fr_col, fr_lo, fr_hi, fr_how,
          ft_col, ft_targets, ft_neg, FR, FT, TV) = filters
 
-        def eval_one(dq, fr_c, fr_l, fr_h, fr_n, ft_c, ft_t, ft_n):
+        def eval_one(dq, fr_c, fr_l, fr_h, fr_w, ft_c, ft_t, ft_n):
             ok = jnp.ones(dq.shape, bool)
             # gather every column at the candidate slots FIRST, then pick
             # the slot's column: [NC, W] per query. Picking the column
@@ -332,9 +346,12 @@ def _serve_packed_impl(packed_q, doc_ids, tf, dl, k1, b, avgdl, const, *,
             vals = fcols.take(dq, axis=1, mode="clip")
             for fi in range(FR):
                 v = jnp.take(vals, jnp.maximum(fr_c[fi], 0), axis=0)
-                m = (v >= fr_l[fi]) & (v <= fr_h[fi])
+                m = jnp.where((fr_w[fi] & RANGE_LO_OPEN) != 0,
+                              v > fr_l[fi], v >= fr_l[fi]) \
+                    & jnp.where((fr_w[fi] & RANGE_HI_OPEN) != 0,
+                                v < fr_h[fi], v <= fr_h[fi])
                 m = jnp.where(fr_c[fi] == -2, False, m)  # absent column
-                m = jnp.where(fr_n[fi] > 0, ~m, m)
+                m = jnp.where((fr_w[fi] & RANGE_NEGATED) != 0, ~m, m)
                 ok = ok & jnp.where(fr_c[fi] != -1, m, True)
             for fi in range(FT):
                 v = jnp.take(vals, jnp.maximum(ft_c[fi], 0), axis=0)
@@ -345,8 +362,25 @@ def _serve_packed_impl(packed_q, doc_ids, tf, dl, k1, b, avgdl, const, *,
             return ok
 
         with jax.named_scope("packed.filters"):
-            keep = keep & jax.vmap(eval_one)(
-                d, fr_col, fr_lo, fr_hi, fr_neg, ft_col, ft_targets, ft_neg)
+            # A block of candidate columns at a time, and only the blocks
+            # that hold a candidate: the sort left every query's unused
+            # lanes (PAD) last, so past the longest query's last candidate
+            # there is nothing to filter, and a batch padded to the next
+            # power of two of slots does not pay for the padding. A block
+            # is FILTER_ROWS candidate rows.
+            cols = max(1, min(W, FILTER_ROWS // Q))
+            longest = jnp.max(jnp.sum(is_real, axis=1, dtype=jnp.int32))
+
+            def filter_block(i, ok):
+                at = i * jnp.int32(cols)
+                got = jax.vmap(eval_one)(
+                    jax.lax.dynamic_slice_in_dim(d, at, cols, axis=1),
+                    fr_col, fr_lo, fr_hi, fr_how, ft_col, ft_targets, ft_neg)
+                return jax.lax.dynamic_update_slice_in_dim(ok, got, at, axis=1)
+
+            n_blocks = (longest + jnp.int32(cols - 1)) // jnp.int32(cols)
+            keep = keep & jax.lax.fori_loop(
+                jnp.int32(0), n_blocks, filter_block, jnp.zeros((Q, W), bool))
 
     with jax.named_scope("packed.topk"):
         masked = jnp.where(keep, total + const, -jnp.inf)

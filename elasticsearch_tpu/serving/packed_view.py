@@ -27,6 +27,7 @@ on the device, and the program gathers no liveness per request.
 
 from __future__ import annotations
 
+import bisect
 import contextlib
 import math
 import re
@@ -39,9 +40,10 @@ import jax.numpy as jnp
 
 from ..common import tracing
 from ..common.metrics import (device_fetch, note_h2d, record_packed_consts,
-                              record_packed_gather)
+                              record_packed_dispatch)
 from ..index.segment import Segment, next_pow2
 from ..ops.bm25_sparse import (FOLD_IDS_BLOCK, FOLD_IDS_MAX, PACKED_PAD_DOC,
+                               RANGE_HI_OPEN, RANGE_LO_OPEN, RANGE_NEGATED,
                                bm25_serve_packed, bm25_serve_packed_filtered,
                                packed_fold_ids, packed_fold_live,
                                packed_gather_form)
@@ -535,23 +537,31 @@ class PackedIndexView:
             packed, S, R = self._build_slots(pf, queries, field, k1, b)
             k_pad = next_pow2(k, floor=8)
             dev = [packed]
-            stack = None
+            stack, columns = None, 0
             if any(q.filters for q in queries):
-                fields, *descriptors = \
-                    self._filter_descriptors(queries, packed.shape[0])
-                dev += descriptors
-                stack = self._filter_stack(fields)
+                described = tracing.span(
+                    "packed.filter_descriptors",
+                    filters=sum(len(q.filters) for q in queries))
+                with described:
+                    fields, *descriptors = \
+                        self._filter_descriptors(queries, packed.shape[0])
+                    dev += descriptors
+                    stack = self._filter_stack(fields)
+                    described.attrs["columns"] = columns = len(fields)
             scalars, state = self._constants(field, k1, b)
             prep.attrs.update(
                 consts=state, operands=len(dev),
                 h2d_bytes=sum(a.nbytes for a in dev)
                 + (4 * len(scalars) if state == "made" else 0))
             note_h2d(prep.attrs["h2d_bytes"])
-        # the form of the program's slot gather rides its `program` span and
-        # /_metrics: a chip run that fell back to "sliced" shows there
+        # the form of the program's slot gather and which program it is ride
+        # its `program` span and /_metrics: a chip run that fell back to
+        # "sliced" shows there
         form = packed_gather_form()
+        program = "plain" if stack is None else "filtered"
         with self._folded_ids(pf) as doc_ids, \
-                tracing.program_attrs(gather=form):
+                tracing.program_attrs(gather=form, program=program,
+                                      columns=columns):
             if stack is not None:
                 out = bm25_serve_packed_filtered(
                     dev[0], doc_ids, pf.tf, pf.dl, *scalars,
@@ -562,7 +572,7 @@ class PackedIndexView:
                     dev[0], doc_ids, pf.tf, pf.dl, *scalars,
                     S=S, CHUNK=CHUNK, R=R, k=k_pad)
         self.device_calls += 1
-        record_packed_gather(form)
+        record_packed_dispatch(form, program)
         fetch = tracing.span("packed.d2h")
         with fetch:
             arr = device_fetch(out)          # the ONE D2H transfer
@@ -690,35 +700,38 @@ class PackedIndexView:
                 self.breaker.add_estimate(self.n_pad_total * 8)
             except CircuitBreakingException as e:
                 raise FilterColumnRefused(name) from e
-        if has_num:
-            vals = np.full(self.n_pad_total, np.nan)
-            for ei, (_, seg) in enumerate(self.entries):
-                nc = seg.numerics.get(name)
-                if nc is None or seg.n_docs == 0:
-                    continue
-                base = int(self.bases[ei])
-                v = np.asarray(nc.vals).astype(np.float64)
-                miss = np.asarray(nc.missing)
-                n = min(seg.n_pad, len(v))
-                vals[base:base + n] = np.where(miss[:n], np.nan, v[:n])
-            col = PackedFilterColumn("numeric", jnp.asarray(vals))
-        else:
-            vocab = sorted(set().union(*(
-                seg.keywords[name].values for _, seg in self.entries
-                if name in seg.keywords)))
-            union_of = {v: i for i, v in enumerate(vocab)}
-            vals = np.full(self.n_pad_total, -1.0)
-            for ei, (_, seg) in enumerate(self.entries):
-                kc = seg.keywords.get(name)
-                if kc is None or seg.n_docs == 0:
-                    continue
-                base = int(self.bases[ei])
-                lut = np.array([union_of[v] for v in kc.values] + [-1.0])
-                ords = np.asarray(kc.ords)
-                n = min(seg.n_pad, len(ords))
-                vals[base:base + n] = lut[ords[:n]]
-            col = PackedFilterColumn("keyword", jnp.asarray(vals),
-                                     vocab=vocab)
+        with tracing.span("packed.filter_column", field=name,
+                          kind="numeric" if has_num else "keyword",
+                          bytes=self.n_pad_total * 8):
+            if has_num:
+                vals = np.full(self.n_pad_total, np.nan)
+                for ei, (_, seg) in enumerate(self.entries):
+                    nc = seg.numerics.get(name)
+                    if nc is None or seg.n_docs == 0:
+                        continue
+                    base = int(self.bases[ei])
+                    v = np.asarray(nc.vals).astype(np.float64)
+                    miss = np.asarray(nc.missing)
+                    n = min(seg.n_pad, len(v))
+                    vals[base:base + n] = np.where(miss[:n], np.nan, v[:n])
+                col = PackedFilterColumn("numeric", jnp.asarray(vals))
+            else:
+                vocab = sorted(set().union(*(
+                    seg.keywords[name].values for _, seg in self.entries
+                    if name in seg.keywords)))
+                union_of = {v: i for i, v in enumerate(vocab)}
+                vals = np.full(self.n_pad_total, -1.0)
+                for ei, (_, seg) in enumerate(self.entries):
+                    kc = seg.keywords.get(name)
+                    if kc is None or seg.n_docs == 0:
+                        continue
+                    base = int(self.bases[ei])
+                    lut = np.array([union_of[v] for v in kc.values] + [-1.0])
+                    ords = np.asarray(kc.ords)
+                    n = min(seg.n_pad, len(ords))
+                    vals[base:base + n] = lut[ords[:n]]
+                col = PackedFilterColumn("keyword", jnp.asarray(vals),
+                                         vocab=vocab)
         self.memory_bytes += self.n_pad_total * 8
         self._filter_cols[name] = col
         return col
@@ -734,7 +747,7 @@ class PackedIndexView:
         return st
 
     def _filter_descriptors(self, queries: list[PackedQuery], Q_pad: int):
-        """-> (fields tuple, fr_col, fr_lo, fr_hi, fr_neg, ft_col,
+        """-> (fields tuple, fr_col, fr_lo, fr_hi, fr_how, ft_col,
         ft_targets, ft_neg) numpy descriptor arrays for the kernel.
         Raises FilterColumnRefused if a needed column was breaker-refused."""
         from ..search.query_dsl import RangeNode, TermFilterNode
@@ -752,7 +765,7 @@ class PackedIndexView:
         fr_col = np.full((Q_pad, F_RANGE), -1, np.int32)
         fr_lo = np.zeros((Q_pad, F_RANGE))
         fr_hi = np.zeros((Q_pad, F_RANGE))
-        fr_neg = np.zeros((Q_pad, F_RANGE), np.int32)
+        fr_how = np.zeros((Q_pad, F_RANGE), np.int32)
         ft_col = np.full((Q_pad, F_TERM), -1, np.int32)
         ft_targets = np.full((Q_pad, F_TERM, F_TERM_VALS), np.nan)
         ft_neg = np.zeros((Q_pad, F_TERM), np.int32)
@@ -766,31 +779,34 @@ class PackedIndexView:
                     if col is not None and col.kind == "keyword":
                         # lexicographic bounds -> inclusive ordinal bounds
                         # over the union vocab (mirrors RangeNode's kc path)
-                        import bisect as _b
                         l = 0
                         if lo is not None:
-                            l = _b.bisect_left(col.vocab, str(lo))
+                            l = bisect.bisect_left(col.vocab, str(lo))
                             if not inc_lo and l < len(col.vocab) \
                                     and col.vocab[l] == str(lo):
                                 l += 1
                         h = len(col.vocab) - 1
                         if hi is not None:
-                            h = _b.bisect_right(col.vocab, str(hi)) - 1
+                            h = bisect.bisect_right(col.vocab, str(hi)) - 1
                             if not inc_hi and h >= 0 \
                                     and col.vocab[h] == str(hi):
                                 h -= 1
-                        flo, fhi = float(l), float(h)
+                        flo, fhi, how = float(l), float(h), 0
                     else:
+                        # the bounds as they were sent; the program compares
+                        # an open end strictly. (A bound stepped one float64
+                        # ulp is not a value the TPU's float64 holds, a pair
+                        # of float32 of about 48 bits: it rounded back onto
+                        # a date in milliseconds and `lt` read as `lte`
+                        # there; PERF.md §6, PR 33.)
                         flo = -np.inf if lo is None else float(lo)
                         fhi = np.inf if hi is None else float(hi)
-                        if lo is not None and not inc_lo:
-                            flo = np.nextafter(flo, np.inf)
-                        if hi is not None and not inc_hi:
-                            fhi = np.nextafter(fhi, -np.inf)
+                        how = (0 if inc_lo else RANGE_LO_OPEN) \
+                            | (0 if inc_hi else RANGE_HI_OPEN)
                     fr_col[qi, ri] = ci
                     fr_lo[qi, ri] = flo
                     fr_hi[qi, ri] = fhi
-                    fr_neg[qi, ri] = int(neg)
+                    fr_how[qi, ri] = how | (RANGE_NEGATED if neg else 0)
                     ri += 1
                 elif isinstance(node, TermFilterNode):
                     ci, col = col_idx(node.field_name)
@@ -800,8 +816,7 @@ class PackedIndexView:
                         if col is None:
                             break
                         if col.kind == "keyword":
-                            import bisect as _b
-                            p = _b.bisect_left(col.vocab, str(v))
+                            p = bisect.bisect_left(col.vocab, str(v))
                             ft_targets[qi, ti, vi] = float(p) \
                                 if p < len(col.vocab) \
                                 and col.vocab[p] == str(v) else np.nan
@@ -813,7 +828,7 @@ class PackedIndexView:
                     ft_col[qi, ti] = ci
                     ft_neg[qi, ti] = int(neg)
                     ti += 1
-        return (tuple(fields), fr_col, fr_lo, fr_hi, fr_neg,
+        return (tuple(fields), fr_col, fr_lo, fr_hi, fr_how,
                 ft_col, ft_targets, ft_neg)
 
     # -- host-side doc resolution ------------------------------------------
@@ -835,10 +850,12 @@ class PackedIndexView:
                shapes=((1, 32, 16), (32, 32, 16), (1, 64, 16)),
                filtered_shapes=((1, 32, 16), (32, 32, 16))) -> None:
         """Precompile the solo + batcher shapes so first queries don't eat a
-        multi-second XLA compile (p99 guard): Q in {1, 32} covers every solo
-        and dynamically-batched request (_build_slots' Q/S buckets steer
-        traffic onto these), plain and filtered, with operands of the kinds
-        `search` hands over, and the two folds of liveness (empty ones)."""
+        multi-second XLA compile (p99 guard), with operands of the kinds
+        `search` hands over: Q in {1, 32} at 32 slots and k 16, plain (and
+        Q 1 at 64 slots) and filtered on one column, and the two folds of
+        liveness (empty ones). What an `_msearch` runs (Q 256, more slots, a
+        second column, k 1024) compiles on its first batch: a benchmark
+        cell's replay."""
         pf = self._fields.get(field)
         if pf is None:
             return

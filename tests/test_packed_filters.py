@@ -3,9 +3,29 @@ ONE-program kernel (BASELINE config #2 shape), with exact parity against the
 general path (VERDICT r3 task 2a).
 """
 
+import copy
+import json
+import os
+import re
+import sys
+
+import numpy as np
 import pytest
 
+from elasticsearch_tpu.common import tracing
+from elasticsearch_tpu.common.metrics import render_openmetrics
 from elasticsearch_tpu.node import NodeService
+from elasticsearch_tpu.rest.http_server import _parse_bulk
+
+BENCH = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "benchmark")
+if BENCH not in sys.path:
+    sys.path.insert(0, BENCH)
+
+import compare    # noqa: E402 — the benchmark's own modules
+import corpus     # noqa: E402
+import traffic    # noqa: E402
+from reference import Reference    # noqa: E402
 
 MAPPING = {"_doc": {"properties": {
     "body": {"type": "text"},
@@ -164,3 +184,280 @@ class TestPackedFilterEdges:
                       "filter": [{"term": {"tag": "a"}}]}}
         p, g = _both_lanes(node, q)
         assert _check_parity(p, g) == {"0"}
+
+
+# -- BASELINE config #2 as the benchmark states it ---------------------------
+# `benchmark/configs/wiki-filtered-5s.json` at 3,000 documents from
+# `benchmark/corpus.py`, the three body templates of the cell
+# `wiki.filtered-top1000` through `NodeService.msearch`, against the
+# benchmark's plain reference (`benchmark/reference.py`).
+
+SEED = 2 ** 31 + 33
+N_DOCS = 3000
+
+
+def _bench_json(*parts):
+    with open(os.path.join(BENCH, *parts)) as f:
+        return json.load(f)
+
+
+CFG = {**_bench_json("configs", "wiki-filtered-5s.json"), "documents": N_DOCS}
+CELL = _bench_json("workloads", "wiki.filtered-top1000.json")
+TOL = compare.load_limits(CELL)["score_rel_err_max"]
+# the cell's templates by the columns they filter on
+TEMPLATES = {"timestamp": [0], "month": [2], "both": [0, 1, 2]}
+
+
+def _load(node, cfg, seed=SEED):
+    made = corpus.mapping(cfg)
+    node.create_index(cfg["index"], settings=made["settings"],
+                      mappings=made["mappings"])
+    for k in range(corpus.n_chunks(cfg)):
+        items = node.bulk(_parse_bulk(corpus.payload(cfg, seed, k),
+                                      cfg["index"]))
+        assert not any(i["index"].get("status", 201) >= 300 for i in items)
+    node.refresh(cfg["index"])
+
+
+@pytest.fixture(scope="module")
+def wikif(tmp_path_factory):
+    n = NodeService(data_path=str(tmp_path_factory.mktemp("wikif")))
+    _load(n, CFG)
+    yield n, Reference(CFG, SEED)
+    n.close()
+
+
+def _bodies(which: str, q: int, seed: int) -> list[dict]:
+    """`q` bodies drawn from the cell's own templates `which`."""
+    rng = np.random.default_rng(seed)
+    kinds = rng.choice(TEMPLATES[which], size=q)
+    kinds[0] = TEMPLATES[which][len(TEMPLATES[which]) // 2]  # "both": both
+    return [traffic._expand(CELL["mix"][k]["body"], CFG, rng) for k in kinds]
+
+
+def _msearch(node, bodies, raw=False):
+    svc = node.indices[CFG["index"]]
+    before = svc.search_stats.get("packed", 0)
+    out = node.msearch([({"index": CFG["index"]}, copy.deepcopy(b))
+                        for b in bodies], raw=raw)
+    assert svc.search_stats.get("packed", 0) == before + len(bodies), \
+        "the packed lane must serve every body"
+    return out
+
+
+def _check_against_reference(body, resp, ref):
+    """ids, order, total and scores of one answer against the reference's
+    own (`Reference.respond`); a tie in float32 may stand in another order,
+    so the order is held on the reference's scores within the limit."""
+    want = ref.respond(body)["hits"]
+    hits = resp["hits"]
+    assert hits["total"] == want["total"]
+    got = [int(h["_id"]) for h in hits["hits"]]
+    assert len(got) == len(want["hits"]) and len(set(got)) == len(got)
+    if want["total"] <= body["size"]:       # everything that matches
+        assert sorted(got) == sorted(int(h["_id"]) for h in want["hits"])
+    answer = ref.answer(body)
+    assert answer["mask"][got].all() if got else True
+    ref_scores = answer["score"][got]
+    scores = np.array([h["_score"] for h in hits["hits"]])
+    assert np.all(np.abs(scores - ref_scores) <= TOL * ref_scores)
+    assert np.all(np.diff(scores) <= 0)
+    assert np.all(np.diff(ref_scores) <= TOL * ref_scores[:-1])
+    tally = compare.Tally()                 # and the benchmark's own check
+    compare.compare_answer(tally, "body", body, resp, ref, TOL)
+    assert tally.verdict(compare.load_limits(CELL))[0], tally.notes
+
+
+@pytest.mark.parametrize("which", list(TEMPLATES))
+@pytest.mark.parametrize("q", [1, 32, 256])
+def test_msearch_of_the_cells_templates_equals_the_reference(wikif, q, which):
+    node, ref = wikif
+    bodies = _bodies(which, q, seed=q + len(which))
+    columns = {f for b in bodies for flt in b["query"]["bool"]["filter"]
+               for spec in flt.values() for f in spec}
+    assert columns == ({"timestamp", "month"} if which == "both"
+                       else {which})
+    out = _msearch(node, bodies)["responses"]
+    assert len(out) == q
+    short = 0
+    for body, resp in zip(bodies, out):
+        _check_against_reference(body, resp, ref)
+        short += len(resp["hits"]["hits"]) < body["size"]
+    assert short        # a filter leaves some bodies fewer hits than `size`
+
+
+def _on_the_bound(ref):
+    """(a body term, a document that holds it, the document's timestamp)."""
+    post_doc, _, start, _ = ref.postings("body")
+    term = int(np.argmax(np.diff(start)[64:]) + 64)   # the template's skip_top
+    doc = int(post_doc[start[term]])
+    return "t%06d" % term, doc, int(ref.cols["timestamp"][doc])
+
+
+@pytest.mark.parametrize("bound,shift,inside", [
+    ("gte", 0, True), ("gte", 1, False), ("gte", -1, True),
+    ("lt", 0, False), ("lt", 1, True), ("lt", -1, False),
+    ("gt", 0, False), ("gt", -1, True), ("lte", 0, True), ("lte", -1, False),
+])
+def test_a_bound_is_exact_to_the_millisecond(wikif, bound, shift, inside):
+    """A document exactly on `gte` is in and exactly on `lt` is out, and
+    one millisecond to either side turns each: 64-bit, never float32."""
+    node, ref = wikif
+    word, doc, ts = _on_the_bound(ref)
+    assert float(np.float32(ts)) != ts      # float32 cannot tell them apart
+    assert np.float32(ts + shift) == np.float32(ts)
+    body = {"query": {"bool": {"must": [{"match": {"body": word}}], "filter": [
+        {"range": {"timestamp": {bound: ts + shift}}}]}},
+        "size": 1000, "_source": False}
+    resp, = _msearch(node, [body])["responses"]
+    assert (str(doc) in {h["_id"] for h in resp["hits"]["hits"]}) is inside
+    assert resp["hits"]["total"] == ref.answer(body)["total"]
+
+
+def test_an_open_end_goes_to_the_program_as_it_was_sent(wikif, node):
+    """The TPU's float64 is a pair of float32 (about 48 bits): a bound
+    stepped one float64 ulp rounds back onto a date in milliseconds there,
+    and `lt` read as `lte` on the chip (PR 33). The descriptors carry the
+    bound as sent and which ends are open; the program compares strictly."""
+    from elasticsearch_tpu.ops.bm25_sparse import (
+        RANGE_HI_OPEN, RANGE_LO_OPEN, RANGE_NEGATED)
+    from elasticsearch_tpu.search.query_parser import QueryParser
+    from elasticsearch_tpu.serving.executor import packed_spec_of
+
+    def described(node, index, query):
+        svc = node.indices[index]
+        spec = packed_spec_of(QueryParser(svc.mappers), {"query": query})
+        fields, _, fr_lo, fr_hi, fr_how, *_ = \
+            svc.packed_view()._filter_descriptors([spec[0]], 1)
+        return fields, float(fr_lo[0, 0]), float(fr_hi[0, 0]), fr_how[0, 0]
+
+    ts = 1211699326344
+    assert described(wikif[0], CFG["index"], {"bool": {
+        "must": [{"match": {"body": "t000100"}}], "filter": [{"range": {
+            "timestamp": {"gt": ts, "lt": ts + 5}}}]}}) == (
+        ("timestamp",), float(ts), ts + 5.0, RANGE_LO_OPEN | RANGE_HI_OPEN)
+    quick = {"match": {"body": "quick"}}
+    assert described(node, "px", {"bool": {"must": [quick], "filter": [
+        {"range": {"rating": {"gte": 2.5, "lt": 4.5}}}]}}) == (
+        ("rating",), 2.5, 4.5, RANGE_HI_OPEN)
+    assert described(node, "px", {"bool": {"must": [quick], "must_not": [
+        {"range": {"price": {"gt": 20}}}]}}) == (
+        ("price",), 20.0, np.inf, RANGE_LO_OPEN | RANGE_NEGATED)
+
+
+@pytest.mark.parametrize("clause,field,bounds,want", [
+    # `rating` is a double: 1.5, 2.5, 3.5, -, 4.5, (slow), 5.0, -
+    ("filter", "rating", {"gt": 2.5, "lt": 4.5}, {"2"}),
+    ("filter", "rating", {"gte": 2.5, "lte": 4.5}, {"1", "2", "4"}),
+    ("filter", "rating", {"gt": 2.4999, "lt": 4.5001}, {"1", "2", "4"}),
+    ("must_not", "rating", {"gt": 2.5}, {"0", "1", "3", "7"}),
+    # `price` is a long: 10, 20, 30, 40, 50, (slow), 70, -
+    ("filter", "price", {"gt": 10, "lt": 30}, {"1"}),
+    ("filter", "price", {"gt": 9.5, "lt": 30.5}, {"0", "1", "2"}),
+    ("must_not", "price", {"gte": 20, "lt": 70}, {"0", "6", "7"}),
+    # a bound that is no finite number stays what it is
+    ("filter", "price", {"gt": 10, "lte": float("inf")},
+     {"1", "2", "3", "4", "6"}),
+    ("filter", "price", {"gte": float("-inf"), "lt": 20}, {"0"}),
+    ("filter", "price", {"gt": float("inf")}, set()),
+])
+def test_open_and_unbounded_range_ends_are_exact(node, clause, field,
+                                                 bounds, want):
+    q = {"bool": {"must": [{"match": {"body": "quick"}}],
+                  clause: [{"range": {field: bounds}}]}}
+    # held to `want` and not to the general lane, which reads a fractional
+    # bound over a long as the whole number below it and raises on inf
+    svc = node.indices["px"]
+    before = svc.search_stats.get("packed", 0)
+    p = node.search("px", {"query": q})
+    assert svc.search_stats.get("packed", 0) == before + 1
+    assert {h["_id"] for h in p["hits"]["hits"]} == want
+    assert p["hits"]["total"] == len(want)
+
+
+def test_raw_render_of_bodies_with_fewer_hits_than_size(wikif):
+    """The bytes of a raw `_msearch` say what the dicts say, also for
+    bodies whose filter leaves fewer hits than `size`, or none."""
+    node, ref = wikif
+    bodies = _bodies("both", 32, seed=5)
+    none = copy.deepcopy(bodies[0])
+    none["query"]["bool"]["filter"] = [{"range": {"timestamp": {
+        "gte": CFG["fields"]["timestamp"]["base_millis"] - 10, "lt":
+        CFG["fields"]["timestamp"]["base_millis"] - 5}}}]
+    bodies.insert(7, none)
+    raw = _msearch(node, bodies, raw=True)
+    assert isinstance(raw, bytes)
+    got = json.loads(raw)["responses"]
+    want = _msearch(node, bodies)["responses"]
+    counts = [len(r["hits"]["hits"]) for r in got]
+    assert counts[7] == 0 and got[7]["hits"]["max_score"] is None
+    assert 0 < min(c for c in counts if c) < 1000
+    for g, w in zip(got, want):
+        g.pop("took"), w.pop("took")
+        for h in w["hits"]["hits"]:         # the raw form prints `%.9g`
+            h["_score"] = float("%.9g" % h["_score"])
+        if w["hits"]["max_score"] is not None:
+            w["hits"]["max_score"] = float("%.9g" % w["hits"]["max_score"])
+        assert g == w
+    for body, resp in zip(bodies, got):
+        _check_against_reference(body, resp, ref)
+
+
+def _families(node) -> dict:
+    """`/_metrics` -> {program: batches} of es_packed_batches_total."""
+    text = render_openmetrics(node.metric_sections(), node="n")
+    return {m.group(1): float(m.group(2)) for m in re.finditer(
+        r'^es_packed_batches_total\{[^}]*program="(\w+)"[^}]*\} (\S+)$',
+        text, re.M)}
+
+
+def test_spans_and_counter_of_a_filtered_batch_and_never_of_a_plain_one(
+        tmp_path):
+    cfg = {**CFG, "documents": 600}
+    node = NodeService(data_path=str(tmp_path))
+    try:
+        _load(node, cfg)
+        plain = [{"query": b["query"]["bool"]["must"][0], "size": 1000,
+                  "_source": False} for b in _bodies("both", 32, seed=9)]
+
+        def spans():
+            rows = tracing.AGGREGATE.stats()
+            return {s: rows.get(s, {}).get("total", 0) for s in (
+                "packed.filter_descriptors", "packed.filter_column",
+                "packed.build_slots")}
+
+        s0, c0 = spans(), _families(node)
+        _msearch(node, plain)
+        s1, c1 = spans(), _families(node)
+        assert s1["packed.build_slots"] == s0["packed.build_slots"] + 1
+        assert s1["packed.filter_descriptors"] == \
+            s0["packed.filter_descriptors"]
+        assert s1["packed.filter_column"] == s0["packed.filter_column"]
+        assert (c1["plain"] - c0["plain"], c1["filtered"] - c0["filtered"]) \
+            == (1, 0)
+        seen = []
+        flight = tracing.flight
+
+        def noting(site):
+            ctx = flight(site)
+            seen.append(ctx.attrs)
+            return ctx
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(tracing, "flight", noting)
+            _msearch(node, _bodies("both", 32, seed=9))
+            _msearch(node, _bodies("month", 32, seed=10))
+        s2, c2 = spans(), _families(node)
+        # two filtered batches; the columns are built once a view, by the
+        # first batch that names them
+        assert s2["packed.filter_descriptors"] == \
+            s1["packed.filter_descriptors"] + 2
+        assert s2["packed.filter_column"] == s1["packed.filter_column"] + 2
+        assert (c2["plain"] - c1["plain"], c2["filtered"] - c1["filtered"]) \
+            == (0, 2)
+        programs = [a for a in seen if "program" in a]
+        assert [(a["program"], a["columns"]) for a in programs] == \
+            [("filtered", 2), ("filtered", 1)]
+        assert all(a["site"] == "ops:bm25_serve_packed_filtered"
+                   for a in programs)
+    finally:
+        node.close()

@@ -687,7 +687,9 @@ class TestLivenessFold:
                 r'tensor<([\dx]+)x(\w+)>', text):
             sizes, operand, dims, dtype = m.groups()
             elements = int(np.prod([int(x) for x in dims.split("x")]))
-            if elements >= Q * S * CHUNK and set(sizes.split(", ")) == {"1"}:
+            # the filter columns' gather takes K.FILTER_ROWS candidate rows
+            # (whole queries) at a time, inside a loop
+            if elements >= K.FILTER_ROWS and set(sizes.split(", ")) == {"1"}:
                 per_slot.append((operand, dtype))
         assert per_slot == ([] if program == "plain"
                             else [(f"tensor<1x{N}xf64>", "f64")])

@@ -118,6 +118,40 @@ def test_kernel_compiles_for_the_chip(one_chip, N, P):
                 if f"[{P // 128},128]" in ln and " copy(" in ln]
 
 
+def test_filtered_program_compiles_for_the_chip_at_the_cells_shapes(
+        one_chip, monkeypatch):
+    """The largest program of `wiki.filtered-top1000`: 256 bodies x 256
+    slots, k 1024, two float64 columns over 262,144 documents, 2^25
+    postings. With the gather of the columns over all 256 queries at once
+    the chip's compiler refused it: the result, f32[2^25, 2], is laid out
+    with its two columns padded to a tile's 128 lanes, 16 GB of the 15.75
+    the chip has, and every request with a body of more than 128 slots came
+    back as 256 item errors (my chip run, PR 33). `FILTER_ROWS` candidate
+    rows at a time since."""
+    from elasticsearch_tpu.serving.packed_view import (F_RANGE, F_TERM,
+                                                       F_TERM_VALS)
+    Q, S, P, N, NC = 256, 256, 1 << 25, 262_144, 2
+
+    def sd(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    # as on the chip: the Pallas gather compiled, not interpreted
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = K.bm25_serve_packed_filtered.jit.lower(
+        sd((Q, 3 * S + 1), jnp.int32), sd((P,), jnp.int32),
+        sd((P,), jnp.float32), sd((P,), jnp.float32),
+        *[sd((), jnp.float32)] * 4, sd((NC, N), jnp.float64),
+        sd((Q, F_RANGE), jnp.int32), sd((Q, F_RANGE), jnp.float64),
+        sd((Q, F_RANGE), jnp.float64), sd((Q, F_RANGE), jnp.int32),
+        sd((Q, F_TERM), jnp.int32), sd((Q, F_TERM, F_TERM_VALS), jnp.float64),
+        sd((Q, F_TERM), jnp.int32), S=S, CHUNK=CHUNK, R=8, k=1024,
+        FR=F_RANGE, FT=F_TERM, TV=F_TERM_VALS).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    # two padded blocks of 2 GB and a few [Q, S x CHUNK] rows (it read
+    # 17.0 GB)
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 << 30
+
+
 @pytest.mark.parametrize("kind,Q", [("hist", 4), ("count", 1), ("terms", 1)])
 def test_panel_programs_compile_for_the_four_chips(topo, kind, Q):
     """The panel lane's collective programs (search/aggs/panels.py; this
@@ -239,6 +273,26 @@ def test_program_with_the_kernel_returns_the_same_table(dispatched, program):
     assert got.shape == want.shape == (args[0].shape[0], 2 * static["k"] + 1)
     np.testing.assert_array_equal(np.asarray(got), want)
     assert (want[:, -1] > 0).any()                  # it found something
+
+
+def test_filter_blocks_cover_every_candidate_and_stop_after_the_last(
+        dispatched, monkeypatch):
+    """`packed.filters` takes `FILTER_ROWS` candidate rows at a time and only
+    the blocks that hold a candidate. With blocks of 16 columns the recorded
+    batch (some two hundred candidates of 16,384 lanes) takes over ten
+    blocks of the 1,024: the same table, number for number."""
+    calls, _, _ = dispatched
+    args, static, want = calls["filtered"]
+    static = {k: v for k, v in static.items() if k not in ("FR", "FT", "TV")}
+    filters = args[8:] + (pv.F_RANGE, pv.F_TERM, pv.F_TERM_VALS)
+    d = np.asarray(args[1])
+    assert static["S"] * CHUNK // 16 == 1024 and (d != K.PACKED_PAD_DOC).any()
+    monkeypatch.setattr(K, "FILTER_ROWS", 16 * args[0].shape[0])
+    got = jax.jit(functools.partial(
+        K._serve_packed_impl, **static, filters=filters,
+        gather="sliced"))(*args[:8])
+    np.testing.assert_array_equal(np.asarray(got), want)
+    assert 0 < want[:, -1].max() < 30           # the filter kept some, not all
 
 
 def test_a_search_counts_one_dispatch_under_the_form_that_ran(dispatched):

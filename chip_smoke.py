@@ -405,9 +405,9 @@ class Smoke:
         # every bound is the value of a document that matches the body's
         # text (of one with a low half in 0..7 where there is one: others
         # then sit one unit to either side). A body in four closes both
-        # ends, the others open one or both: an open end that is stepped by
-        # less than the device's float64 can hold reads as a closed one
-        # (`lt` as `lte` on the TPU, PR 33), and the total is then too high
+        # ends, the others open one or both: `lt` read as `lte` on the TPU
+        # when the chip compared emulated float64 (PR 33), and the total was
+        # then too high; since PR 34 the host resolves each end to an ordinal
         specs = []
         for qi in range(Q_BATCH):
             ts = self.terms()

@@ -63,16 +63,16 @@ FOLD_IDS_MAX = 8192
 
 # Candidate rows (a block of candidate columns of every query) that the
 # filtered program's `packed.filters` gathers at a time. On the TPU the
-# gather's result comes out as [rows, NC] with the NC columns padded to a
-# tile's 128 lanes, 512 B a row and float32 half: 2 GB a block. All 256
-# queries x 256 slots at once are 16 GB, and the chip's compiler refused the
-# program (PERF.md §6, PR 33).
+# gather's result comes out as [rows, NC] with the NC int32 columns padded to
+# a tile's 128 lanes, 512 B a row: 2 GB a block. All 256 queries x 256 slots
+# at once are 16 GB, and the chip's compiler refused the program (PERF.md §6,
+# PR 33).
 FILTER_ROWS = 1 << 22
 
-# A range slot's `fr_how`: bits. An open end is compared strictly by the
-# program: a bound stepped one float64 ulp is not a value the TPU's float64
-# (a pair of float32, about 48 bits) holds.
-RANGE_NEGATED, RANGE_LO_OPEN, RANGE_HI_OPEN = 1, 2, 4
+# A term target that no row of a filter column holds (rows hold an ordinal
+# >= 0, or -1 where the document has no value): an unused target, and a value
+# that no document has.
+NO_ORDINAL = -2
 
 
 def required_padding(n_postings: int, max_df: int) -> int:
@@ -176,7 +176,7 @@ def bm25_serve_packed_filtered(packed_q: jax.Array, doc_ids: jax.Array,
                                k1, b, avgdl, const,
                                fcols: jax.Array,
                                fr_col: jax.Array, fr_lo: jax.Array,
-                               fr_hi: jax.Array, fr_how: jax.Array,
+                               fr_hi: jax.Array, fr_neg: jax.Array,
                                ft_col: jax.Array, ft_targets: jax.Array,
                                ft_neg: jax.Array, *,
                                S: int, CHUNK: int, R: int, k: int,
@@ -185,15 +185,24 @@ def bm25_serve_packed_filtered(packed_q: jax.Array, doc_ids: jax.Array,
     the candidate positions (the filter analog of Lucene's filtered query
     inside QueryPhase — BASELINE config #2's bool{match + filter} shape).
 
-    fcols f64[NC, Npad]: the filter columns this batch touches, packed over
-        the global doc space — numeric values (NaN = missing) or keyword
-        ordinals in the view's union vocabulary (-1 = missing).
+    fcols i32[NC, Npad]: the filter columns this batch touches, packed over
+        the global doc space. Whatever the field's type, a row holds its
+        value's ORDINAL among the view's sorted distinct values (-1 = no
+        value, and every padding row): order and equality are all a filter
+        needs of a column, and one int32 is one gather a candidate. The
+        64-bit values stay on the host (`PackedFilterColumn.distinct`),
+        where `_filter_descriptors` turns every bound and target into an
+        ordinal exactly; the program holds no float64.
     Range slots (AND-ed): fr_col i32[Q, FR] (index into fcols; -1 = slot
         unused, -2 = active but the field has no column: matches nothing),
-        fr_lo/fr_hi f64[Q, FR] bounds, fr_how i32[Q, FR]: RANGE_NEGATED |
-        RANGE_LO_OPEN | RANGE_HI_OPEN (an end is inclusive unless open).
+        fr_lo/fr_hi i32[Q, FR] an INCLUSIVE ordinal interval (an open end,
+        a bound between two values and a missing bound are resolved on the
+        host; lo > hi matches nothing), fr_neg i32[Q, FR].
     Term slots (AND-ed; OR within a slot's TV targets): ft_col i32[Q, FT],
-        ft_targets f64[Q, FT, TV] (NaN = unused target), ft_neg i32[Q, FT].
+        ft_targets i32[Q, FT, TV] ordinals (NO_ORDINAL = unused target, or
+        a value no document holds), ft_neg i32[Q, FT].
+    A row without a value fails every range and term and passes their
+        negations.
 
     Filters gate `keep` exactly like `min_match`, so total_hits and top-k
     honor them in the same single program — still 1 upload + 1 download.
@@ -201,7 +210,7 @@ def bm25_serve_packed_filtered(packed_q: jax.Array, doc_ids: jax.Array,
     return _serve_packed_impl(
         packed_q, doc_ids, tf, dl, k1, b, avgdl, const,
         S=S, CHUNK=CHUNK, R=R, k=k, gather=packed_gather_form(),
-        filters=(fcols, fr_col, fr_lo, fr_hi, fr_how,
+        filters=(fcols, fr_col, fr_lo, fr_hi, fr_neg,
                  ft_col, ft_targets, ft_neg, FR, FT, TV))
 
 
@@ -334,24 +343,23 @@ def _serve_packed_impl(packed_q, doc_ids, tf, dl, k1, b, avgdl, const, *,
         keep = ends & (count >= min_match[:, None].astype(jnp.float32))
 
     if filters is not None:
-        (fcols, fr_col, fr_lo, fr_hi, fr_how,
+        (fcols, fr_col, fr_lo, fr_hi, fr_neg,
          ft_col, ft_targets, ft_neg, FR, FT, TV) = filters
 
-        def eval_one(dq, fr_c, fr_l, fr_h, fr_w, ft_c, ft_t, ft_n):
+        def eval_one(dq, fr_c, fr_l, fr_h, fr_n, ft_c, ft_t, ft_n):
             ok = jnp.ones(dq.shape, bool)
             # gather every column at the candidate slots FIRST, then pick
             # the slot's column: [NC, W] per query. Picking the column
             # first materializes a [Q, Npad] copy per filter slot under
             # the vmap — 16 GB at 1M docs x 256 queries (chip run, PR 21).
+            # ONE int32 gather: a row without a value reads -1, below every
+            # interval's low end and equal to no target.
             vals = fcols.take(dq, axis=1, mode="clip")
             for fi in range(FR):
                 v = jnp.take(vals, jnp.maximum(fr_c[fi], 0), axis=0)
-                m = jnp.where((fr_w[fi] & RANGE_LO_OPEN) != 0,
-                              v > fr_l[fi], v >= fr_l[fi]) \
-                    & jnp.where((fr_w[fi] & RANGE_HI_OPEN) != 0,
-                                v < fr_h[fi], v <= fr_h[fi])
+                m = (v >= fr_l[fi]) & (v <= fr_h[fi])
                 m = jnp.where(fr_c[fi] == -2, False, m)  # absent column
-                m = jnp.where((fr_w[fi] & RANGE_NEGATED) != 0, ~m, m)
+                m = jnp.where(fr_n[fi] > 0, ~m, m)
                 ok = ok & jnp.where(fr_c[fi] != -1, m, True)
             for fi in range(FT):
                 v = jnp.take(vals, jnp.maximum(ft_c[fi], 0), axis=0)
@@ -375,7 +383,7 @@ def _serve_packed_impl(packed_q, doc_ids, tf, dl, k1, b, avgdl, const, *,
                 at = i * jnp.int32(cols)
                 got = jax.vmap(eval_one)(
                     jax.lax.dynamic_slice_in_dim(d, at, cols, axis=1),
-                    fr_col, fr_lo, fr_hi, fr_how, ft_col, ft_targets, ft_neg)
+                    fr_col, fr_lo, fr_hi, fr_neg, ft_col, ft_targets, ft_neg)
                 return jax.lax.dynamic_update_slice_in_dim(ok, got, at, axis=1)
 
             n_blocks = (longest + jnp.int32(cols - 1)) // jnp.int32(cols)

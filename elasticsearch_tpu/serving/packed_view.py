@@ -27,7 +27,6 @@ on the device, and the program gathers no liveness per request.
 
 from __future__ import annotations
 
-import bisect
 import contextlib
 import math
 import re
@@ -42,11 +41,10 @@ from ..common import tracing
 from ..common.metrics import (device_fetch, note_h2d, record_packed_consts,
                               record_packed_dispatch)
 from ..index.segment import Segment, next_pow2
-from ..ops.bm25_sparse import (FOLD_IDS_BLOCK, FOLD_IDS_MAX, PACKED_PAD_DOC,
-                               RANGE_HI_OPEN, RANGE_LO_OPEN, RANGE_NEGATED,
-                               bm25_serve_packed, bm25_serve_packed_filtered,
-                               packed_fold_ids, packed_fold_live,
-                               packed_gather_form)
+from ..ops.bm25_sparse import (FOLD_IDS_BLOCK, FOLD_IDS_MAX, NO_ORDINAL,
+                               PACKED_PAD_DOC, bm25_serve_packed,
+                               bm25_serve_packed_filtered, packed_fold_ids,
+                               packed_fold_live, packed_gather_form)
 
 # Fixed postings chunk: compile-cache keys depend on (Q, S) pow2 buckets only,
 # never on the corpus' df distribution.
@@ -82,12 +80,15 @@ class PackedQuery:
 
 @dataclass
 class PackedFilterColumn:
-    """One field's filter column over the global packed doc space, f64-
-    encoded for the kernel: numeric values (NaN = missing) or keyword
-    ordinals in the union vocabulary (-1 = missing)."""
+    """One field's filter column over the global packed doc space. The chip
+    holds a row's ORDINAL: the rank of its value among `distinct`, the view's
+    sorted distinct values, which stay on the host in the column's own type.
+    A filter needs only order and equality of a column, ranks keep both, and
+    `_filter_descriptors` finds a bound's or a target's rank where 64-bit
+    values are exact (the TPU's float64 is a pair of float32)."""
     kind: str                      # "numeric" | "keyword"
-    vals: jax.Array                # f64[n_pad_total]
-    vocab: list[str] | None = None
+    vals: jax.Array                # i32[n_pad_total]; -1 = no value, padding
+    distinct: np.ndarray           # i64[D] | f64[D] | object[D] (str), sorted
 
 
 class PackedField:
@@ -295,53 +296,10 @@ class PackedIndexView:
 
     def _extend_filter_col(self, name: str, base: "PackedIndexView",
                            col: PackedFilterColumn) -> PackedFilterColumn:
-        """Extend a filter column over the appended doc space. Keyword
-        columns may need an ordinal REMAP when new segments introduce new
-        vocabulary — numeric ones are a pure concat."""
+        """Extend a filter column over the appended doc space."""
         if self.breaker is not None:
-            self.breaker.add_estimate(self.n_pad_total * 8)
-        new_entries = list(enumerate(self.entries))[len(base.entries):]
-        if col.kind == "numeric":
-            tail = np.full(self.n_pad_total - base.n_total, np.nan)
-            for ei, (_, seg) in new_entries:
-                nc = seg.numerics.get(name)
-                if nc is None or seg.n_docs == 0:
-                    continue
-                lo = int(self.bases[ei]) - base.n_total
-                v = np.asarray(nc.vals).astype(np.float64)
-                miss = np.asarray(nc.missing)
-                n = min(seg.n_pad, len(v))
-                tail[lo:lo + n] = np.where(miss[:n], np.nan, v[:n])
-            vals = jnp.concatenate([col.vals[: base.n_total],
-                                    jnp.asarray(tail)])
-            self.memory_bytes += self.n_pad_total * 8
-            return PackedFilterColumn("numeric", vals)
-        # keyword: union vocab; remap old ordinals only if vocab grew
-        new_vocabs = [seg.keywords[name].values
-                      for _, (_, seg) in new_entries
-                      if name in seg.keywords]
-        vocab = sorted(set(col.vocab).union(*new_vocabs)) if new_vocabs \
-            else col.vocab
-        union_of = {v: i for i, v in enumerate(vocab)}
-        if vocab != col.vocab:
-            lut = np.array([union_of[v] for v in col.vocab] + [-1.0])
-            old = np.asarray(col.vals[: base.n_total]).astype(np.int64)
-            head = jnp.asarray(lut[old])
-        else:
-            head = col.vals[: base.n_total]
-        tail = np.full(self.n_pad_total - base.n_total, -1.0)
-        for ei, (_, seg) in new_entries:
-            kc = seg.keywords.get(name)
-            if kc is None or seg.n_docs == 0:
-                continue
-            lo = int(self.bases[ei]) - base.n_total
-            lut = np.array([union_of[v] for v in kc.values] + [-1.0])
-            ords = np.asarray(kc.ords)
-            n = min(seg.n_pad, len(ords))
-            tail[lo:lo + n] = lut[ords[:n]]
-        vals = jnp.concatenate([head, jnp.asarray(tail)])
-        self.memory_bytes += self.n_pad_total * 8
-        return PackedFilterColumn("keyword", vals, vocab=vocab)
+            self.breaker.add_estimate(self.n_pad_total * 4)
+        return self._ranked_column(name, col.kind, len(base.entries), col)
 
     # -- liveness (folded into the postings when tombstones change) --------
 
@@ -683,10 +641,11 @@ class PackedIndexView:
     # -- filter columns (lazy, cached) -------------------------------------
 
     def filter_column(self, name: str) -> PackedFilterColumn | None:
-        """The f64 filter column for one field over the global doc space.
-        None = no segment has the field (a filter on it matches nothing).
-        Raises FilterColumnRefused when the request breaker refuses the
-        device bytes — the caller serves via the per-segment lane."""
+        """The int32 ordinal column for one field over the global doc space
+        (`PackedFilterColumn`). None = no segment has the field (a filter on
+        it matches nothing). Raises FilterColumnRefused when the request
+        breaker refuses the device bytes — the caller serves via the
+        per-segment lane."""
         if name in self._filter_cols:
             return self._filter_cols[name]
         has_kw = any(name in seg.keywords for _, seg in self.entries)
@@ -697,44 +656,54 @@ class PackedIndexView:
         if self.breaker is not None:
             from ..common.breaker import CircuitBreakingException
             try:
-                self.breaker.add_estimate(self.n_pad_total * 8)
+                self.breaker.add_estimate(self.n_pad_total * 4)
             except CircuitBreakingException as e:
                 raise FilterColumnRefused(name) from e
-        with tracing.span("packed.filter_column", field=name,
-                          kind="numeric" if has_num else "keyword",
-                          bytes=self.n_pad_total * 8):
-            if has_num:
-                vals = np.full(self.n_pad_total, np.nan)
-                for ei, (_, seg) in enumerate(self.entries):
-                    nc = seg.numerics.get(name)
-                    if nc is None or seg.n_docs == 0:
-                        continue
-                    base = int(self.bases[ei])
-                    v = np.asarray(nc.vals).astype(np.float64)
-                    miss = np.asarray(nc.missing)
-                    n = min(seg.n_pad, len(v))
-                    vals[base:base + n] = np.where(miss[:n], np.nan, v[:n])
-                col = PackedFilterColumn("numeric", jnp.asarray(vals))
-            else:
-                vocab = sorted(set().union(*(
-                    seg.keywords[name].values for _, seg in self.entries
-                    if name in seg.keywords)))
-                union_of = {v: i for i, v in enumerate(vocab)}
-                vals = np.full(self.n_pad_total, -1.0)
-                for ei, (_, seg) in enumerate(self.entries):
-                    kc = seg.keywords.get(name)
-                    if kc is None or seg.n_docs == 0:
-                        continue
-                    base = int(self.bases[ei])
-                    lut = np.array([union_of[v] for v in kc.values] + [-1.0])
-                    ords = np.asarray(kc.ords)
-                    n = min(seg.n_pad, len(ords))
-                    vals[base:base + n] = lut[ords[:n]]
-                col = PackedFilterColumn("keyword", jnp.asarray(vals),
-                                         vocab=vocab)
-        self.memory_bytes += self.n_pad_total * 8
+        col = self._ranked_column(name, "numeric" if has_num else "keyword")
         self._filter_cols[name] = col
         return col
+
+    def _ranked_column(self, name: str, kind: str, first_entry: int = 0,
+                       head: PackedFilterColumn | None = None
+                       ) -> PackedFilterColumn:
+        """Build the column of field `name` from entries[first_entry:],
+        after `head`, a base view's column over the entries before them.
+        Every segment's own distinct values are merged into the view's
+        (with `head.distinct`), and a row's rank goes through its segment's
+        lookup table; -1 = no value, and every padding row: the row that
+        `take(..., mode="clip")` reads for PACKED_PAD_DOC is one. The old
+        rows come over on the device as they are, unless the new segments
+        bring values the base had not seen: then every old rank moves up by
+        the new values below it (`reranked` on the span counts those rows)."""
+        built = tracing.span("packed.filter_column", field=name, kind=kind,
+                             bytes=self.n_pad_total * 4)
+        with built:
+            parts = []                  # (first row, own values, own ranks)
+            for ei in range(first_entry, len(self.entries)):
+                own = _segment_ranks(self.entries[ei][1], name, kind)
+                if own is not None:
+                    parts.append((int(self.bases[ei]), *own))
+            distinct = np.unique(np.concatenate(
+                ([] if head is None else [head.distinct])
+                + [values for _, values, _ in parts]))
+            lo = int(self.bases[first_entry])
+            rows = np.full(self.n_pad_total - lo, -1, np.int32)
+            for at, values, ranks in parts:
+                rows[at - lo:at - lo + len(ranks)] = \
+                    _ranks_in(distinct, values)[ranks]
+            vals = jnp.asarray(rows)
+            if head is not None:
+                old = head.vals[:lo]
+                grew = len(distinct) != len(head.distinct)
+                if grew:
+                    old = jnp.asarray(_ranks_in(
+                        distinct, head.distinct)[np.asarray(old)])
+                vals = jnp.concatenate([old, vals])
+                built.attrs["reranked"] = lo if grew else 0
+            built.attrs.update(distinct=len(distinct),
+                               host_bytes=distinct.nbytes)
+        self.memory_bytes += self.n_pad_total * 4
+        return PackedFilterColumn(kind, vals, distinct)
 
     def _filter_stack(self, fields: tuple) -> jax.Array:
         st = self._filter_stacks.get(fields)
@@ -742,93 +711,72 @@ class PackedIndexView:
             if fields:
                 st = jnp.stack([self._filter_cols[f].vals for f in fields])
             else:
-                st = jnp.zeros((1, self.n_pad_total), jnp.float64)
+                st = jnp.full((1, self.n_pad_total), -1, jnp.int32)
             self._filter_stacks[fields] = st
         return st
 
     def _filter_descriptors(self, queries: list[PackedQuery], Q_pad: int):
-        """-> (fields tuple, fr_col, fr_lo, fr_hi, fr_how, ft_col,
-        ft_targets, ft_neg) numpy descriptor arrays for the kernel.
+        """-> (fields tuple, fr_col, fr_lo, fr_hi, fr_neg, ft_col,
+        ft_targets, ft_neg) numpy descriptor arrays for the kernel
+        (`bm25_serve_packed_filtered` has their meaning): every bound and
+        target as an ordinal, looked up among the column's distinct values
+        in the column's own type, all of a batch's at once a column.
         Raises FilterColumnRefused if a needed column was breaker-refused."""
         from ..search.query_dsl import RangeNode, TermFilterNode
 
         fields: list[str] = []
+        asked: dict[str, _ColumnKeys | None] = {}
 
-        def col_idx(name):
-            col = self.filter_column(name)
-            if col is None:
-                return -2, None     # active slot, absent field
-            if name not in fields:
-                fields.append(name)
-            return fields.index(name), col
+        def column(name):
+            if name not in asked:
+                col = self.filter_column(name)
+                asked[name] = None if col is None \
+                    else _ColumnKeys(col.distinct, len(fields))
+                if col is not None:
+                    fields.append(name)
+            keys = asked[name]
+            return (-2 if keys is None else keys.index), keys
 
         fr_col = np.full((Q_pad, F_RANGE), -1, np.int32)
-        fr_lo = np.zeros((Q_pad, F_RANGE))
-        fr_hi = np.zeros((Q_pad, F_RANGE))
-        fr_how = np.zeros((Q_pad, F_RANGE), np.int32)
+        fr_ends = np.zeros((2, Q_pad, F_RANGE), np.int32)    # low | high
+        fr_neg = np.zeros((Q_pad, F_RANGE), np.int32)
         ft_col = np.full((Q_pad, F_TERM), -1, np.int32)
-        ft_targets = np.full((Q_pad, F_TERM, F_TERM_VALS), np.nan)
+        ft_targets = np.full((Q_pad, F_TERM, F_TERM_VALS), NO_ORDINAL,
+                             np.int32)
         ft_neg = np.zeros((Q_pad, F_TERM), np.int32)
 
         for qi, q in enumerate(queries):
             ri = ti = 0
             for neg, node in q.filters:
+                ci, keys = column(node.field_name)
                 if isinstance(node, RangeNode):
-                    ci, col = col_idx(node.field_name)
                     lo, hi, inc_lo, inc_hi = node.bounds_per_query[0]
-                    if col is not None and col.kind == "keyword":
-                        # lexicographic bounds -> inclusive ordinal bounds
-                        # over the union vocab (mirrors RangeNode's kc path)
-                        l = 0
+                    if keys is not None:
+                        # no bound: the least (0) / the greatest rank
                         if lo is not None:
-                            l = bisect.bisect_left(col.vocab, str(lo))
-                            if not inc_lo and l < len(col.vocab) \
-                                    and col.vocab[l] == str(lo):
-                                l += 1
-                        h = len(col.vocab) - 1
+                            keys.range_end(0, qi, ri, lo, inc_lo)
                         if hi is not None:
-                            h = bisect.bisect_right(col.vocab, str(hi)) - 1
-                            if not inc_hi and h >= 0 \
-                                    and col.vocab[h] == str(hi):
-                                h -= 1
-                        flo, fhi, how = float(l), float(h), 0
-                    else:
-                        # the bounds as they were sent; the program compares
-                        # an open end strictly. (A bound stepped one float64
-                        # ulp is not a value the TPU's float64 holds, a pair
-                        # of float32 of about 48 bits: it rounded back onto
-                        # a date in milliseconds and `lt` read as `lte`
-                        # there; PERF.md §6, PR 33.)
-                        flo = -np.inf if lo is None else float(lo)
-                        fhi = np.inf if hi is None else float(hi)
-                        how = (0 if inc_lo else RANGE_LO_OPEN) \
-                            | (0 if inc_hi else RANGE_HI_OPEN)
+                            keys.range_end(1, qi, ri, hi, inc_hi)
+                        else:
+                            fr_ends[1, qi, ri] = len(keys.distinct) - 1
                     fr_col[qi, ri] = ci
-                    fr_lo[qi, ri] = flo
-                    fr_hi[qi, ri] = fhi
-                    fr_how[qi, ri] = how | (RANGE_NEGATED if neg else 0)
+                    if neg:
+                        fr_neg[qi, ri] = 1
                     ri += 1
                 elif isinstance(node, TermFilterNode):
-                    ci, col = col_idx(node.field_name)
                     vals = node.values_per_query[0] \
                         if node.values_per_query else []
-                    for vi, v in enumerate(vals[:F_TERM_VALS]):
-                        if col is None:
-                            break
-                        if col.kind == "keyword":
-                            p = bisect.bisect_left(col.vocab, str(v))
-                            ft_targets[qi, ti, vi] = float(p) \
-                                if p < len(col.vocab) \
-                                and col.vocab[p] == str(v) else np.nan
-                        else:
-                            try:
-                                ft_targets[qi, ti, vi] = float(v)
-                            except (TypeError, ValueError):
-                                ft_targets[qi, ti, vi] = np.nan
+                    if keys is not None:
+                        for vi, v in enumerate(vals[:F_TERM_VALS]):
+                            keys.target(qi, ti, vi, v)
                     ft_col[qi, ti] = ci
-                    ft_neg[qi, ti] = int(neg)
+                    if neg:
+                        ft_neg[qi, ti] = 1
                     ti += 1
-        return (tuple(fields), fr_col, fr_lo, fr_hi, fr_how,
+        for keys in asked.values():
+            if keys is not None:
+                keys.resolve(fr_ends, ft_targets)
+        return (tuple(fields), fr_col, fr_ends[0], fr_ends[1], fr_neg,
                 ft_col, ft_targets, ft_neg)
 
     # -- host-side doc resolution ------------------------------------------
@@ -871,17 +819,18 @@ class PackedIndexView:
                 packed[:, 3 * s] = 1
                 bm25_serve_packed(packed, *common,
                                   S=s, CHUNK=CHUNK, R=4, k=k)
+            no_column = jnp.full((1, self.n_pad_total), -1, jnp.int32)
             for (q, s, k) in filtered_shapes:
                 packed = np.zeros((q, 3 * s + 1), np.int32)
                 packed[:, 3 * s] = 1
                 bm25_serve_packed_filtered(
-                    packed, *common,
-                    jnp.zeros((1, self.n_pad_total), jnp.float64),
+                    packed, *common, no_column,
                     np.full((q, F_RANGE), -1, np.int32),
-                    np.zeros((q, F_RANGE)), np.zeros((q, F_RANGE)),
+                    np.zeros((q, F_RANGE), np.int32),
+                    np.zeros((q, F_RANGE), np.int32),
                     np.zeros((q, F_RANGE), np.int32),
                     np.full((q, F_TERM), -1, np.int32),
-                    np.full((q, F_TERM, F_TERM_VALS), np.nan),
+                    np.full((q, F_TERM, F_TERM_VALS), NO_ORDINAL, np.int32),
                     np.zeros((q, F_TERM), np.int32),
                     S=s, CHUNK=CHUNK, R=4, k=k,
                     FR=F_RANGE, FT=F_TERM, TV=F_TERM_VALS)
@@ -903,3 +852,115 @@ def _device_scalars(k1: float, b: float, avgdl: float) -> tuple:
     rounded on the host, one transfer, no operation on the device."""
     return tuple(jax.device_put([np.float32(k1), np.float32(b),
                                  np.float32(avgdl), np.float32(0.0)]))
+
+
+def _ranks_in(distinct: np.ndarray, values: np.ndarray) -> np.ndarray:
+    """i32[len(values) + 1]: the rank in `distinct` of each of `values` (all
+    held by it), then -1, which a rank of -1 (no value) indexes."""
+    return np.append(np.searchsorted(distinct, values), -1).astype(np.int32)
+
+
+def _segment_ranks(seg: Segment, name: str, kind: str):
+    """-> (the segment's own sorted distinct values of the field, in the
+    column's 64-bit type: a whole-number column never passes through a
+    float; each row's rank among them, -1 = no value), or None where the
+    segment has no such column."""
+    if kind == "keyword":
+        kc = seg.keywords.get(name)
+        if kc is None:
+            return None
+        values = np.empty(len(kc.values), object)
+        values[:] = kc.values
+        return values, np.asarray(kc.ords)[:seg.n_pad]
+    nc = seg.numerics.get(name)
+    if nc is None:
+        return None
+    vals = np.asarray(nc.vals)[:seg.n_pad]
+    has = ~np.asarray(nc.missing)[:len(vals)]
+    values, held = np.unique(vals[has], return_inverse=True)
+    ranks = np.full(len(vals), -1, np.int32)
+    ranks[has] = held
+    return values, ranks
+
+
+_I64_MIN, _I64_MAX = -2 ** 63, 2 ** 63 - 1
+
+
+def _column_key(dtype_kind: str, x):
+    """A bound or a target as it was sent -> (key in the column's own type,
+    side), for a column whose distinct values' `dtype.kind` is `dtype_kind`
+    ("i" int64, "f" float64, "O" str). side None where the key is `x` itself;
+    True where `x` lies above the key and below the next value the type
+    holds (a fraction over a whole-number column, or beyond int64), False
+    where it lies below the least. Raises ValueError / TypeError on what is
+    no value of the type."""
+    if dtype_kind == "O":
+        return str(x), None
+    if type(x) is str:
+        try:
+            x = int(x)
+        except ValueError:
+            x = float(x)
+    if dtype_kind == "f":
+        return float(x), None
+    side = None
+    if type(x) is not int:              # a float, a bool
+        if isinstance(x, float):
+            if math.isinf(x):
+                return (_I64_MAX, True) if x > 0 else (_I64_MIN, False)
+            whole = math.floor(x)
+            side = None if whole == x else True
+            x = whole
+        x = int(x)
+    if _I64_MIN <= x <= _I64_MAX:
+        return x, side
+    return (_I64_MAX, True) if x > 0 else (_I64_MIN, False)
+
+
+class _ColumnKeys:
+    """What one batch asks of one filter column: its range ends and term
+    targets as keys in the column's own type, all turned into ordinals by
+    ONE `searchsorted` over the column's distinct values (`resolve`)."""
+
+    def __init__(self, distinct: np.ndarray, index: int):
+        self.distinct = distinct
+        self.dtype_kind = distinct.dtype.kind
+        self.index = index              # of the column in the batch's stack
+        # (key, right, high, then the three indices of where the rank goes:
+        # fr_ends[high, qi, ri] | ft_targets[qi, ti, vi]). A range end's
+        # rank is searchsorted-left + (right and held) - high; high -1 marks
+        # a term target
+        self.asked: list[tuple] = []
+
+    def range_end(self, high: int, qi: int, ri: int, x,
+                  inclusive: bool) -> None:
+        """`gte` / `gt` (high 0) -> the least rank inside, `lte` / `lt`
+        (high 1) -> the greatest: an inclusive interval of ranks."""
+        key, side = _column_key(self.dtype_kind, x)
+        if side is None:
+            side = inclusive if high else not inclusive
+        self.asked.append((key, side, high, high, qi, ri))
+
+    def target(self, qi: int, ti: int, vi: int, x) -> None:
+        try:
+            key, side = _column_key(self.dtype_kind, x)
+        except (TypeError, ValueError):
+            return                      # no value of the type: equals no row
+        if side is None:
+            self.asked.append((key, False, -1, qi, ti, vi))
+
+    def resolve(self, fr_ends: np.ndarray, ft_targets: np.ndarray) -> None:
+        if not self.asked:
+            return
+        d = self.distinct
+        asked_keys, right, high, *at = zip(*self.asked)
+        keys = np.empty(len(asked_keys), d.dtype)
+        keys[:] = asked_keys
+        pos = np.searchsorted(d, keys)
+        held = d[np.minimum(pos, len(d) - 1)] == keys if len(d) \
+            else np.zeros(len(keys), bool)
+        at, high = np.asarray(at), np.asarray(high)
+        ends, hits = high >= 0, (high < 0) & held
+        fr_ends[tuple(at[:, ends])] = \
+            (pos + (np.asarray(right) & held) - high)[ends]
+        ft_targets[tuple(at[:, hits])] = pos[hits]

@@ -314,35 +314,305 @@ def test_a_bound_is_exact_to_the_millisecond(wikif, bound, shift, inside):
     assert resp["hits"]["total"] == ref.answer(body)["total"]
 
 
-def test_an_open_end_goes_to_the_program_as_it_was_sent(wikif, node):
-    """The TPU's float64 is a pair of float32 (about 48 bits): a bound
-    stepped one float64 ulp rounds back onto a date in milliseconds there,
-    and `lt` read as `lte` on the chip (PR 33). The descriptors carry the
-    bound as sent and which ends are open; the program compares strictly."""
-    from elasticsearch_tpu.ops.bm25_sparse import (
-        RANGE_HI_OPEN, RANGE_LO_OPEN, RANGE_NEGATED)
+# -- ordinals: every compare decides as the 64-bit one does (ISSUE 34) -------
+# The chip holds a row's rank among the column's sorted distinct values; the
+# host finds each bound's and target's rank in the column's own type. Held to
+# a NumPy int64 / float64 reference over the same rows.
+
+T0 = 1211699326344                      # a date in milliseconds
+ULP = float(np.nextafter(1.0, 2.0))     # the double next to 1.0
+EXACT_MAPPING = {"_doc": {"properties": {
+    "body": {"type": "text"}, "n": {"type": "long"}, "ts": {"type": "date"},
+    "x": {"type": "double"}, "tag": {"type": "keyword"}}}}
+# what the rows hold, None = no value: two timestamps 1 ms apart, int64
+# 2^53 and 2^53 + 1 (one float64), two doubles one ulp apart
+HELD = {
+    "n": [-5, 0, 7, None, 2 ** 53, 2 ** 53 + 1, 2 ** 62, 7, None, -5],
+    "ts": [T0, T0 + 1, None, T0 + 86_400_000, T0, T0 + 1, None,
+           T0 - 3, T0 + 2, T0 + 86_400_000],
+    "x": [-1.5, 1.0, ULP, 2.5, None, 1e300, 1.0, None, ULP, -0.0],
+    "tag": ["b", None, "d", "b", "f", None, "d", "bb", "f", "b"],
+}
+N_ROWS = len(HELD["n"])
+
+
+@pytest.fixture(scope="module")
+def exact(tmp_path_factory):
+    n = NodeService(data_path=str(tmp_path_factory.mktemp("exact")))
+    n.create_index("ex", settings={"number_of_shards": 2},
+                   mappings=EXACT_MAPPING)
+    for i in range(N_ROWS):
+        doc = {"body": "word", **{f: v[i] for f, v in HELD.items()
+                                  if v[i] is not None}}
+        n.index_doc("ex", str(i), doc)
+        if i in (2, 6):
+            n.refresh("ex")             # several segments a shard
+    n.refresh("ex")
+    yield n
+    n.close()
+
+
+def _held(field):
+    """(values in the column's own 64-bit type, which rows hold one)."""
+    has = np.array([v is not None for v in HELD[field]])
+    dtype = {"n": np.int64, "ts": np.int64, "x": np.float64}[field]
+    return np.array([v if v is not None else 0 for v in HELD[field]],
+                    dtype), has
+
+
+def _step(field, v, by):
+    """The value next to `v` in the column's type: 1 for a whole number
+    (a millisecond for a date), one ulp for a double."""
+    if field == "x":
+        return float(np.nextafter(v, np.inf if by > 0 else -np.inf))
+    return v + by
+
+
+OPS = {"gt": np.greater, "gte": np.greater_equal,
+       "lt": np.less, "lte": np.less_equal}
+
+
+def _packed_ids(node, index, query, size=50):
+    svc = node.indices[index]
+    before = svc.search_stats.get("packed", 0)
+    out = node.search(index, {"query": query, "size": size})
+    assert svc.search_stats.get("packed", 0) == before + 1, query
+    assert out["hits"]["total"] == len(out["hits"]["hits"])
+    return {int(h["_id"]) for h in out["hits"]["hits"]}
+
+
+def _bounds():
+    """(field, bound): on every held value, one step below and one above
+    it, below the least and above the greatest."""
+    for field in ("n", "ts", "x"):
+        held = sorted({v for v in HELD[field] if v is not None})
+        picks = {held[0], held[len(held) // 2], held[-2], held[-1]} \
+            if field == "n" else set(held[:3]) | {held[-1]}
+        near = set()
+        for v in picks:
+            near |= {v, _step(field, v, -1), _step(field, v, +1)}
+        for b in sorted(near | {_step(field, held[0], -1),
+                                _step(field, held[-1], +1)}):
+            yield field, b
+
+
+@pytest.mark.parametrize("negated", [False, True], ids=["filter", "must_not"])
+@pytest.mark.parametrize("op", list(OPS))
+@pytest.mark.parametrize("field,bound", list(_bounds()),
+                         ids=lambda v: v if isinstance(v, str) else repr(v))
+def test_a_range_decides_as_the_64_bit_compare_does(exact, field, bound, op,
+                                                    negated):
+    vals, has = _held(field)
+    inside = has & OPS[op](vals, vals.dtype.type(bound))
+    want = set(np.flatnonzero(~inside if negated else inside).tolist())
+    clause = "must_not" if negated else "filter"
+    got = _packed_ids(exact, "ex", {"bool": {
+        "must": [{"match": {"body": "word"}}],
+        clause: [{"range": {field: {op: bound}}}]}})
+    # a row without a value fails every range and passes its negation
+    assert got == want and (set(np.flatnonzero(~has)) <= got) is negated
+
+
+def test_the_reference_tells_apart_what_a_float64_column_could_not():
+    assert float(2 ** 53) == float(2 ** 53 + 1) and 1.0 < ULP
+    vals, has = _held("n")
+    assert (vals[has] > 2 ** 53).sum() == 2      # 2^53 + 1 and 2^62
+
+
+@pytest.mark.parametrize("field,bounds,want", [
+    # an empty interval matches nothing, and its negation everything
+    ("n", {"gte": 7, "lte": 0}, set()),
+    ("n", {"gt": 2 ** 53, "lt": 2 ** 53 + 1}, set()),
+    ("ts", {"gt": T0, "lt": T0 + 1}, set()),
+    ("x", {"gt": 1.0, "lt": ULP}, set()),
+    ("tag", {"gt": "b", "lt": "bb"}, set()),
+    # between two held values, a millisecond or an ulp apart
+    ("n", {"gt": 2 ** 53, "lte": 2 ** 53 + 1}, {5}),
+    ("ts", {"gte": T0 + 1, "lt": T0 + 2}, {1, 5}),
+    ("x", {"gt": 1.0, "lte": ULP}, {2, 8}),
+    ("x", {"gte": -0.0, "lte": 0.0}, {9}),
+    ("tag", {"gte": "bb", "lt": "f"}, {2, 6, 7}),
+    # a fraction over a whole-number column, and beyond int64
+    ("n", {"gt": 6.5, "lt": 7.5}, {2, 7}),
+    ("n", {"gte": 2 ** 63}, set()),
+    ("n", {"lt": 2 ** 70, "gt": -(2 ** 70)}, {0, 1, 2, 4, 5, 6, 7, 9}),
+    ("n", {"gte": "7", "lte": "7"}, {2, 7}),
+])
+def test_an_interval_and_its_negation(exact, field, bounds, want):
+    must = [{"match": {"body": "word"}}]
+    assert _packed_ids(exact, "ex", {"bool": {
+        "must": must, "filter": [{"range": {field: bounds}}]}}) == want
+    assert _packed_ids(exact, "ex", {"bool": {
+        "must": must, "must_not": [{"range": {field: bounds}}]}}) \
+        == set(range(N_ROWS)) - want
+
+
+@pytest.mark.parametrize("field,values,want", [
+    ("n", [2 ** 53 + 1], {5}),
+    ("n", [2 ** 53], {4}),
+    ("n", [8], set()),                  # a value no document holds
+    ("n", [7.5], set()),
+    ("n", [8, 7, "0", 2 ** 64], {1, 2, 7}),
+    ("ts", [T0 + 1], {1, 5}),
+    ("ts", [T0 - 1], set()),
+    ("x", [ULP], {2, 8}),
+    ("x", [1.0], {1, 6}),
+    ("x", [0], {9}),
+    ("tag", ["bb"], {7}),
+    ("tag", ["c"], set()),
+    ("tag", ["a", "b", "z"], {0, 3, 9}),
+])
+def test_a_term_and_its_negation(exact, field, values, want):
+    must = [{"match": {"body": "word"}}]
+    assert _packed_ids(exact, "ex", {"bool": {
+        "must": must, "filter": [{"terms": {field: values}}]}}) == want
+    # a row without a value equals no target: its negation keeps it
+    assert _packed_ids(exact, "ex", {"bool": {
+        "must": must, "must_not": [{"terms": {field: values}}]}}) \
+        == set(range(N_ROWS)) - want
+
+
+def test_a_keyword_and_a_numeric_column_in_one_batch(exact):
+    """One `_msearch`: a body on a keyword column, one on a date, one on a
+    double and a long, one on a keyword and a long, one with no filter."""
+    must = [{"match": {"body": "word"}}]
+    filters = [
+        [{"term": {"tag": "d"}}],
+        [{"range": {"ts": {"gt": T0, "lte": T0 + 2}}}],
+        [{"range": {"x": {"gte": 1.0}}}, {"range": {"n": {"lt": 2 ** 53}}}],
+        [{"range": {"tag": {"lte": "bb"}}}, {"term": {"n": -5}}],
+        []]
+    want = [{2, 6}, {1, 5, 8}, {1, 2}, {0, 9}, set(range(N_ROWS))]
+    svc = exact.indices["ex"]
+    before = svc.search_stats.get("packed", 0)
+    out = exact.msearch([({"index": "ex"}, {"query": {"bool": {
+        "must": must, "filter": f}}, "size": 50}) for f in filters])
+    assert svc.search_stats.get("packed", 0) == before + len(filters)
+    assert [{int(h["_id"]) for h in r["hits"]["hits"]}
+            for r in out["responses"]] == want
+    view = svc.packed_view()
+    assert {f: (c.kind, c.vals.dtype.name, c.distinct.dtype.name)
+            for f, c in view._filter_cols.items()} == {
+        "tag": ("keyword", "int32", "object"),
+        "ts": ("numeric", "int32", "int64"),
+        "x": ("numeric", "int32", "float64"),
+        "n": ("numeric", "int32", "int64")}
+
+
+def _described(node, index, query):
+    """A body's filter descriptors as `_filter_descriptors` makes them."""
     from elasticsearch_tpu.search.query_parser import QueryParser
     from elasticsearch_tpu.serving.executor import packed_spec_of
+    svc = node.indices[index]
+    spec = packed_spec_of(QueryParser(svc.mappers), {"query": query})
+    return svc.packed_view(), svc.packed_view()._filter_descriptors(
+        [spec[0]], 1)
 
-    def described(node, index, query):
-        svc = node.indices[index]
-        spec = packed_spec_of(QueryParser(svc.mappers), {"query": query})
-        fields, _, fr_lo, fr_hi, fr_how, *_ = \
-            svc.packed_view()._filter_descriptors([spec[0]], 1)
-        return fields, float(fr_lo[0, 0]), float(fr_hi[0, 0]), fr_how[0, 0]
 
-    ts = 1211699326344
-    assert described(wikif[0], CFG["index"], {"bool": {
-        "must": [{"match": {"body": "t000100"}}], "filter": [{"range": {
-            "timestamp": {"gt": ts, "lt": ts + 5}}}]}}) == (
-        ("timestamp",), float(ts), ts + 5.0, RANGE_LO_OPEN | RANGE_HI_OPEN)
-    quick = {"match": {"body": "quick"}}
-    assert described(node, "px", {"bool": {"must": [quick], "filter": [
-        {"range": {"rating": {"gte": 2.5, "lt": 4.5}}}]}}) == (
-        ("rating",), 2.5, 4.5, RANGE_HI_OPEN)
-    assert described(node, "px", {"bool": {"must": [quick], "must_not": [
-        {"range": {"price": {"gt": 20}}}]}}) == (
-        ("price",), 20.0, np.inf, RANGE_LO_OPEN | RANGE_NEGATED)
+@pytest.mark.parametrize("clause,field,bounds,ends,neg", [
+    # (least rank inside, greatest rank inside) among the distinct values:
+    # n: -5 0 7 2^53 2^53+1 2^62; ts: T0-3 T0 T0+1 T0+2 T0+1d;
+    # x: -1.5 -0.0 1.0 ULP 2.5 1e300; tag: b bb d f
+    ("filter", "ts", {"gt": T0, "lt": T0 + 2}, (2, 2), 0),
+    ("filter", "ts", {"gte": T0, "lte": T0 + 2}, (1, 3), 0),
+    ("filter", "ts", {"gt": T0 - 2, "lt": T0 - 1}, (1, 0), 0),   # empty
+    ("filter", "x", {"gte": 1.0, "lt": 2.5}, (2, 3), 0),
+    ("filter", "x", {"gt": 1.0}, (3, 5), 0),
+    ("must_not", "n", {"gt": 7}, (3, 5), 1),
+    ("filter", "n", {"gte": 2 ** 53 + 1}, (4, 5), 0),
+    ("filter", "n", {"gt": 6.5, "lte": 2.0 ** 53}, (2, 3), 0),
+    ("filter", "n", {"lt": -5}, (0, -1), 0),                     # empty
+    ("filter", "n", {"gt": 2 ** 62}, (6, 5), 0),                 # empty
+    ("filter", "n", {"gte": float("-inf"), "lte": float("inf")}, (0, 5), 0),
+    ("filter", "tag", {"gt": "b", "lte": "e"}, (1, 2), 0),
+    ("filter", "nope", {"gte": 1}, None, 0),         # no such column: -2
+])
+def test_the_host_resolves_a_range_to_an_inclusive_interval_of_ranks(
+        exact, clause, field, bounds, ends, neg):
+    """An open end, a bound between two values and a missing bound are
+    decided on the host, where the values are exact: the program is handed
+    ordinals, and no bit says which end is open."""
+    view, (fields, fr_col, fr_lo, fr_hi, fr_neg, ft_col, ft_targets, _) = \
+        _described(exact, "ex", {"bool": {
+            "must": [{"match": {"body": "word"}}],
+            clause: [{"range": {field: bounds}}]}})
+    assert all(a.dtype == np.int32 for a in (fr_col, fr_lo, fr_hi, fr_neg,
+                                             ft_col, ft_targets))
+    assert fr_neg[0].tolist() == [neg, 0] and fr_col[0, 1] == -1
+    if ends is None:
+        assert fields == () and fr_col[0, 0] == -2
+        return
+    assert fields == (field,) and fr_col[0, 0] == 0
+    assert (int(fr_lo[0, 0]), int(fr_hi[0, 0])) == ends
+    want = sorted({v for v in HELD[field] if v is not None})
+    assert view.filter_column(field).distinct.tolist() == want
+
+
+def test_the_host_resolves_a_term_to_its_rank_or_to_no_ordinal(exact):
+    from elasticsearch_tpu.ops.bm25_sparse import NO_ORDINAL
+    _, (fields, *_, ft_col, ft_targets, ft_neg) = _described(exact, "ex", {
+        "bool": {"must": [{"match": {"body": "word"}}],
+                 "filter": [{"terms": {"n": [2 ** 53 + 1, 8, 7.0, "x"]}}],
+                 "must_not": [{"term": {"tag": "d"}}]}})
+    assert fields == ("n", "tag")
+    assert ft_col[0].tolist() == [0, 1] and ft_neg[0].tolist() == [0, 1]
+    assert ft_targets[0].tolist() == [
+        [4, NO_ORDINAL, 2, NO_ORDINAL], [2] + [NO_ORDINAL] * 3]
+    assert NO_ORDINAL < -1      # a row without a value (-1) equals no target
+
+
+def _equations(jaxpr, inside=()):
+    """Every equation of a jaxpr and of the jaxprs in its parameters, with
+    the primitives it lies inside."""
+    for eqn in jaxpr.eqns:
+        yield eqn, inside
+        for p in eqn.params.values():
+            for sub in p if isinstance(p, (tuple, list)) else (p,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _equations(
+                        sub, inside + (eqn.primitive.name,))
+
+
+@pytest.mark.parametrize("S", [128, 256])
+def test_the_filtered_program_holds_no_float64_and_gathers_once_a_block(S):
+    """The jaxpr at the cell's two shapes (Q 256, S 128 | 256, k 1024, two
+    columns): int32 filter operands, no float64 value anywhere, and in the
+    loop over blocks of candidate rows ONE gather that reads the columns
+    (the float64 form was two on the TPU, a high and a low half)."""
+    import jax
+    import jax.numpy as jnp
+    from elasticsearch_tpu.ops import bm25_sparse as K
+    from elasticsearch_tpu.serving.packed_view import (
+        CHUNK, F_RANGE, F_TERM, F_TERM_VALS)
+    Q, P, N, NC = 256, 1 << 25, 262_144, 2
+    sd = jax.ShapeDtypeStruct
+    traced = K.bm25_serve_packed_filtered.jit.trace(
+        sd((Q, 3 * S + 1), jnp.int32), sd((P,), jnp.int32),
+        sd((P,), jnp.float32), sd((P,), jnp.float32),
+        *[sd((), jnp.float32)] * 4, sd((NC, N), jnp.int32),
+        *[sd((Q, F_RANGE), jnp.int32)] * 4, sd((Q, F_TERM), jnp.int32),
+        sd((Q, F_TERM, F_TERM_VALS), jnp.int32), sd((Q, F_TERM), jnp.int32),
+        S=S, CHUNK=CHUNK, R=8, k=1024,
+        FR=F_RANGE, FT=F_TERM, TV=F_TERM_VALS)
+    eqns = list(_equations(traced.jaxpr.jaxpr))
+    values = [(v.aval, inside) for eqn, inside in eqns
+              for v in (*eqn.invars, *eqn.outvars) if hasattr(v, "aval")]
+    # (a Python literal of the shared phases, `0.0` or `-inf`, is a weakly
+    # typed scalar until the next equation makes it a float32: no value)
+    assert not [a for a, inside in values if a.dtype == jnp.float64
+                and not (a.weak_type and a.shape == ()
+                         and "while" not in inside)]
+    assert {a.dtype.name for a, inside in values if "while" in inside} \
+        == {"int32", "bool"}
+    column_reads = [(eqn, inside) for eqn, inside in eqns
+                    if eqn.primitive.name == "gather"
+                    and eqn.invars[0].aval.shape[-2:] == (NC, N)]
+    (eqn, inside), = column_reads
+    assert "while" in inside
+    assert eqn.invars[0].aval.dtype == eqn.outvars[0].aval.dtype == jnp.int32
+    # FILTER_ROWS candidate rows a block, both columns at once
+    assert int(np.prod(eqn.outvars[0].aval.shape)) == NC * K.FILTER_ROWS
 
 
 @pytest.mark.parametrize("clause,field,bounds,want", [

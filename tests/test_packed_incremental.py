@@ -90,6 +90,68 @@ class TestIncrementalExtension:
         want = {str(i) for i in range(20, 32) if i % 3 == 1}
         assert {h["_id"] for h in out3["hits"]["hits"]} == want
 
+    @pytest.mark.parametrize("field", ["price", "tag"])
+    @pytest.mark.parametrize("second,reranked", [
+        # values between, below and above the old ones: every old rank moves
+        ([(5, "a"), (15, "c"), (25, "e"), (35, "g"), (20, "d")], True),
+        # only values the base holds: the old rows come over as they are
+        ([(30, "b"), (10, "f"), (20, "d")], False)],
+        ids=["new-values", "known-values"])
+    def test_extended_filter_column_equals_one_built_anew(
+            self, node, field, second, reranked):
+        """A refresh extends the column: ordinals and distinct values as a
+        view built from all segments has them, and the span says how many
+        head rows went through the lookup table."""
+        from elasticsearch_tpu.common import tracing
+
+        def index(rows, at):
+            for i, (price, tag) in enumerate(rows):
+                node.index_doc("inc", str(at + i), {
+                    "body": "common", "price": price, "tag": tag})
+            node.refresh("inc")
+
+        def ids(flt):
+            out = node.search("inc", {"query": {"bool": {
+                "must": [{"match": {"body": "common"}}],
+                "filter": [flt]}}, "size": 50})
+            return {int(h["_id"]) for h in out["hits"]["hits"]}
+
+        first = [(10, "b"), (20, "d"), (30, "f"), (20, "d")]
+        index(first, 0)
+        assert ids({"range": {"price": {"gt": 10, "lte": 30}}}) == {1, 2, 3}
+        assert ids({"range": {"tag": {"gt": "b", "lte": "f"}}}) == {1, 2, 3}
+        base = node.indices["inc"].packed_view()
+        index(second, len(first))
+        with tracing.Tracer().request("test") as trace:
+            view = node.indices["inc"].packed_view()
+        assert view is not base and view.extended_from_base
+        spans = {s.attrs["field"]: s.attrs for s in trace.spans
+                 if s.name == "packed.filter_column"}
+        rows = first + second
+        want = sorted({r[field == "tag"] for r in rows})
+        assert spans[field]["reranked"] == \
+            (base.n_total if reranked else 0)
+        assert spans[field]["distinct"] == len(want)
+        assert spans[field]["host_bytes"] == 8 * len(want)
+        assert spans[field]["bytes"] == 4 * view.n_pad_total
+        col, anew = view._filter_cols[field], _fresh_view(node) \
+            .filter_column(field)
+        assert col.distinct.tolist() == anew.distinct.tolist() == want
+        assert col.distinct.dtype == anew.distinct.dtype
+        assert col.vals.dtype == anew.vals.dtype == np.int32
+        np.testing.assert_array_equal(np.asarray(col.vals),
+                                      np.asarray(anew.vals))
+        # every row's rank names its own value; padding rows hold -1
+        held = np.asarray(col.vals)[:view.n_total]
+        real = [r[field == "tag"] for r in rows]
+        assert sorted(col.distinct[held[held >= 0]].tolist()) == sorted(real)
+        assert (np.asarray(col.vals)[view.n_total:] == -1).all()
+        lo, hi = (("gt", 10), ("lte", 30)) if field == "price" \
+            else (("gt", "b"), ("lte", "f"))
+        assert ids({"range": {field: dict([lo, hi])}}) == {
+            i for i, r in enumerate(rows)
+            if r[field == "tag"] > lo[1] and r[field == "tag"] <= hi[1]}
+
     def test_merge_triggers_full_rebuild(self, node):
         _index_batch(node, 0, 10)
         node.search("inc", {"query": {"match": {"body": "common"}}})

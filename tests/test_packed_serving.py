@@ -668,18 +668,17 @@ class TestLivenessFold:
                 *args, S=S, CHUNK=CHUNK, R=8, k=1024)
         else:
             low = K.bm25_serve_packed_filtered.jit.lower(
-                *args, sd((1, N), jnp.float64),
-                sd((Q, F_RANGE), jnp.int32), sd((Q, F_RANGE), jnp.float64),
-                sd((Q, F_RANGE), jnp.float64), sd((Q, F_RANGE), jnp.int32),
+                *args, sd((1, N), jnp.int32),
+                *[sd((Q, F_RANGE), jnp.int32)] * 4,
                 sd((Q, F_TERM), jnp.int32),
-                sd((Q, F_TERM, F_TERM_VALS), jnp.float64),
+                sd((Q, F_TERM, F_TERM_VALS), jnp.int32),
                 sd((Q, F_TERM), jnp.int32), S=S, CHUNK=CHUNK, R=8, k=1024,
                 FR=F_RANGE, FT=F_TERM, TV=F_TERM_VALS)
         text = low.as_text()
         params = re.search(r"func\.func public @main\((.*?)\)\s*->", text,
                            re.S).group(1)
         assert "xi1>" not in params and f"tensor<{N}x" not in \
-            params.replace(f"tensor<1x{N}xf64>", "")
+            params.replace(f"tensor<1x{N}xi32>", "")
         per_slot = []           # (operand type, result type) of such gathers
         for m in re.finditer(
                 r'"stablehlo\.gather"\(.*?slice_sizes = array<i64: ([\d, ]+)>'
@@ -692,7 +691,7 @@ class TestLivenessFold:
             if elements >= K.FILTER_ROWS and set(sizes.split(", ")) == {"1"}:
                 per_slot.append((operand, dtype))
         assert per_slot == ([] if program == "plain"
-                            else [(f"tensor<1x{N}xf64>", "f64")])
+                            else [(f"tensor<1x{N}xi32>", "i32")])
 
 
 # -- a batch's operands ride the program's own dispatch (ISSUE 32) ------------
@@ -863,7 +862,7 @@ def test_warm_search_is_one_program_and_nothing_else(case, device_calls):
     assert prep["consts"] == "reused" and prep["operands"] == operands
     Q_pad = 1 if len(queries) == 1 else 32
     table = 4 * Q_pad * (3 * 32 + 1)
-    descriptors = Q_pad * (2 * (4 + 8 + 8 + 4) + 2 * (4 + 4 * 8 + 4))
+    descriptors = Q_pad * (2 * (4 + 4 + 4 + 4) + 2 * (4 + 4 * 4 + 4))
     assert prep["h2d_bytes"] == uploaded \
         == table + (descriptors if operands == 8 else 0)
     for a, b in zip(first, again):

@@ -121,13 +121,14 @@ def test_kernel_compiles_for_the_chip(one_chip, N, P):
 def test_filtered_program_compiles_for_the_chip_at_the_cells_shapes(
         one_chip, monkeypatch):
     """The largest program of `wiki.filtered-top1000`: 256 bodies x 256
-    slots, k 1024, two float64 columns over 262,144 documents, 2^25
+    slots, k 1024, two int32 ordinal columns over 262,144 documents, 2^25
     postings. With the gather of the columns over all 256 queries at once
-    the chip's compiler refused it: the result, f32[2^25, 2], is laid out
+    the chip's compiler refused it: the result, [2^25, 2], is laid out
     with its two columns padded to a tile's 128 lanes, 16 GB of the 15.75
     the chip has, and every request with a body of more than 128 slots came
     back as 256 item errors (my chip run, PR 33). `FILTER_ROWS` candidate
-    rows at a time since."""
+    rows at a time since, and since PR 34 one int32 gather a block where
+    the float64 columns took two (a high and a low float32 half)."""
     from elasticsearch_tpu.serving.packed_view import (F_RANGE, F_TERM,
                                                        F_TERM_VALS)
     Q, S, P, N, NC = 256, 256, 1 << 25, 262_144, 2
@@ -140,16 +141,17 @@ def test_filtered_program_compiles_for_the_chip_at_the_cells_shapes(
     compiled = K.bm25_serve_packed_filtered.jit.lower(
         sd((Q, 3 * S + 1), jnp.int32), sd((P,), jnp.int32),
         sd((P,), jnp.float32), sd((P,), jnp.float32),
-        *[sd((), jnp.float32)] * 4, sd((NC, N), jnp.float64),
-        sd((Q, F_RANGE), jnp.int32), sd((Q, F_RANGE), jnp.float64),
-        sd((Q, F_RANGE), jnp.float64), sd((Q, F_RANGE), jnp.int32),
-        sd((Q, F_TERM), jnp.int32), sd((Q, F_TERM, F_TERM_VALS), jnp.float64),
+        *[sd((), jnp.float32)] * 4, sd((NC, N), jnp.int32),
+        *[sd((Q, F_RANGE), jnp.int32)] * 4,
+        sd((Q, F_TERM), jnp.int32), sd((Q, F_TERM, F_TERM_VALS), jnp.int32),
         sd((Q, F_TERM), jnp.int32), S=S, CHUNK=CHUNK, R=8, k=1024,
         FR=F_RANGE, FT=F_TERM, TV=F_TERM_VALS).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-    # two padded blocks of 2 GB and a few [Q, S x CHUNK] rows (it read
-    # 17.0 GB)
-    assert compiled.memory_analysis().temp_size_in_bytes < 4 << 30
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "f64[" not in text
+    # one padded block of 2.1 GB and a few [Q, S x CHUNK] rows: 2.79 GB.
+    # (The float64 columns' two blocks took turns at one buffer: 3.01 GB,
+    # the low halves' compares beside it; all queries at once read 17.0 GB.)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2_900_000_000
 
 
 @pytest.mark.parametrize("kind,Q", [("hist", 4), ("count", 1), ("terms", 1)])

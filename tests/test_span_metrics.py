@@ -338,10 +338,10 @@ def test_packed_program_names_every_scope():
     assert named(plain) == scopes
     FR, FT, TV = 2, 2, 4
     filtered = bm25_serve_packed_filtered.jit.lower(
-        *common, jnp.zeros((1, N), jnp.float64),
-        jnp.full((Q, FR), -1, jnp.int32), jnp.zeros((Q, FR)),
-        jnp.zeros((Q, FR)), jnp.zeros((Q, FR), jnp.int32),
-        jnp.full((Q, FT), -1, jnp.int32), jnp.zeros((Q, FT, TV)),
+        *common, jnp.zeros((1, N), jnp.int32),
+        jnp.full((Q, FR), -1, jnp.int32), jnp.zeros((Q, FR), jnp.int32),
+        jnp.zeros((Q, FR), jnp.int32), jnp.zeros((Q, FR), jnp.int32),
+        jnp.full((Q, FT), -1, jnp.int32), jnp.zeros((Q, FT, TV), jnp.int32),
         jnp.zeros((Q, FT), jnp.int32),
         S=S, CHUNK=CHUNK, R=4, k=8, FR=FR, FT=FT, TV=TV)
     assert named(filtered) == scopes | {"packed.filters"}

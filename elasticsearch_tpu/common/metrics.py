@@ -368,22 +368,6 @@ def packed_render_snapshot() -> dict:
                 for form, n in _PACKED_RENDER.items()}
 
 
-# a packed batch's four BM25 scalar operands (PackedIndexView._constants):
-# es_packed_consts_total{state=}. "made" once a (view, field, k1, b), then
-# "reused" by every batch: a warm window reads 100 % reused.
-_PACKED_CONSTS = {"reused": 0, "made": 0}
-
-
-def record_packed_consts(state: str) -> None:
-    with _DEVICE_LOCK:
-        _PACKED_CONSTS[state] += 1
-
-
-def packed_consts_snapshot() -> dict:
-    with _DEVICE_LOCK:
-        return {state: {"total": n} for state, n in _PACKED_CONSTS.items()}
-
-
 def transfer_snapshot() -> dict:
     """Process-wide host↔device transfer counters (every device_fetch /
     note_h2d call accounts here, profiler active or not) — the scrape's

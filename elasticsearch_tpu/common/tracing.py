@@ -37,7 +37,14 @@ path. The same two clock reads of a span feed three sinks:
 
   1. `AGGREGATE`, always on: count / seconds / self seconds / max by span
      name, `es_span_*{span=}` on `/_metrics`. Self time is the duration
-     minus what child `span()` blocks on the SAME thread cover.
+     minus what child `span()` blocks on the SAME thread cover. One in
+     `CPU_SAMPLE` of the spans opened with `cpu=True` (the host-compute
+     spans) also reads the thread's own CPU time (`time.thread_time_ns`)
+     beside its two clock reads, and books it with its own wall time:
+     wall minus CPU is time the thread was ready but off the CPU (the
+     interpreter lock, a core). Only so many do: on the chip's host that
+     clock is a system call of about 6 µs that ticks in 10 ms (PERF.md §6,
+     PR 35), fine summed over a window's spans and too dear for every one.
   2. a `jax.profiler.TraceAnnotation("es:<name>")` held open for the
      block, so a running profiler session (xprof, the benchmark's traced
      slice) shows the span on the host plane beside `XLA Ops`, on the
@@ -48,12 +55,19 @@ path. The same two clock reads of a span feed three sinks:
 (common/device_stats.InstrumentedProgram) and also feeds `GAPS`, the
 device-gap ledger: whenever no program is in flight the device is idle as
 the host sees it, and the gap is charged to the spans of the thread that
-ends it (`es_device_gap_seconds_total{during=}`).
+ends it (`es_device_gap_seconds_total{during=}`); the longest gaps are kept
+as records (`GET /_nodes/device_gaps`). A flight that took off behind
+others books `program.queue` up to the landing of the last of them: the
+device's one queue as the host sees it. The profiler event of a flight
+carries `t0_ns`, its start on this module's clock, so any `es:program`
+event of a capture gives `offset = event.start_ns - t0_ns`, which maps
+every timestamp here (a span's, a gap record's) onto that capture.
 """
 
 from __future__ import annotations
 
 import contextlib
+import itertools
 import random
 import threading
 import time
@@ -71,6 +85,10 @@ _ACTIVE: ContextVar["tuple[Trace, Span] | None"] = \
 
 # every timestamp of this module is one read of this clock (a test seam)
 _clock = time.monotonic_ns
+# and every CPU time one read of this one, of the calling thread
+_cpu_clock = time.thread_time_ns
+CPU_SAMPLE = 4
+_cpu_turn = itertools.count()
 
 
 def now_ns() -> int:
@@ -202,32 +220,44 @@ class Trace:
 # ---------------------------------------------------------------------------
 
 class SpanAggregate:
-    """count / total / self / max nanoseconds by span name. Names are the
-    static strings of the call sites, so the table is bounded by the code."""
+    """count / total / self / max nanoseconds by span name, and for names
+    whose spans read the CPU clock the CPU and wall nanoseconds of those
+    that did. Names are the static strings of the call sites, so the table
+    is bounded by the code."""
 
     def __init__(self):
         self._lock = threading.Lock()
-        self._rows: dict[str, list[int]] = {}
+        self._rows: dict[str, list] = {}
 
-    def add(self, name: str, dur_ns: int, self_ns: int) -> None:
+    def add(self, name: str, dur_ns: int, self_ns: int,
+            cpu: tuple[int, int] | None = None) -> None:
+        """`cpu`: (CPU ns, wall ns) of a span that read the CPU clock."""
         with self._lock:
             row = self._rows.get(name)
             if row is None:
-                row = self._rows[name] = [0, 0, 0, 0]
+                row = self._rows[name] = [0, 0, 0, 0, None, 0]
             row[0] += 1
             row[1] += dur_ns
             row[2] += self_ns
             if dur_ns > row[3]:
                 row[3] = dur_ns
+            if cpu is not None:
+                row[4] = (row[4] or 0) + cpu[0]
+                row[5] += cpu[1]
 
     def stats(self) -> dict[str, dict]:
         """The `es_span_*{span=}` payload of the `/_metrics` walk."""
         with self._lock:
             rows = {n: tuple(r) for n, r in self._rows.items()}
-        return {n: {"total": r[0], "seconds_total": r[1] / 1e9,
-                    "self_seconds_total": r[2] / 1e9,
-                    "max_seconds": r[3] / 1e9}
-                for n, r in sorted(rows.items())}
+        out = {}
+        for n, r in sorted(rows.items()):
+            out[n] = {"total": r[0], "seconds_total": r[1] / 1e9,
+                      "self_seconds_total": r[2] / 1e9,
+                      "max_seconds": r[3] / 1e9}
+            if r[4] is not None:
+                out[n]["cpu_seconds_total"] = r[4] / 1e9
+                out[n]["cpu_wall_seconds_total"] = r[5] / 1e9
+        return out
 
 
 AGGREGATE = SpanAggregate()
@@ -298,27 +328,55 @@ def charge_gap(g0: int, g1: int, state: _ThreadState) -> dict[str, int]:
     return out
 
 
+class _Flight:
+    """One program in flight: when it took off, how many flights were in
+    flight then and have not landed, and when the last of them landed."""
+
+    __slots__ = ("takeoff_ns", "ahead", "queued", "queue_end_ns")
+
+    def __init__(self, takeoff_ns: int, ahead: int):
+        self.takeoff_ns = takeoff_ns
+        self.ahead = ahead
+        self.queued = ahead > 0
+        self.queue_end_ns: int | None = None
+
+
 class GapLedger:
-    """Process-wide count of programs in flight (dispatch to ready, as the
-    blocked host thread sees it). While it is 0 the device is idle in the
-    host's view; the dispatch that ends a gap charges it to its thread's
-    spans. A host view: a flight includes dispatch latency and the wake-up
-    of the blocked thread, so the gap total reads at or below the device
-    trace's idle time."""
+    """Process-wide record of the programs in flight (dispatch to ready, as
+    the blocked host thread sees it). While none is the device is idle in
+    the host's view; the dispatch that ends a gap charges it to its
+    thread's spans. A host view: a flight includes dispatch latency and the
+    wake-up of the blocked thread, so the gap total reads at or below the
+    device trace's idle time.
+
+    The host's view of the device's one queue: a flight that takes off
+    while others are in flight waits for them, until the last of them
+    lands, and books that wait as `program.queue` when it lands itself
+    (`program` less `program.queue` is then its own time). The longest
+    gaps are kept with their charges: `RECORDS_A_SECOND` a second of this
+    module's clock (by their start), for the last `RECORD_SECONDS`."""
+
+    RECORDS_A_SECOND = 4
+    RECORD_SECONDS = 600
+    RECORD_CHARGES = 4          # the largest charges of a record; the rest
+    # are summed under "other", so a record's charges sum to its length
 
     def __init__(self):
         self._lock = threading.Lock()
-        self._in_flight = 0
+        self._flying: list[_Flight] = []     # in take-off order
         self._flight_start_ns = 0
         self._idle_since_ns: int | None = None   # None before any landing
         self._flight_ns = 0
         self._gap_ns: dict[str, int] = {}
+        # [second, [(length, start, end, charges), ...]], oldest first
+        self._records: deque = deque()
 
-    def takeoff(self, now: int, state: _ThreadState) -> None:
+    def takeoff(self, now: int, state: _ThreadState) -> _Flight:
         gap0 = None
         with self._lock:
-            self._in_flight += 1
-            if self._in_flight == 1:
+            flight = _Flight(now, len(self._flying))
+            self._flying.append(flight)
+            if flight.ahead == 0:
                 self._flight_start_ns = now
                 gap0 = self._idle_since_ns
         if gap0 is not None and now > gap0:
@@ -326,19 +384,61 @@ class GapLedger:
             with self._lock:
                 for name, ns in charges.items():
                     self._gap_ns[name] = self._gap_ns.get(name, 0) + ns
+                self._keep(gap0, now, charges)
         # a later gap starts at a landing, so after now: the closed spans
         # of this thread can lie in none
         state.trail.clear()
+        return flight
 
-    def land(self, now: int) -> None:
+    def land(self, now: int, flight: _Flight) -> None:
         with self._lock:
-            self._in_flight -= 1
-            if self._in_flight == 0:
+            i = self._flying.index(flight)
+            # every flight behind this one took off while it was in flight
+            for behind in self._flying[i + 1:]:
+                behind.ahead -= 1
+                if behind.ahead == 0:
+                    behind.queue_end_ns = now
+            del self._flying[i]
+            if not self._flying:
                 # threads race from their clock read to this lock: never
                 # let a landing be booked before its flight's start
                 now = max(now, self._flight_start_ns)
                 self._idle_since_ns = now
                 self._flight_ns += now - self._flight_start_ns
+        if flight.queued:
+            end = now if flight.queue_end_ns is None \
+                else min(flight.queue_end_ns, now)
+            add_span("program.queue", flight.takeoff_ns, end)
+
+    def _keep(self, g0: int, g1: int, charges: dict[str, int]) -> None:
+        """Under the lock: the gap [g0, g1) among its second's longest.
+        Gaps end in time order (the flight that ends one is in flight
+        until the next can start), so a new second is the newest."""
+        second = g0 // 1_000_000_000
+        if not self._records or self._records[-1][0] != second:
+            self._records.append([second, []])
+            while self._records[0][0] <= second - self.RECORD_SECONDS:
+                self._records.popleft()
+        kept = self._records[-1][1]
+        if len(kept) == self.RECORDS_A_SECOND:
+            shortest = min(kept)            # by length, then start
+            if shortest[0] >= g1 - g0:
+                return
+            kept.remove(shortest)
+        top = sorted(charges.items(), key=lambda kv: -kv[1])
+        during = dict(top[:self.RECORD_CHARGES])
+        rest = sum(ns for _, ns in top[self.RECORD_CHARGES:])
+        if rest:
+            during["other"] = rest
+        kept.append((g1 - g0, g0, g1, during))
+
+    def gap_records(self) -> list[dict]:
+        """The `GET /_nodes/device_gaps` payload: the kept gaps on this
+        module's clock (`monotonic_ns`), newest last."""
+        with self._lock:
+            kept = [r for _, recs in self._records for r in recs]
+        return [{"start_ns": g0, "end_ns": g1, "during": dict(during)}
+                for _, g0, g1, during in sorted(kept, key=lambda r: r[1])]
 
     def gap_stats(self) -> dict[str, dict]:
         """The `es_device_gap_seconds_total{during=}` payload."""
@@ -382,11 +482,14 @@ class _SpanCtx:
     context itself are there either way, `end_ns` once the block ended."""
 
     __slots__ = ("name", "attrs", "start_ns", "end_ns", "_entered_ns",
-                 "_state", "_span", "_tok", "_ann", "_child_ns")
+                 "_cpu", "_cpu_ns", "_state", "_span", "_tok", "_ann",
+                 "_child_ns")
 
-    def __init__(self, name: str, start_ns: int | None, attrs: dict):
+    def __init__(self, name: str, start_ns: int | None, attrs: dict,
+                 cpu: bool = False):
         self.name = name
         self.attrs = attrs
+        self._cpu = cpu and next(_cpu_turn) % CPU_SAMPLE == 0
         self.start_ns = start_ns
         self.end_ns = None
         self._span = None
@@ -401,10 +504,15 @@ class _SpanCtx:
     def __enter__(self) -> Span | None:
         self._ann = TraceAnnotation("es:" + self.name, **self.attrs)
         self._ann.__enter__()
+        return self._open(_clock())
+
+    def _open(self, now: int) -> Span | None:
         # a backdated span is on this thread only from now on: self time
         # and the gap ledger count it from here, so that the spans of one
         # thread always nest
-        self._entered_ns = _clock()
+        self._entered_ns = now
+        if self._cpu:
+            self._cpu_ns = _cpu_clock()
         if self.start_ns is None:
             self.start_ns = self._entered_ns
         self._state = _thread_state()
@@ -424,42 +532,54 @@ class _SpanCtx:
 
     def __exit__(self, *exc) -> bool:
         end = self.end_ns = _clock()
+        cpu = _cpu_clock() - self._cpu_ns if self._cpu else None
         self._ann.__exit__(None, None, None)
         dur = end - self.start_ns
         here = end - self._entered_ns
+        if cpu is not None:
+            cpu = (cpu, here)
         st = self._state
         st.stack.pop()
         if st.stack:
             st.stack[-1]._child_ns += here
         st.trail.append((self.name, self._entered_ns, end))
-        AGGREGATE.add(self.name, dur, max(dur - self._child_ns, 0))
+        AGGREGATE.add(self.name, dur, max(dur - self._child_ns, 0), cpu)
         if self._span is not None:
             self._span.end_ns = end
             _ACTIVE.reset(self._tok)
         return False
 
 
-def span(name: str, start_ns: int | None = None, **attrs) -> _SpanCtx:
+def span(name: str, start_ns: int | None = None, *, cpu: bool = False,
+         **attrs) -> _SpanCtx:
     """Time the block as a span: aggregate, profiler annotation and, when a
     request trace is active, a child of the current span. `start_ns`
-    backdates the start (the shard-span-covers-queue-wait case). A stats
+    backdates the start (the shard-span-covers-queue-wait case). `cpu`:
+    the block computes on the host; one such span in `CPU_SAMPLE` books
+    the thread's CPU time in it beside its wall time
+    (`es_span_cpu_seconds_total`, `es_span_cpu_wall_seconds_total`). A stats
     registry that reports the same interval (PhaseTimers, MetricsRegistry,
     RequestProfiler, ProgramRecord) is fed after the block from the
     context's own `start_ns` / `end_ns`, not from a second pair of reads."""
-    return _SpanCtx(name, start_ns, attrs)
+    return _SpanCtx(name, start_ns, attrs, cpu)
 
 
 class _FlightCtx(_SpanCtx):
-    __slots__ = ()
+    __slots__ = ("_flight",)
 
     def __enter__(self) -> Span | None:
-        span_ = super().__enter__()
-        GAPS.takeoff(self.start_ns, self._state)
+        # the profiler's event carries the flight's start on this module's
+        # clock: the anchor of the capture (the request tree leaves it out)
+        t0 = _clock()
+        self._ann = TraceAnnotation("es:program", t0_ns=t0, **self.attrs)
+        self._ann.__enter__()
+        span_ = self._open(t0)
+        self._flight = GAPS.takeoff(self.start_ns, self._state)
         return span_
 
     def __exit__(self, *exc) -> bool:
         super().__exit__(*exc)
-        GAPS.land(self.end_ns)
+        GAPS.land(self.end_ns, self._flight)
         return False
 
 

@@ -813,7 +813,7 @@ class NodeService:
         compiles0 = device_events_snapshot()[0]
         # the request's clock starts with its plan: `took`, the `total`
         # phase and the general lane's `parse` all count from this read
-        planning = tracing.span("search.plan")
+        planning = tracing.span("search.plan", cpu=True)
         with planning:
             plan = self._search_plan(index, body or {}, size, from_,
                                      request_cache, scroll is not None)
@@ -1853,7 +1853,7 @@ class NodeService:
             scores, docs, hits = view.search(field, queries, k=k, k1=k1, b=b)
         except FilterColumnRefused:
             return None    # breaker refused a filter column: general path
-        respond = tracing.span("packed.respond")
+        respond = tracing.span("packed.respond", cpu=True)
         with respond:
             # `took` ends where the rendering starts (the span's own read).
             # serving/executor.respond renders the batch: the `_source:
@@ -2100,7 +2100,7 @@ class NodeService:
         packed_specs: dict[int, Any] = {}
         parsers: dict[str, Any] = {}
         leftovers: list[int] = []
-        planning = tracing.span("search.plan")
+        planning = tracing.span("search.plan", cpu=True)
         with planning:
             for i, (header, body) in enumerate(requests):
                 index = (header or {}).get("index") or "_all"
@@ -2175,7 +2175,7 @@ class NodeService:
             # `rest.serialize` finds bytes and has nothing left to do
             # (the packed lane's items are bytes already; a dict came from
             # another lane or is an item's error)
-            with tracing.span("rest.serialize"):
+            with tracing.span("rest.serialize", cpu=True):
                 return b'{"responses":[' + b",".join(
                     r if isinstance(r, bytes) else json.dumps(r).encode()
                     for r in responses) + b']}'
@@ -2340,7 +2340,7 @@ class NodeService:
         for lo in range(0, len(rows), step):
             chunk = rows[lo:lo + step]
             totals, partials = panels.execute(chunk, view)
-            render = tracing.span("aggs.render", rows=len(chunk))
+            render = tracing.span("aggs.render", rows=len(chunk), cpu=True)
             with render:
                 took = (render.start_ns - t0_ns) // 1_000_000
                 for qi, row in enumerate(chunk):
@@ -3095,7 +3095,6 @@ class NodeService:
         from .common import device_stats, monitor
         from .common.metrics import (device_events_snapshot,
                                      packed_batches_snapshot,
-                                     packed_consts_snapshot,
                                      packed_gather_snapshot,
                                      packed_render_snapshot,
                                      transfer_snapshot)
@@ -3306,9 +3305,6 @@ class NodeService:
             # es_packed_render_hits_total{form=}: hits the packed lane
             # rendered, by how (vector | patched | dict)
             "packed_render": ("form", packed_render_snapshot()),
-            # es_packed_consts_total{state=}: packed batches by whether
-            # their BM25 scalar operands were on the chip (reused | made)
-            "packed_consts": ("state", packed_consts_snapshot()),
             # es_packed_batches_total{program=}: packed batches by the
             # program that answered them (plain | filtered)
             "packed_batches": ("program", packed_batches_snapshot()),

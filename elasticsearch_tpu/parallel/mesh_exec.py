@@ -52,6 +52,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
+from ..common import tracing
 from ..common.cache import Cache
 from ..index.segment import Segment, next_pow2
 from ..ops import bm25
@@ -97,13 +98,15 @@ def exec_guard(pool=None):
     """Serialize device dispatch per pool. pool=None (or the shared
     pool) -> the legacy EXEC_LOCK; an owned DevicePool -> its private
     lock, uncontended across nodes by construction. A "wait" is counted
-    only when the lock was not immediately available."""
+    only when the lock was not immediately available, and timed as the
+    span `exec.lock_wait`."""
     lock = EXEC_LOCK if pool is None else pool.lock
     shared = lock is EXEC_LOCK
     if not lock.acquire(blocking=False):
         with _EXEC_STATS_LOCK:
             _EXEC_STATS["shared_waits" if shared else "pool_waits"] += 1
-        lock.acquire()
+        with tracing.span("exec.lock_wait"):
+            lock.acquire()
     with _EXEC_STATS_LOCK:
         _EXEC_STATS["shared_acquisitions" if shared
                     else "pool_acquisitions"] += 1

@@ -85,7 +85,6 @@ def register_cluster_routes(c, node: ClusterNode) -> None:
         return 200, render_openmetrics(node.metric_sections(),
                                        node=node.node_id)
     c.register("GET", "/_metrics", metrics_local)
-    c.register("GET", "/_prometheus/metrics", metrics_local)
 
     def cluster_metrics(g, p, b):
         # cluster-wide exposition: per-node sections fan out over the

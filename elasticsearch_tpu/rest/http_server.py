@@ -258,7 +258,7 @@ def _register_routes(c: RestController, node: NodeService) -> None:
 
     # -- search (must register before the generic doc routes) -------------
     def search(g, p, b):
-        with tracing.span("rest.parse_body"):
+        with tracing.span("rest.parse_body", cpu=True):
             body = _search_body(p, b)
         scroll = p.get("scroll", [None])[0]
         scan = p.get("search_type", [None])[0] == "scan"
@@ -353,7 +353,7 @@ def _register_routes(c: RestController, node: NodeService) -> None:
     def msearch(g, p, b):
         # NDJSON: alternating header / body lines
         # (ref rest/action/search/RestMultiSearchAction)
-        with tracing.span("rest.parse_body"):
+        with tracing.span("rest.parse_body", cpu=True):
             lines = [json.loads(ln) for ln in b.decode("utf-8").split("\n")
                      if ln.strip()]
             if len(lines) % 2:
@@ -2725,6 +2725,17 @@ def _register_indices_routes(c: RestController, node: NodeService) -> None:
             "tpu-node-0": node.device_stats_payload(top_n=top_n)}}
     c.register("GET", "/_nodes/device_stats", nodes_device_stats)
 
+    def nodes_device_gaps(g, p, b):
+        # the device-gap ledger's longest gaps (common/tracing.GapLedger:
+        # 4 a second for 600 s), each with what the dispatching thread did
+        # in it, on the program's clock; an `es:program` event of a
+        # profiler capture carries `t0_ns` on that clock, and
+        # `start_ns - t0_ns` maps a gap onto the capture
+        return 200, {"cluster_name": node.cluster_name, "nodes": {
+            "tpu-node-0": {"clock": "monotonic_ns",
+                           "gaps": tracing.GAPS.gap_records()}}}
+    c.register("GET", "/_nodes/device_gaps", nodes_device_gaps)
+
     def nodes_stats_history(g, p, b):
         # the StatsSampler ring (common/monitor.py): timestamped gauge
         # samples + min/max/avg rollups, so a spike BETWEEN two stats
@@ -2760,7 +2771,6 @@ def _register_indices_routes(c: RestController, node: NodeService) -> None:
         return 200, render_openmetrics(node.metric_sections(),
                                        node="tpu-node-0")
     c.register("GET", "/_metrics", metrics_exposition)
-    c.register("GET", "/_prometheus/metrics", metrics_exposition)
 
     # -- watcher alerting tier (ISSUE 20): watch CRUD + stats + alerts -----
     def _watcher_service():
@@ -3161,7 +3171,7 @@ class HttpServer:
                         extra_headers["Retry-After"] = \
                             str(int(_math.ceil(retry or 1.0)))
                 fmt = params.get("format", [None])[0]
-                with tracing.span("rest.serialize"):
+                with tracing.span("rest.serialize", cpu=True):
                     if isinstance(payload, bytes):
                         data = payload       # pre-serialized JSON fast lane
                         ctype = "application/json; charset=UTF-8"

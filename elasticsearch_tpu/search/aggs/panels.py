@@ -726,7 +726,7 @@ def execute(rows: list[PanelRow], view: PanelView):
     kind = first.kind
     Q_pad = _q_bucket(Q)
     operands = view.operands(first)
-    plan = tracing.span("aggs.plan", shape=kind, rows=Q)
+    plan = tracing.span("aggs.plan", shape=kind, rows=Q, cpu=True)
     with plan:
         bounds = np.zeros((3, Q_pad), np.int64)
         bounds[0] = _I64.max                        # padded rows: lo > hi

@@ -52,7 +52,7 @@ logger = logging.getLogger("elasticsearch_tpu.serving.batcher")
 
 class _Entry:
     __slots__ = ("item", "event", "out", "err", "t_submit", "t_taken",
-                 "abandoned")
+                 "t_set", "abandoned")
 
     def __init__(self, item):
         self.item = item         # the caller's own; `run` gets it back
@@ -60,7 +60,7 @@ class _Entry:
         self.out = None          # its slot of `run`'s answer, or None
         self.err = None
         self.t_submit = tracing.now_ns()
-        self.t_taken = None      # ns: a batch took the entry off the queue
+        self.t_taken = self.t_set = None  # ns: taken off the queue; woken
         self.abandoned = False   # follower timed out; don't spend a row
 
 
@@ -179,6 +179,7 @@ class SearchBatcher:
                 self.last_error = f"{type(ex).__name__}: {ex}"
             for x in batch:
                 x.err = ex
+                x.t_set = tracing.now_ns()
                 x.event.set()
             return
         with self._lock:
@@ -188,6 +189,7 @@ class SearchBatcher:
                 self.occupancy.get(len(batch), 0) + 1
         for i, x in enumerate(batch):
             x.out = None if outs is None else outs[i]
+            x.t_set = tracing.now_ns()
             x.event.set()
 
     def _release(self, key: tuple) -> None:
@@ -200,6 +202,7 @@ class SearchBatcher:
             self.stranded += len(leftover)
         for x in leftover:   # no leader left: don't strand them silently
             x.out = None
+            x.t_set = tracing.now_ns()
             x.event.set()
         if leftover:
             self._log_anomaly(
@@ -210,10 +213,14 @@ class SearchBatcher:
 
     def _wait(self, e: _Entry) -> bool:
         """Follower wait with the deadline-aware timeout -> served? A
-        timeout is counted and logged instead of silent."""
+        served follower books `batcher.wake`, from the leader's `set()` to
+        its own thread running again. A timeout is counted and logged
+        instead of silent."""
         timeout = self.qos.follower_wait_s()
         with tracing.span("batcher.follow"):
             served = e.event.wait(timeout=timeout)
+            if served:
+                tracing.add_span("batcher.wake", e.t_set, tracing.now_ns())
         if not served:
             e.abandoned = True
             with self._lock:

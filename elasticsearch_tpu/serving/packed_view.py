@@ -38,7 +38,7 @@ import jax
 import jax.numpy as jnp
 
 from ..common import tracing
-from ..common.metrics import (device_fetch, note_h2d, record_packed_consts,
+from ..common.metrics import (device_fetch, note_h2d,
                               record_packed_dispatch)
 from ..index.segment import Segment, next_pow2
 from ..ops.bm25_sparse import (FOLD_IDS_BLOCK, FOLD_IDS_MAX, NO_ORDINAL,
@@ -490,7 +490,7 @@ class PackedIndexView:
         # everything the host does before the dispatch is one span: the slot
         # table and the filter descriptors. `dev` is what goes to the device:
         # host arrays as they are, uploaded by the program's own dispatch
-        prep = tracing.span("packed.build_slots")
+        prep = tracing.span("packed.build_slots", cpu=True)
         with prep:
             packed, S, R = self._build_slots(pf, queries, field, k1, b)
             k_pad = next_pow2(k, floor=8)
@@ -550,12 +550,12 @@ class PackedIndexView:
     def _constants(self, field: str, k1: float, b: float):
         """-> ((k1, b, avgdl, 0) as f32 scalars on the device, "reused" or
         "made"): made once for the view, whose doc_count and sum_dl never
-        change (a refresh builds a new view, and new constants with it)."""
+        change (a refresh builds a new view, and new constants with it).
+        The state is what the `packed.build_slots` span says, `consts=`."""
         key = (field, k1, b)
         state = "reused" if key in self._consts else "made"
         if state == "made":
             self._consts[key] = _device_scalars(k1, b, self.avgdl(field))
-        record_packed_consts(state)
         return self._consts[key], state
 
     def _build_slots(self, pf: PackedField, queries: list[PackedQuery],

@@ -364,6 +364,39 @@ class TestCoalesceAlone:
         assert st["run_errors_total"] == 0     # `lead` is not a batch
         assert not b._busy and not b._queues
 
+    @pytest.mark.parametrize("answered", [True, False],
+                             ids=["served", "timed-out"])
+    def test_a_served_follower_books_its_wake_up(self, monkeypatch,
+                                                 answered):
+        monkeypatch.setattr(tracing, "AGGREGATE", tracing.SpanAggregate())
+        b = batcher_alone(wait_s=5.0 if answered else 0.05)
+        got = {}
+
+        def lead():
+            got["threads"], got["outs"] = _followers(
+                b, self.KEY, ["f1"], served)
+            if not answered:            # until the follower gave up
+                deadline = time.time() + 5
+                while b.stats()["wait_timeouts_total"] == 0 \
+                        and time.time() < deadline:
+                    time.sleep(0.005)
+            return "led"
+
+        b.coalesce(self.KEY, "a", served, lead=lead)
+        got["threads"][0].join(5)
+        assert not got["threads"][0].is_alive()
+        rows = tracing.AGGREGATE.stats()
+        assert rows["batcher.follow"]["total"] == 1
+        if answered:
+            assert got["outs"] == {"f1": ({"served": "f1"}, True)}
+            wake = rows["batcher.wake"]
+            assert wake["total"] == 1
+            assert 0 <= wake["seconds_total"] \
+                <= rows["batcher.follow"]["seconds_total"]
+        else:
+            assert got["outs"] == {"f1": (None, False)}
+            assert "batcher.wake" not in rows
+
     def test_run_is_given_the_instant_the_batch_left_the_queue(self):
         b = batcher_alone()
         taken = []

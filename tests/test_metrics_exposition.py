@@ -9,6 +9,7 @@ to join `NodeService.metric_sections()` fails here, not in production.
 
 import json
 import re
+import urllib.error
 import urllib.request
 
 import pytest
@@ -216,16 +217,14 @@ def test_new_timer_joins_the_scrape_automatically(http):
     assert "custom.drift_guard" in timer_labels
 
 
-def test_aliases_and_content(http):
+def test_one_path_and_its_content(http):
     node, req = http
     code, a = req("GET", "/_metrics")
-    code2, b = req("GET", "/_prometheus/metrics")
-    assert code == code2 == 200
-    # same families on both paths (values may drift between scrapes)
-    assert {ln.split("{")[0] for ln in a.splitlines()
-            if ln and not ln.startswith("#")} \
-        == {ln.split("{")[0] for ln in b.splitlines()
-            if ln and not ln.startswith("#")}
+    assert code == 200
+    # the `/_prometheus/metrics` alias is gone (ROADMAP D9): one path
+    with pytest.raises(urllib.error.HTTPError) as gone:
+        req("GET", "/_prometheus/metrics")
+    assert gone.value.code in (400, 404, 405)
     # indexed docs + searches are visible in the scrape
     fams = parse_openmetrics(a)
     total = sum(v for _, v in fams["es_index_docs"]["samples"])

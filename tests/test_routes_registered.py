@@ -46,7 +46,7 @@ def test_route_count_floor_and_uniqueness(controller):
 
 
 def test_new_observability_routes_resolve(controller):
-    for path in ("/_metrics", "/_prometheus/metrics",
+    for path in ("/_metrics", "/_nodes/device_gaps",
                  "/_nodes/stats/history", "/_nodes/stats",
                  "/_cat/thread_pool", "/_cat/indices",
                  "/_cache/clear", "/someindex/_cache/clear",

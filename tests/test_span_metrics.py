@@ -60,16 +60,16 @@ def http(tmp_path_factory):
 
 def _scrape(req) -> dict:
     """`/_metrics` -> {family: {label value or "": number}} for the span,
-    gap, flight, transfer and packed-constants families."""
+    gap, flight and transfer families."""
     out: dict[str, dict] = {}
     for line in req("GET", "/_metrics").splitlines():
-        m = re.match(r'^(es_(?:span|device_gap|device_flight|transfer'
-                     r'|packed_consts)\w*)\{(.*)\} (\S+)$', line)
+        m = re.match(r'^(es_(?:span|device_gap|device_flight|transfer)\w*)'
+                     r'\{(.*)\} (\S+)$', line)
         if not m:
             continue
         labels = dict(p.split("=", 1) for p in m.group(2).split(","))
         key = (labels.get("span") or labels.get("during")
-               or labels.get("state") or '""').strip('"')
+               or '""').strip('"')
         out.setdefault(m.group(1), {})[key] = float(m.group(3))
     return out
 
@@ -81,8 +81,9 @@ def _msearch_body(n: int) -> str:
 
 # -- (a) the aggregate on /_metrics ----------------------------------------
 
-def test_metrics_carry_every_span_of_the_packed_path(http):
+def test_metrics_carry_every_span_of_the_packed_path(http, monkeypatch):
     node, req = http
+    monkeypatch.setattr(tracing, "CPU_SAMPLE", 1)   # every host span reads
     before = _scrape(req)
     for _ in range(N_SOLO):
         assert req("POST", "/sp/_search", MATCH)["hits"]["total"] == 120
@@ -136,6 +137,16 @@ def test_metrics_carry_every_span_of_the_packed_path(http):
     children = sum(delta(after, "es_span_seconds_total", s) for s in inside)
     assert 0 < children \
         <= delta(after, "es_span_seconds_total", "rest.request")
+    # the host-compute spans book their thread's CPU time beside their
+    # wall time; a wait, timed or booked from two timestamps (`add_span`),
+    # books none
+    host = {"rest.parse_body", "search.plan", "packed.build_slots",
+            "packed.respond", "rest.serialize"}
+    assert set(after["es_span_cpu_seconds_total"]) == host
+    assert set(after["es_span_cpu_wall_seconds_total"]) == host
+    assert delta(after, "es_span_cpu_seconds_total", "rest.parse_body") > 0
+    assert delta(after, "es_span_cpu_wall_seconds_total", "search.plan") \
+        == pytest.approx(delta(after, "es_span_seconds_total", "search.plan"))
     # a program in flight is no gap: both views are there and positive
     assert after["es_device_flight_seconds_total"][""] > \
         before["es_device_flight_seconds_total"][""]
@@ -170,10 +181,6 @@ def test_transfer_counters_reach_the_packed_lane(http):
     # the table is the one host array the program's dispatch uploads; the
     # BM25 scalars were made by the fixture's warming search and stay
     assert (prep["operands"], prep["consts"]) == (1, "reused")
-    consts = {state: after["es_packed_consts_total"][state]
-              - before["es_packed_consts_total"][state]
-              for state in ("reused", "made")}
-    assert consts == {"reused": 1, "made": 0}
     assert spans["packed.d2h"]["args"]["d2h_bytes"] == down
     # the leader's tree: packed_batch is the parent of its whole stay
     stay = spans["packed_batch"]["args"]["span_id"]
@@ -212,10 +219,10 @@ def _gaps() -> dict:
 def test_overlapping_flights_make_no_gap(ledger):
     one, other = tracing._ThreadState(), tracing._ThreadState()
     gaps = tracing.GAPS
-    gaps.takeoff(1_000, one)
-    gaps.takeoff(1_400, other)      # overlaps the first
-    gaps.land(1_700)                # the second is still in flight
-    gaps.land(2_000)
+    first = gaps.takeoff(1_000, one)
+    second = gaps.takeoff(1_400, other)     # overlaps the first
+    gaps.land(1_700, first)                 # the second is still in flight
+    gaps.land(2_000, second)
     assert _gaps() == {}
     assert gaps.flight_stats()["seconds_total"] * 1e9 \
         == pytest.approx(1_000)     # the union, not the sum (1300)
@@ -281,6 +288,151 @@ def test_a_span_that_began_before_the_gap_is_clipped_to_it(ledger):
         "packed.respond": 200, "unattributed": 800}
 
 
+def test_a_flight_behind_others_books_its_queue(ledger):
+    one, two, three = (tracing._ThreadState() for _ in range(3))
+    gaps = tracing.GAPS
+    a = gaps.takeoff(1_000, one)
+    b = gaps.takeoff(1_200, two)        # behind a
+    c = gaps.takeoff(1_300, three)      # behind a and b
+    gaps.land(1_700, a)                 # b's queue ends; c still waits for b
+    gaps.land(2_100, b)                 # c's queue ends
+    gaps.land(2_500, c)
+    d = gaps.takeoff(3_000, one)        # alone: the device was idle
+    gaps.land(3_200, d)
+    row = tracing.AGGREGATE.stats()["program.queue"]
+    assert row["total"] == 2
+    assert row["seconds_total"] * 1e9 == pytest.approx(500 + 800)
+    assert "cpu_seconds_total" not in row
+
+
+def test_a_queue_ends_at_the_flights_own_landing_at_the_latest(ledger):
+    # the host's view: threads race from their landing to the ledger's
+    # lock, so the one behind may be booked first; its wait is then all of
+    # its flight, never more
+    one, two = tracing._ThreadState(), tracing._ThreadState()
+    gaps = tracing.GAPS
+    a = gaps.takeoff(1_000, one)
+    b = gaps.takeoff(1_100, two)
+    gaps.land(1_600, b)
+    gaps.land(1_650, a)
+    row = tracing.AGGREGATE.stats()["program.queue"]
+    assert (row["total"], round(row["seconds_total"] * 1e9)) == (1, 500)
+
+
+def test_disjoint_flights_book_no_queue(ledger):
+    for t in (1_000, 2_000, 3_000):
+        with tracing.flight("ops:a"):
+            ledger.t = t + 500
+        ledger.t = t + 1_000
+    rows = tracing.AGGREGATE.stats()
+    assert rows["program"]["total"] == 3
+    assert "program.queue" not in rows
+
+
+S = 1_000_000_000
+
+
+def _leave_gaps(*gaps) -> None:
+    """Flights that leave exactly these (start, length) gaps, in order."""
+    st = tracing._ThreadState()
+    flight = tracing.GAPS.takeoff(gaps[0][0] - 100, st)
+    for start, length in gaps:
+        tracing.GAPS.land(start, flight)
+        flight = tracing.GAPS.takeoff(start + length, st)
+    tracing.GAPS.land(gaps[-1][0] + gaps[-1][1] + 100, flight)
+
+
+def test_the_longest_gaps_of_each_second_are_kept(ledger):
+    _leave_gaps(*[(5 * S + i * 10_000, length) for i, length
+            in enumerate([300, 100, 600, 200, 500, 400])], (6 * S, 50))
+    got = tracing.GAPS.gap_records()
+    assert [r["end_ns"] - r["start_ns"] for r in got] \
+        == [300, 600, 500, 400, 50]         # by start, newest last
+    for r in got:
+        assert r["during"] == {"no_request": r["end_ns"] - r["start_ns"]}
+    # 600 s on, the first second's records are gone (the idle stretch
+    # from the last landing, in second 6, is a gap too)
+    _leave_gaps(((5 + 600) * S, 70))
+    assert [r["start_ns"] // S for r in tracing.GAPS.gap_records()] \
+        == [6, 6, 605]
+
+
+def test_a_gap_record_keeps_four_charges_and_the_rest_as_other(ledger):
+    ledger.t = 990
+    with tracing.flight("ops:a"):
+        ledger.t = 1_000
+    st = tracing._thread_state()
+    st.request_start_ns = 1_000
+    for i, name in enumerate(["a", "b", "c", "d", "e", "f"]):
+        st.trail.append((name, 1_000 + 100 * i, 1_000 + 100 * i + 10 * (i + 1)))
+    ledger.t = 1_700
+    with tracing.flight("ops:a"):
+        ledger.t = 1_800
+    rec, = tracing.GAPS.gap_records()
+    assert (rec["start_ns"], rec["end_ns"]) == (1_000, 1_700)
+    assert rec["during"] == {"unattributed": 490, "f": 60, "e": 50, "d": 40,
+                             "other": 30 + 20 + 10}
+    assert sum(rec["during"].values()) == 700
+
+
+def test_cpu_time_is_below_wall_off_the_cpu_and_near_it_on(monkeypatch):
+    monkeypatch.setattr(tracing, "AGGREGATE", tracing.SpanAggregate())
+    monkeypatch.setattr(tracing, "CPU_SAMPLE", 1)
+    with tracing.span("sleeps", cpu=True):
+        time.sleep(0.05)
+    with tracing.span("spins", cpu=True):
+        t = time.perf_counter()
+        while time.perf_counter() - t < 0.05:
+            pass
+    with tracing.span("waits"):         # not a host-compute span
+        time.sleep(0.01)
+    rows = tracing.AGGREGATE.stats()
+    assert "cpu_seconds_total" not in rows["waits"]
+    sleeps, spins = rows["sleeps"], rows["spins"]
+    assert sleeps["cpu_wall_seconds_total"] == sleeps["seconds_total"]
+    assert sleeps["seconds_total"] >= 0.05
+    assert sleeps["cpu_seconds_total"] < 0.2 * sleeps["seconds_total"]
+    # near the wall: the core may be shared with the suite's other workers
+    assert 0.5 * spins["seconds_total"] < spins["cpu_seconds_total"] \
+        <= spins["seconds_total"] * 1.01
+
+
+def test_one_host_span_in_cpu_sample_reads_the_cpu_clock(monkeypatch):
+    import itertools
+    monkeypatch.setattr(tracing, "AGGREGATE", tracing.SpanAggregate())
+    monkeypatch.setattr(tracing, "_cpu_turn", itertools.count())
+    for _ in range(2 * tracing.CPU_SAMPLE):
+        with tracing.span("host", cpu=True):
+            time.sleep(0.002)
+    row = tracing.AGGREGATE.stats()["host"]
+    assert row["total"] == 2 * tracing.CPU_SAMPLE
+    # two of the spans read the clock: their wall, not all the spans'
+    share = row["cpu_wall_seconds_total"] / row["seconds_total"]
+    assert 0.5 / tracing.CPU_SAMPLE < share < 1.6 / tracing.CPU_SAMPLE
+    assert row["cpu_seconds_total"] < row["cpu_wall_seconds_total"]
+
+
+def test_the_dispatch_lock_wait_is_a_span_only_when_the_lock_was_held(
+        monkeypatch):
+    import threading
+    import types
+    from elasticsearch_tpu.parallel.mesh_exec import exec_guard
+    monkeypatch.setattr(tracing, "AGGREGATE", tracing.SpanAggregate())
+    pool = types.SimpleNamespace(lock=threading.Lock())
+    with exec_guard(pool):
+        pass
+    assert "exec.lock_wait" not in tracing.AGGREGATE.stats()
+    pool.lock.acquire()
+    holder = threading.Timer(0.05, pool.lock.release)
+    holder.start()
+    with exec_guard(pool):
+        pass
+    holder.join(5)
+    row = tracing.AGGREGATE.stats()["exec.lock_wait"]
+    assert row["total"] == 1
+    assert 0.03 <= row["seconds_total"] < 5
+
+
 # -- (c) the join: spans on the profiler's clock ------------------------------
 
 def test_profiler_trace_holds_the_spans_as_host_events(http, tmp_path):
@@ -311,6 +463,63 @@ def test_profiler_trace_holds_the_spans_as_host_events(http, tmp_path):
     assert r0 <= b0 < b1 <= r1
     (p0, p1), = events["es:program"]
     assert b1 <= p0 < p1 <= r1
+
+
+def test_the_gap_ledger_maps_onto_the_profilers_clock(tmp_path, monkeypatch):
+    """Every `es:program` event carries `t0_ns`, its start on the program's
+    clock: one offset maps a gap record onto the capture, where it lies
+    between the programs."""
+    import jax
+    import jax.numpy as jnp
+    from jax.profiler import ProfileData
+    monkeypatch.setattr(tracing, "GAPS", tracing.GapLedger())
+    double = jax.jit(lambda v: v * 2 + 1)
+    x = jnp.ones(64)
+    double(x).block_until_ready()
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        for _ in range(8):
+            with tracing.flight("ops:join"):
+                jax.block_until_ready(double(x))
+            time.sleep(0.005)
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(os.path.join(str(tmp_path), "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    programs = [(e.start_ns, e.start_ns + e.duration_ns,
+                 e.start_ns - dict(e.stats)["t0_ns"])
+                for plane in ProfileData.from_file(path).planes
+                if plane.name.startswith("/host:")
+                for line in plane.lines for e in line.events
+                if e.name == "es:program"]
+    assert len(programs) == 8
+    offsets = [off for _, _, off in programs]
+    spread = max(offsets) - min(offsets)
+    assert spread <= 200_000
+    offset = min(offsets)
+    gaps = tracing.GAPS.gap_records()
+    assert 4 <= len(gaps) <= 7          # 7 gaps, at most 4 kept a second
+    for g in gaps:
+        g0, g1 = g["start_ns"] + offset, g["end_ns"] + offset
+        assert g1 - g0 >= 4_000_000
+        for p0, p1, _ in programs:   # no overlap beyond the anchor's error
+            assert min(g1, p1) - max(g0, p0) <= spread + 20_000
+
+
+def test_device_gaps_endpoint_lists_the_kept_gaps(http):
+    node, req = http
+    req("POST", "/sp/_search", MATCH)
+    time.sleep(0.01)
+    req("POST", "/sp/_search", MATCH)
+    body = req("GET", "/_nodes/device_gaps")["nodes"]["tpu-node-0"]
+    assert body["clock"] == "monotonic_ns"
+    starts = [g["start_ns"] for g in body["gaps"]]
+    assert starts and starts == sorted(starts)       # newest last
+    for g in body["gaps"]:
+        assert sum(g["during"].values()) == g["end_ns"] - g["start_ns"]
 
 
 # -- (d) the named scopes inside the packed program ---------------------------

@@ -349,6 +349,24 @@ def packed_batches_snapshot() -> dict:
                 for program, n in _PACKED_BATCHES.items()}
 
 
+# the rank streams a filtered packed batch hands its program, one a column
+# (PackedIndexView._filter_streams): es_packed_filter_streams_total{state=}.
+# "made" once a (view, text field, column), then "reused" by every batch that
+# names the pair: a warm window reads 100 % reused.
+_PACKED_FILTER_STREAMS = {"made": 0, "reused": 0}
+
+
+def record_filter_stream(state: str) -> None:
+    with _DEVICE_LOCK:
+        _PACKED_FILTER_STREAMS[state] += 1
+
+
+def packed_filter_streams_snapshot() -> dict:
+    with _DEVICE_LOCK:
+        return {state: {"total": n}
+                for state, n in _PACKED_FILTER_STREAMS.items()}
+
+
 # hits the packed lane rendered, by how (serving/executor.respond):
 # es_packed_render_hits_total{form=}. "vector" took the raw render's numpy
 # passes, "patched" its scalar `%.9g` (an exponent form, inf, nan), "dict"

@@ -3095,6 +3095,7 @@ class NodeService:
         from .common import device_stats, monitor
         from .common.metrics import (device_events_snapshot,
                                      packed_batches_snapshot,
+                                     packed_filter_streams_snapshot,
                                      packed_gather_snapshot,
                                      packed_render_snapshot,
                                      transfer_snapshot)
@@ -3308,6 +3309,10 @@ class NodeService:
             # es_packed_batches_total{program=}: packed batches by the
             # program that answered them (plain | filtered)
             "packed_batches": ("program", packed_batches_snapshot()),
+            # es_packed_filter_streams_total{state=}: the rank streams that
+            # filtered packed batches used, by whether made for it or reused
+            "packed_filter_streams": ("state",
+                                      packed_filter_streams_snapshot()),
             "tasks": (None, self.tasks.stats()),
             # span tracer: started/retained/sampled-out trace counters,
             # ring-eviction + span-cap drop counters, live gauges

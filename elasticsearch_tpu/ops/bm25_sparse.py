@@ -61,14 +61,6 @@ PACKED_PAD_DOC = int(jnp.iinfo(jnp.int32).max)
 FOLD_IDS_BLOCK = 32
 FOLD_IDS_MAX = 8192
 
-# Candidate rows (a block of candidate columns of every query) that the
-# filtered program's `packed.filters` gathers at a time. On the TPU the
-# gather's result comes out as [rows, NC] with the NC int32 columns padded to
-# a tile's 128 lanes, 512 B a row: 2 GB a block. All 256 queries x 256 slots
-# at once are 16 GB, and the chip's compiler refused the program (PERF.md §6,
-# PR 33).
-FILTER_ROWS = 1 << 22
-
 # A term target that no row of a filter column holds (rows hold an ordinal
 # >= 0, or -1 where the document has no value): an unused target, and a value
 # that no document has.
@@ -174,26 +166,36 @@ def slot_budget(term_lens) -> int:
 def bm25_serve_packed_filtered(packed_q: jax.Array, doc_ids: jax.Array,
                                tf: jax.Array, dl: jax.Array,
                                k1, b, avgdl, const,
-                               fcols: jax.Array,
+                               ranks: tuple,
                                fr_col: jax.Array, fr_lo: jax.Array,
                                fr_hi: jax.Array, fr_neg: jax.Array,
                                ft_col: jax.Array, ft_targets: jax.Array,
                                ft_neg: jax.Array, *,
                                S: int, CHUNK: int, R: int, k: int,
                                FR: int, FT: int, TV: int) -> jax.Array:
-    """bm25_serve_packed + per-query COLUMNAR FILTERS evaluated on device at
-    the candidate positions (the filter analog of Lucene's filtered query
-    inside QueryPhase — BASELINE config #2's bool{match + filter} shape).
+    """bm25_serve_packed + per-query COLUMNAR FILTERS evaluated on device on
+    every posting the slots copy (the filter analog of Lucene's filtered
+    query inside QueryPhase — BASELINE config #2's bool{match + filter}).
 
-    fcols i32[NC, Npad]: the filter columns this batch touches, packed over
-        the global doc space. Whatever the field's type, a row holds its
-        value's ORDINAL among the view's sorted distinct values (-1 = no
-        value, and every padding row): order and equality are all a filter
-        needs of a column, and one int32 is one gather a candidate. The
-        64-bit values stay on the host (`PackedFilterColumn.distinct`),
-        where `_filter_descriptors` turns every bound and target into an
-        ordinal exactly; the program holds no float64.
-    Range slots (AND-ed): fr_col i32[Q, FR] (index into fcols; -1 = slot
+    ranks: NC streams i32[P], one a filter column this batch touches, each
+        aligned with `doc_ids` (`packed_filter_stream`): a posting's entry is
+        its document's ORDINAL in the column, the rank of the document's
+        value among the view's sorted distinct values (-1 = no value, and
+        every padding posting). Whatever the field's type, order and
+        equality are all a filter needs of a column. The 64-bit values stay
+        on the host (`PackedFilterColumn.distinct`), where
+        `_filter_descriptors` turns every bound and target into an ordinal
+        exactly; the program holds no float64.
+    `packed.gather` copies the streams' slots with the postings' own (one
+        kernel, 3 + NC streams), and `packed.filters` compares the [Q, S,
+        CHUNK] rank blocks with each query's bounds and targets before the
+        score: a posting that fails scores 0 and counts 0, and `min_match`
+        (at least 1) drops its document. A document holds one rank in all
+        its postings, so a posting's compare decides what the document's
+        would. No column is read at a candidate position: on the TPU that
+        gather is serial, 7.5 ns a candidate row, and it was four fifths of
+        the program (PERF.md §5).
+    Range slots (AND-ed): fr_col i32[Q, FR] (index into ranks; -1 = slot
         unused, -2 = active but the field has no column: matches nothing),
         fr_lo/fr_hi i32[Q, FR] an INCLUSIVE ordinal interval (an open end,
         a bound between two values and a missing bound are resolved on the
@@ -201,16 +203,13 @@ def bm25_serve_packed_filtered(packed_q: jax.Array, doc_ids: jax.Array,
     Term slots (AND-ed; OR within a slot's TV targets): ft_col i32[Q, FT],
         ft_targets i32[Q, FT, TV] ordinals (NO_ORDINAL = unused target, or
         a value no document holds), ft_neg i32[Q, FT].
-    A row without a value fails every range and term and passes their
-        negations.
-
-    Filters gate `keep` exactly like `min_match`, so total_hits and top-k
-    honor them in the same single program — still 1 upload + 1 download.
+    A document without a value fails every range and term and passes
+        their negations. Still 1 upload + 1 download a batch.
     """
     return _serve_packed_impl(
         packed_q, doc_ids, tf, dl, k1, b, avgdl, const,
-        S=S, CHUNK=CHUNK, R=R, k=k, gather=packed_gather_form(),
-        filters=(fcols, fr_col, fr_lo, fr_hi, fr_neg,
+        S=S, CHUNK=CHUNK, R=R, k=k, gather=packed_gather_form(), ranks=ranks,
+        filters=(fr_col, fr_lo, fr_hi, fr_neg,
                  ft_col, ft_targets, ft_neg, FR, FT, TV))
 
 
@@ -255,7 +254,8 @@ def bm25_serve_packed(packed_q: jax.Array, doc_ids: jax.Array, tf: jax.Array,
         them, and a folded posting lies INSIDE its slot's `valid` lanes, so
         a sentinel that a later view can reach would come back as a hit.
         No view reaches this one; it sorts after every real id, and the
-        filter columns' `take(..., mode="clip")` reads a padding row for it.
+        build of a filter's rank stream (`packed_filter_stream`, a `take`
+        with mode "clip") reads a padding row of the column for it.
     R: max distinct query terms — the run-length bound of the windowed
         segment-sum. A doc appears at most once per term (chunks of one term
         are disjoint doc ranges), so runs are <= R regardless of S.
@@ -288,7 +288,7 @@ def packed_gather_form() -> str:
 
 
 def _serve_packed_impl(packed_q, doc_ids, tf, dl, k1, b, avgdl, const, *,
-                       S, CHUNK, R, k, filters, gather):
+                       S, CHUNK, R, k, filters, gather, ranks=()):
     # each phase is a `jax.named_scope`: metadata only (same program, same
     # outputs), so a profile's operations group under stable names
     Q = packed_q.shape[0]
@@ -302,15 +302,21 @@ def _serve_packed_impl(packed_q, doc_ids, tf, dl, k1, b, avgdl, const, *,
     with jax.named_scope("packed.gather"):
         # a copy either way: the same [Q, S, CHUNK] blocks, bit for bit
         if gather == "blocked":
-            d, t, l = (x.reshape(Q, S, CHUNK) for x in gather_slots(
-                starts.reshape(-1), (doc_ids, tf, dl), chunk=CHUNK,
+            slotted = (x.reshape(Q, S, CHUNK) for x in gather_slots(
+                starts.reshape(-1), (doc_ids, tf, dl, *ranks), chunk=CHUNK,
                 interpret=jax.default_backend() != "tpu"))
         else:
-            d, t, l = jax.vmap(jax.vmap(lambda s: tuple(
+            slotted = jax.vmap(jax.vmap(lambda s: tuple(
                 jax.lax.dynamic_slice(x, (s,), (CHUNK,))
-                for x in (doc_ids, tf, dl))))(starts)
+                for x in (doc_ids, tf, dl, *ranks))))(starts)
+        d, t, l, *ranks = slotted      # the filter's rank blocks, if any
         valid = jnp.arange(CHUNK, dtype=jnp.int32) < lens[:, :, None]
         d = jnp.where(valid, d, PAD)
+
+    if filters is not None:
+        with jax.named_scope("packed.filters"):
+            # before the score: a posting that fails counts 0 and scores 0
+            valid = valid & _filter_mask(ranks, valid.shape, *filters)
 
     W = S * CHUNK
     with jax.named_scope("packed.score"):
@@ -342,54 +348,6 @@ def _serve_packed_impl(packed_q, doc_ids, tf, dl, k1, b, avgdl, const, *,
             [d[:, :-1] != d[:, 1:], jnp.ones((Q, 1), bool)], axis=1) & is_real
         keep = ends & (count >= min_match[:, None].astype(jnp.float32))
 
-    if filters is not None:
-        (fcols, fr_col, fr_lo, fr_hi, fr_neg,
-         ft_col, ft_targets, ft_neg, FR, FT, TV) = filters
-
-        def eval_one(dq, fr_c, fr_l, fr_h, fr_n, ft_c, ft_t, ft_n):
-            ok = jnp.ones(dq.shape, bool)
-            # gather every column at the candidate slots FIRST, then pick
-            # the slot's column: [NC, W] per query. Picking the column
-            # first materializes a [Q, Npad] copy per filter slot under
-            # the vmap — 16 GB at 1M docs x 256 queries (chip run, PR 21).
-            # ONE int32 gather: a row without a value reads -1, below every
-            # interval's low end and equal to no target.
-            vals = fcols.take(dq, axis=1, mode="clip")
-            for fi in range(FR):
-                v = jnp.take(vals, jnp.maximum(fr_c[fi], 0), axis=0)
-                m = (v >= fr_l[fi]) & (v <= fr_h[fi])
-                m = jnp.where(fr_c[fi] == -2, False, m)  # absent column
-                m = jnp.where(fr_n[fi] > 0, ~m, m)
-                ok = ok & jnp.where(fr_c[fi] != -1, m, True)
-            for fi in range(FT):
-                v = jnp.take(vals, jnp.maximum(ft_c[fi], 0), axis=0)
-                m = (v[None, :] == ft_t[fi][:, None]).any(axis=0)
-                m = jnp.where(ft_c[fi] == -2, False, m)
-                m = jnp.where(ft_n[fi] > 0, ~m, m)
-                ok = ok & jnp.where(ft_c[fi] != -1, m, True)
-            return ok
-
-        with jax.named_scope("packed.filters"):
-            # A block of candidate columns at a time, and only the blocks
-            # that hold a candidate: the sort left every query's unused
-            # lanes (PAD) last, so past the longest query's last candidate
-            # there is nothing to filter, and a batch padded to the next
-            # power of two of slots does not pay for the padding. A block
-            # is FILTER_ROWS candidate rows.
-            cols = max(1, min(W, FILTER_ROWS // Q))
-            longest = jnp.max(jnp.sum(is_real, axis=1, dtype=jnp.int32))
-
-            def filter_block(i, ok):
-                at = i * jnp.int32(cols)
-                got = jax.vmap(eval_one)(
-                    jax.lax.dynamic_slice_in_dim(d, at, cols, axis=1),
-                    fr_col, fr_lo, fr_hi, fr_neg, ft_col, ft_targets, ft_neg)
-                return jax.lax.dynamic_update_slice_in_dim(ok, got, at, axis=1)
-
-            n_blocks = (longest + jnp.int32(cols - 1)) // jnp.int32(cols)
-            keep = keep & jax.lax.fori_loop(
-                jnp.int32(0), n_blocks, filter_block, jnp.zeros((Q, W), bool))
-
     with jax.named_scope("packed.topk"):
         masked = jnp.where(keep, total + const, -jnp.inf)
         top, pos = jax.lax.top_k(masked, min(k, W))
@@ -408,6 +366,53 @@ def _serve_packed_impl(packed_q, doc_ids, tf, dl, k1, b, avgdl, const, *,
         return jnp.concatenate(
             [jax.lax.bitcast_convert_type(top, jnp.int32), top_docs,
              total_hits[:, None]], axis=1)
+
+
+def _filter_mask(ranks, shape, fr_col, fr_lo, fr_hi, fr_neg, ft_col,
+                 ft_targets, ft_neg, FR, FT, TV):
+    """bool `shape` = [Q, S, CHUNK]: whether each posting's document passes
+    every filter slot of its query (`bm25_serve_packed_filtered` has the
+    descriptors' meaning). `ranks` are the NC rank blocks of that shape; a
+    slot's block is picked by a select over the static NC axis, elementwise,
+    so nothing is gathered. A posting without a value reads -1: below every
+    interval's low end (>= 0) and equal to no target (>= 0 or NO_ORDINAL)."""
+
+    def per_query(x):               # [Q] -> beside every posting of its query
+        return x[:, None, None]
+
+    def column(code):               # the rank block of the slot's column
+        v = jnp.full(shape, -1, jnp.int32)
+        for c, block in enumerate(ranks):
+            v = jnp.where(per_query(code == c), block, v)
+        return v
+
+    def slot(m, code, neg):
+        m = jnp.where(per_query(code == -2), False, m)      # absent column
+        m = jnp.where(per_query(neg > 0), ~m, m)
+        return jnp.where(per_query(code != -1), m, True)    # unused slot
+
+    ok = jnp.ones(shape, bool)
+    for fi in range(FR):
+        v = column(fr_col[:, fi])
+        m = (v >= per_query(fr_lo[:, fi])) & (v <= per_query(fr_hi[:, fi]))
+        ok = ok & slot(m, fr_col[:, fi], fr_neg[:, fi])
+    for fi in range(FT):
+        v = column(ft_col[:, fi])
+        m = v == per_query(ft_targets[:, fi, 0])
+        for vi in range(1, TV):
+            m = m | (v == per_query(ft_targets[:, fi, vi]))
+        ok = ok & slot(m, ft_col[:, fi], ft_neg[:, fi])
+    return ok
+
+
+@jax.jit
+def packed_filter_stream(vals: jax.Array, doc_ids: jax.Array) -> jax.Array:
+    """A filter's rank stream: i32[P], each posting's document's rank in the
+    column `vals` i32[Npad] (-1 = no value, and every padding row). One
+    gather over the postings, once a (view, text field, column); the
+    filtered program then reads the ranks with the postings' own copies.
+    PACKED_PAD_DOC clips to the column's last row, a padding row: -1."""
+    return vals.take(doc_ids, mode="clip")
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -500,5 +505,7 @@ bm25_topk_sparse_masked = _instrument(
 bm25_serve_packed = _instrument("ops:bm25_serve_packed", bm25_serve_packed)
 bm25_serve_packed_filtered = _instrument(
     "ops:bm25_serve_packed_filtered", bm25_serve_packed_filtered)
+packed_filter_stream = _instrument("ops:packed_filter_stream",
+                                   packed_filter_stream)
 packed_fold_live = _instrument("ops:packed_fold_live", packed_fold_live)
 packed_fold_ids = _instrument("ops:packed_fold_ids", packed_fold_ids)

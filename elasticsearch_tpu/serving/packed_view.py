@@ -38,13 +38,13 @@ import jax
 import jax.numpy as jnp
 
 from ..common import tracing
-from ..common.metrics import (device_fetch, note_h2d,
+from ..common.metrics import (device_fetch, note_h2d, record_filter_stream,
                               record_packed_dispatch)
 from ..index.segment import Segment, next_pow2
-from ..ops.bm25_sparse import (FOLD_IDS_BLOCK, FOLD_IDS_MAX, NO_ORDINAL,
-                               PACKED_PAD_DOC, bm25_serve_packed,
-                               bm25_serve_packed_filtered, packed_fold_ids,
-                               packed_fold_live, packed_gather_form)
+from ..ops.bm25_sparse import (
+    FOLD_IDS_BLOCK, FOLD_IDS_MAX, NO_ORDINAL, PACKED_PAD_DOC, bm25_serve_packed,
+    bm25_serve_packed_filtered, packed_filter_stream, packed_fold_ids,
+    packed_fold_live, packed_gather_form)
 
 # Fixed postings chunk: compile-cache keys depend on (Q, S) pow2 buckets only,
 # never on the corpus' df distribution.
@@ -179,7 +179,7 @@ class PackedIndexView:
         self._fields: dict[str, PackedField | None] = {}
         self._refused: set[str] = set()   # breaker-refused (≠ absent) fields
         self._filter_cols: dict[str, PackedFilterColumn | None] = {}
-        self._filter_stacks: dict[tuple, jax.Array] = {}
+        self._rank_streams: dict[tuple, jax.Array] = {}   # (field, column)
         self._consts: dict[tuple, tuple] = {}   # (field, k1, b) -> _constants
         self.device_calls = 0           # serving counters (observability)
         self.memory_bytes = 0
@@ -495,7 +495,7 @@ class PackedIndexView:
             packed, S, R = self._build_slots(pf, queries, field, k1, b)
             k_pad = next_pow2(k, floor=8)
             dev = [packed]
-            stack, columns = None, 0
+            ranks, columns = None, 0
             if any(q.filters for q in queries):
                 described = tracing.span(
                     "packed.filter_descriptors",
@@ -504,11 +504,11 @@ class PackedIndexView:
                     fields, *descriptors = \
                         self._filter_descriptors(queries, packed.shape[0])
                     dev += descriptors
-                    stack = self._filter_stack(fields)
+                    ranks = self._filter_streams(pf, field, fields)
                     described.attrs["columns"] = columns = len(fields)
             scalars, state = self._constants(field, k1, b)
             prep.attrs.update(
-                consts=state, operands=len(dev),
+                consts=state, operands=len(dev), streams=columns,
                 h2d_bytes=sum(a.nbytes for a in dev)
                 + (4 * len(scalars) if state == "made" else 0))
             note_h2d(prep.attrs["h2d_bytes"])
@@ -516,14 +516,14 @@ class PackedIndexView:
         # its `program` span and /_metrics: a chip run that fell back to
         # "sliced" shows there
         form = packed_gather_form()
-        program = "plain" if stack is None else "filtered"
+        program = "plain" if ranks is None else "filtered"
         with self._folded_ids(pf) as doc_ids, \
                 tracing.program_attrs(gather=form, program=program,
                                       columns=columns):
-            if stack is not None:
+            if ranks is not None:
                 out = bm25_serve_packed_filtered(
                     dev[0], doc_ids, pf.tf, pf.dl, *scalars,
-                    stack, *dev[1:], S=S, CHUNK=CHUNK, R=R, k=k_pad,
+                    ranks, *dev[1:], S=S, CHUNK=CHUNK, R=R, k=k_pad,
                     FR=F_RANGE, FT=F_TERM, TV=F_TERM_VALS)
             else:
                 out = bm25_serve_packed(
@@ -705,15 +705,15 @@ class PackedIndexView:
         self.memory_bytes += self.n_pad_total * 4
         return PackedFilterColumn(kind, vals, distinct)
 
-    def _filter_stack(self, fields: tuple) -> jax.Array:
-        st = self._filter_stacks.get(fields)
-        if st is None:
-            if fields:
-                st = jnp.stack([self._filter_cols[f].vals for f in fields])
-            else:
-                st = jnp.full((1, self.n_pad_total), -1, jnp.int32)
-            self._filter_stacks[fields] = st
-        return st
+    def _filter_streams(self, pf: PackedField, field: str, fields: tuple):
+        """-> the batch's rank streams, one a column of `fields` in order,
+        each made once a view (`_filter_stream`) and counted at every use."""
+        for name in fields:
+            state = "reused" if (field, name) in self._rank_streams else "made"
+            if state == "made":
+                self._filter_stream(pf, field, name)
+            record_filter_stream(state)
+        return tuple(self._rank_streams[field, name] for name in fields)
 
     def _filter_descriptors(self, queries: list[PackedQuery], Q_pad: int):
         """-> (fields tuple, fr_col, fr_lo, fr_hi, fr_neg, ft_col,
@@ -819,7 +819,7 @@ class PackedIndexView:
                 packed[:, 3 * s] = 1
                 bm25_serve_packed(packed, *common,
                                   S=s, CHUNK=CHUNK, R=4, k=k)
-            no_column = jnp.full((1, self.n_pad_total), -1, jnp.int32)
+            no_column = (jnp.full(doc_ids.shape, -1, jnp.int32),)
             for (q, s, k) in filtered_shapes:
                 packed = np.zeros((q, 3 * s + 1), np.int32)
                 packed[:, 3 * s] = 1
@@ -834,6 +834,30 @@ class PackedIndexView:
                     np.zeros((q, F_TERM), np.int32),
                     S=s, CHUNK=CHUNK, R=4, k=k,
                     FR=F_RANGE, FT=F_TERM, TV=F_TERM_VALS)
+
+    def _filter_stream(self, pf: PackedField, field: str, name: str) -> None:
+        """Make the rank stream of column `name` over text field `field`'s
+        postings: i32[P_pad], each posting's document's rank in the column
+        (`packed_filter_stream`), charged to the request breaker as the
+        view's own (4 B a posting). Built from this view's column and
+        postings, so a refresh, which makes a new view, never reads a stale
+        one; a later fold leaves it as it is, since a dead posting's doc id
+        is PACKED_PAD_DOC and the program masks it before its rank is read.
+        Raises FilterColumnRefused when the breaker refuses the bytes: the
+        batch is then served by the per-segment lane."""
+        nbytes = 4 * int(pf.doc_ids.size)
+        if self.breaker is not None:
+            from ..common.breaker import CircuitBreakingException
+            try:
+                self.breaker.add_estimate(nbytes)
+            except CircuitBreakingException as e:
+                raise FilterColumnRefused(name) from e
+        with tracing.span("packed.filter_stream", field=field, column=name,
+                          postings=pf.total_p, bytes=nbytes), \
+                self._folded_ids(pf) as doc_ids:
+            self._rank_streams[field, name] = packed_filter_stream(
+                self._filter_cols[name].vals, doc_ids)
+        self.memory_bytes += nbytes
 
 
 def _utf8_rows(ids: np.ndarray) -> np.ndarray:

@@ -15,6 +15,7 @@ import pytest
 from elasticsearch_tpu.common import tracing
 from elasticsearch_tpu.common.metrics import render_openmetrics
 from elasticsearch_tpu.node import NodeService
+from elasticsearch_tpu.ops.bm25_sparse import PACKED_PAD_DOC as K_PAD
 from elasticsearch_tpu.rest.http_server import _parse_bulk
 
 BENCH = os.path.join(os.path.dirname(os.path.dirname(
@@ -184,6 +185,145 @@ class TestPackedFilterEdges:
                       "filter": [{"term": {"tag": "a"}}]}}
         p, g = _both_lanes(node, q)
         assert _check_parity(p, g) == {"0"}
+
+
+# -- the rank streams' life: made once a view, read with the postings -------
+# A filtered batch hands its program one stream a column, the rank of each
+# posting's document (`PackedIndexView._filter_stream`). They are the view's:
+# a refresh makes a new view and new streams, a delete folds the postings
+# under them, and the request breaker is charged for them.
+
+STREAM_BODIES = [
+    {"bool": {"must": [{"match": {"body": "quick"}}],
+              "filter": [{"range": {"price": {"lte": 20}}}]}},
+    {"bool": {"must": [{"match": {"body": "quick"}}],
+              "filter": [{"term": {"tag": "a"}}],
+              "must_not": [{"range": {"price": {"gt": 30}}}]}},
+    {"bool": {"must": [{"match": {"body": "quick"}}],
+              "must_not": [{"terms": {"tag": ["b", "c"]}}]}},
+]
+
+
+def _stream_counts(node) -> dict:
+    """`/_metrics` -> {state: streams} of es_packed_filter_streams_total."""
+    text = render_openmetrics(node.metric_sections(), node="n")
+    return {m.group(1): int(float(m.group(2))) for m in re.finditer(
+        r'^es_packed_filter_streams_total\{[^}]*state="(\w+)"[^}]*\} (\S+)$',
+        text, re.M)}
+
+
+def _msearch_px(node, queries):
+    """The packed lane's answers to one `_msearch` of `queries`, each held to
+    the general lane's answer to the same query (`_check_parity`); -> the
+    ids of each."""
+    svc = node.indices["px"]
+    before = svc.search_stats.get("packed", 0)
+    out = node.msearch([({"index": "px"}, {"query": q, "size": 10})
+                        for q in queries])["responses"]
+    assert svc.search_stats.get("packed", 0) == before + len(queries)
+    return [_check_parity(p, node.search("px", {
+        "query": q, "size": 10, "track_scores": True}))
+        for p, q in zip(out, queries)]
+
+
+def _delta(a, b):
+    return {s: b.get(s, 0) - a.get(s, 0) for s in ("made", "reused")}
+
+
+class TestPackedFilterStreams:
+    def test_made_once_a_field_and_column_then_reused(self, node):
+        c0 = _stream_counts(node)
+        with tracing.Tracer().request("test") as trace:
+            _msearch_px(node, STREAM_BODIES)
+        c1 = _stream_counts(node)
+        # (body, price) and (body, tag): made for the batch that first names
+        # each, and every later batch reuses them
+        assert _delta(c0, c1) == {"made": 2, "reused": 0}
+        view = node.indices["px"].packed_view()
+        assert sorted(view._rank_streams) == [("body", "price"),
+                                              ("body", "tag")]
+        pf = view.field("body")
+        made = {s.attrs["column"]: s.attrs for s in trace.spans
+                if s.name == "packed.filter_stream"}
+        assert made.keys() == {"price", "tag"}
+        assert all(a["field"] == "body" and a["postings"] == pf.total_p
+                   and a["bytes"] == 4 * pf.doc_ids.size
+                   for a in made.values())
+        prep = [s.attrs for s in trace.spans if s.name == "packed.build_slots"]
+        assert [a["streams"] for a in prep] == [2]
+        _msearch_px(node, STREAM_BODIES)
+        _msearch_px(node, STREAM_BODIES[2:])
+        node.search("px", {"query": {"match": {"body": "quick"}}})
+        assert _delta(c1, _stream_counts(node)) == {"made": 0, "reused": 3}
+
+    def test_each_stream_is_its_column_at_every_posting(self, node):
+        _msearch_px(node, STREAM_BODIES)
+        view = node.indices["px"].packed_view()
+        ids = np.asarray(view.field("body").doc_ids)
+        assert (ids == K_PAD).sum() > 0         # the padding reads -1
+        for (field, name), stream in view._rank_streams.items():
+            col = np.asarray(view.filter_column(name).vals)
+            held = col[np.minimum(ids, len(col) - 1)]
+            np.testing.assert_array_equal(
+                np.asarray(stream), np.where(ids == K_PAD, -1, held))
+
+    def test_a_refresh_that_reranks_a_column_makes_fresh_streams(self, node):
+        """A value below every old one moves every old rank up by one: a
+        stream of the old view read by the new one would pass and fail the
+        wrong documents."""
+        assert _msearch_px(node, STREAM_BODIES)[0] == {"0", "1"}
+        old = node.indices["px"].packed_view()
+        node.index_doc("px", "8", {"body": "quick newt", "tag": "A",
+                                   "price": 5, "rating": 0.25})
+        node.refresh("px")
+        view = node.indices["px"].packed_view()
+        assert view is not old and view.extended_from_base
+        assert view.filter_column("price").distinct[0] == 5
+        assert view._rank_streams == {}
+        c0 = _stream_counts(node)
+        assert _msearch_px(node, STREAM_BODIES) == [
+            {"0", "1", "8"}, {"0", "2"}, {"0", "2", "6", "8"}]
+        assert _delta(c0, _stream_counts(node)) == {"made": 2, "reused": 0}
+
+    def test_a_delete_after_the_stream_was_made_is_excluded(self, node):
+        before = _msearch_px(node, STREAM_BODIES)
+        view = node.indices["px"].packed_view()
+        streams = dict(view._rank_streams)
+        node.delete_doc("px", "0")
+        node.refresh("px")
+        assert node.indices["px"].packed_view() is view     # folded in place
+        c0 = _stream_counts(node)
+        after = _msearch_px(node, STREAM_BODIES)
+        assert after == [ids - {"0"} for ids in before] != before
+        assert _delta(c0, _stream_counts(node)) == {"made": 0, "reused": 2}
+        assert all(view._rank_streams[k] is s for k, s in streams.items())
+
+    def test_streams_are_charged_and_a_refused_one_takes_the_segments(
+            self, node):
+        q = STREAM_BODIES[1]
+        svc = node.indices["px"]
+        node.search("px", {"query": {"match": {"body": "quick"}}})
+        view = svc.packed_view()
+        stream_bytes = 4 * view.field("body").doc_ids.size
+        brk = node.breakers.breaker("request")
+        limit = brk.limit
+        # room for the two columns (4 B a document), not for a stream
+        brk.limit = brk.used + 2 * 4 * view.n_pad_total + stream_bytes // 2
+        try:
+            before = svc.search_stats.get("packed", 0)
+            refused = node.search("px", {"query": q, "size": 10})
+            assert svc.search_stats.get("packed", 0) == before
+        finally:
+            brk.limit = limit
+        assert view._rank_streams == {}
+        used, held = brk.used, view.memory_bytes
+        packed = node.search("px", {"query": q, "size": 10})
+        assert svc.search_stats.get("packed", 0) == before + 1
+        assert brk.used - used == view.memory_bytes - held == 2 * stream_bytes
+        general = node.search("px", {"query": q, "size": 10,
+                                     "track_scores": True})
+        assert _check_parity(packed, general) == \
+            _check_parity(refused, general) == {"0", "2"}
 
 
 # -- BASELINE config #2 as the benchmark states it ---------------------------
@@ -574,45 +714,94 @@ def _equations(jaxpr, inside=()):
                         sub, inside + (eqn.primitive.name,))
 
 
-@pytest.mark.parametrize("S", [128, 256])
-def test_the_filtered_program_holds_no_float64_and_gathers_once_a_block(S):
-    """The jaxpr at the cell's two shapes (Q 256, S 128 | 256, k 1024, two
-    columns): int32 filter operands, no float64 value anywhere, and in the
-    loop over blocks of candidate rows ONE gather that reads the columns
-    (the float64 form was two on the TPU, a high and a low half)."""
+def _traced_on_the_chip(monkeypatch, S, NC):
+    """(equations, the program's arguments) of `bm25_serve_packed` (NC None)
+    or `bm25_serve_packed_filtered` on NC rank streams, traced as the chip
+    runs it (the Pallas slot gather, not its CPU stand-in) at a shape of
+    the two top-1000 cells: Q 256, S slots, k 1024, 2^25 postings."""
     import jax
     import jax.numpy as jnp
     from elasticsearch_tpu.ops import bm25_sparse as K
     from elasticsearch_tpu.serving.packed_view import (
         CHUNK, F_RANGE, F_TERM, F_TERM_VALS)
-    Q, P, N, NC = 256, 1 << 25, 262_144, 2
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    Q, P = 256, 1 << 25
     sd = jax.ShapeDtypeStruct
-    traced = K.bm25_serve_packed_filtered.jit.trace(
-        sd((Q, 3 * S + 1), jnp.int32), sd((P,), jnp.int32),
-        sd((P,), jnp.float32), sd((P,), jnp.float32),
-        *[sd((), jnp.float32)] * 4, sd((NC, N), jnp.int32),
-        *[sd((Q, F_RANGE), jnp.int32)] * 4, sd((Q, F_TERM), jnp.int32),
-        sd((Q, F_TERM, F_TERM_VALS), jnp.int32), sd((Q, F_TERM), jnp.int32),
-        S=S, CHUNK=CHUNK, R=8, k=1024,
-        FR=F_RANGE, FT=F_TERM, TV=F_TERM_VALS)
-    eqns = list(_equations(traced.jaxpr.jaxpr))
-    values = [(v.aval, inside) for eqn, inside in eqns
+    args = (sd((Q, 3 * S + 1), jnp.int32), sd((P,), jnp.int32),
+            sd((P,), jnp.float32), sd((P,), jnp.float32),
+            *[sd((), jnp.float32)] * 4)
+    if NC is None:
+        traced = K.bm25_serve_packed.jit.trace(
+            *args, S=S, CHUNK=CHUNK, R=8, k=1024)
+    else:
+        args += ((sd((P,), jnp.int32),) * NC,
+                 *[sd((Q, F_RANGE), jnp.int32)] * 4,
+                 sd((Q, F_TERM), jnp.int32),
+                 sd((Q, F_TERM, F_TERM_VALS), jnp.int32),
+                 sd((Q, F_TERM), jnp.int32))
+        traced = K.bm25_serve_packed_filtered.jit.trace(
+            *args, S=S, CHUNK=CHUNK, R=8, k=1024,
+            FR=F_RANGE, FT=F_TERM, TV=F_TERM_VALS)
+    return list(_equations(traced.jaxpr.jaxpr)), args
+
+
+def _scopes(eqns):
+    """The `packed.*` scopes of the equations, in the order they come."""
+    seen = []
+    for eqn, _ in eqns:
+        for scope in re.findall(r"packed\.\w+",
+                                str(eqn.source_info.name_stack)):
+            if scope not in seen:
+                seen.append(scope)
+    return seen
+
+
+@pytest.mark.parametrize("S", [128, 256])
+def test_the_filtered_program_holds_no_float64_and_gathers_once_a_block(
+        S, monkeypatch):
+    """The jaxpr at the cell's two shapes (Q 256, S 128 | 256, k 1024, two
+    columns): int32 filter operands, no float64 value anywhere, and no
+    gather of any per-document array. The filter's ranks come once a slot
+    block, copied with the postings by the one slot gather (3 + NC streams
+    of one kernel), and are compared before the score, with no loop: the
+    serial gather of the columns at every candidate was four fifths of the
+    program on the chip (PERF.md §5)."""
+    import jax.numpy as jnp
+    from elasticsearch_tpu.serving.packed_view import CHUNK
+    NC, Q = 2, 256
+    eqns, args = _traced_on_the_chip(monkeypatch, S, NC)
+    assert {a.dtype.name for a in args[9:]} == {"int32"}
+    assert [a.dtype.name for a in args[8]] == ["int32"] * NC
+    values = [v.aval for eqn, _ in eqns
               for v in (*eqn.invars, *eqn.outvars) if hasattr(v, "aval")]
     # (a Python literal of the shared phases, `0.0` or `-inf`, is a weakly
     # typed scalar until the next equation makes it a float32: no value)
-    assert not [a for a, inside in values if a.dtype == jnp.float64
-                and not (a.weak_type and a.shape == ()
-                         and "while" not in inside)]
-    assert {a.dtype.name for a, inside in values if "while" in inside} \
-        == {"int32", "bool"}
-    column_reads = [(eqn, inside) for eqn, inside in eqns
-                    if eqn.primitive.name == "gather"
-                    and eqn.invars[0].aval.shape[-2:] == (NC, N)]
-    (eqn, inside), = column_reads
-    assert "while" in inside
-    assert eqn.invars[0].aval.dtype == eqn.outvars[0].aval.dtype == jnp.int32
-    # FILTER_ROWS candidate rows a block, both columns at once
-    assert int(np.prod(eqn.outvars[0].aval.shape)) == NC * K.FILTER_ROWS
+    assert not [a for a in values if a.dtype == jnp.float64
+                and not (a.weak_type and a.shape == ())]
+    # the one gather left is the top-k's take of its positions' doc ids
+    gathers = [eqn.invars[0].aval.shape for eqn, _ in eqns
+               if eqn.primitive.name == "gather"]
+    assert gathers == [(Q, S * CHUNK)]
+    # (the kernel's own two loops over its block of slots aside)
+    assert not [eqn for eqn, inside in eqns if "pallas_call" not in inside
+                and eqn.primitive.name == "while"]
+    kernel, = [eqn for eqn, _ in eqns if eqn.primitive.name == "pallas_call"]
+    assert len(kernel.invars) == 1 + 3 + NC         # the starts, the streams
+    scopes = _scopes(eqns)
+    assert scopes.index("packed.gather") < scopes.index("packed.filters") \
+        < scopes.index("packed.score")
+
+
+@pytest.mark.parametrize("S", [128, 256])
+def test_the_plain_program_copies_three_streams_and_filters_nothing(
+        S, monkeypatch):
+    """The plain program (`wiki.rerank-top1000`, `wiki.match-top10`) is the
+    one it was: three streams through the slot gather, no filter scope."""
+    eqns, _ = _traced_on_the_chip(monkeypatch, S, None)
+    kernel, = [eqn for eqn, _ in eqns if eqn.primitive.name == "pallas_call"]
+    assert len(kernel.invars) == 1 + 3
+    assert "packed.filters" not in _scopes(eqns)
+    assert "packed.gather" in _scopes(eqns)
 
 
 @pytest.mark.parametrize("clause,field,bounds,want", [

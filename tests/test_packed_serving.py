@@ -650,8 +650,10 @@ class TestLivenessFold:
     @pytest.mark.parametrize("program", ["plain", "filtered"])
     def test_program_gathers_no_liveness(self, program):
         """At the shape of `wiki.rerank-top1000`: no operand of the program
-        is a liveness row, and nothing is gathered element by element at the
-        Q x S x CHUNK candidate slots but the filter columns."""
+        is a liveness row or any other array over the doc space, and
+        nothing is gathered element by element at the Q x S x CHUNK
+        candidate slots, by either program: the filtered one reads its
+        ranks from streams aligned with the postings."""
         import re
         import jax
         import jax.numpy as jnp
@@ -668,7 +670,7 @@ class TestLivenessFold:
                 *args, S=S, CHUNK=CHUNK, R=8, k=1024)
         else:
             low = K.bm25_serve_packed_filtered.jit.lower(
-                *args, sd((1, N), jnp.int32),
+                *args, (sd((P,), jnp.int32),),
                 *[sd((Q, F_RANGE), jnp.int32)] * 4,
                 sd((Q, F_TERM), jnp.int32),
                 sd((Q, F_TERM, F_TERM_VALS), jnp.int32),
@@ -677,8 +679,9 @@ class TestLivenessFold:
         text = low.as_text()
         params = re.search(r"func\.func public @main\((.*?)\)\s*->", text,
                            re.S).group(1)
-        assert "xi1>" not in params and f"tensor<{N}x" not in \
-            params.replace(f"tensor<1x{N}xi32>", "")
+        assert "xi1>" not in params and f"{N}x" not in params
+        assert params.count(f"tensor<{P}xi32>") == \
+            (1 if program == "plain" else 2)        # doc ids | and ranks
         per_slot = []           # (operand type, result type) of such gathers
         for m in re.finditer(
                 r'"stablehlo\.gather"\(.*?slice_sizes = array<i64: ([\d, ]+)>'
@@ -686,12 +689,10 @@ class TestLivenessFold:
                 r'tensor<([\dx]+)x(\w+)>', text):
             sizes, operand, dims, dtype = m.groups()
             elements = int(np.prod([int(x) for x in dims.split("x")]))
-            # the filter columns' gather takes K.FILTER_ROWS candidate rows
-            # (whole queries) at a time, inside a loop
-            if elements >= K.FILTER_ROWS and set(sizes.split(", ")) == {"1"}:
+            # an element at every candidate slot
+            if elements >= Q * S * CHUNK and set(sizes.split(", ")) == {"1"}:
                 per_slot.append((operand, dtype))
-        assert per_slot == ([] if program == "plain"
-                            else [(f"tensor<1x{N}xi32>", "i32")])
+        assert per_slot == []
 
 
 # -- a batch's operands ride the program's own dispatch (ISSUE 32) ------------

@@ -121,37 +121,38 @@ def test_kernel_compiles_for_the_chip(one_chip, N, P):
 def test_filtered_program_compiles_for_the_chip_at_the_cells_shapes(
         one_chip, monkeypatch):
     """The largest program of `wiki.filtered-top1000`: 256 bodies x 256
-    slots, k 1024, two int32 ordinal columns over 262,144 documents, 2^25
-    postings. With the gather of the columns over all 256 queries at once
-    the chip's compiler refused it: the result, [2^25, 2], is laid out
-    with its two columns padded to a tile's 128 lanes, 16 GB of the 15.75
-    the chip has, and every request with a body of more than 128 slots came
-    back as 256 item errors (my chip run, PR 33). `FILTER_ROWS` candidate
-    rows at a time since, and since PR 34 one int32 gather a block where
-    the float64 columns took two (a high and a low float32 half)."""
+    slots, k 1024, two filter columns, 2^25 postings. A gather of the
+    columns at every candidate once came out as [2^25, 2] with its two
+    columns padded to a tile's 128 lanes, 16 GB of the 15.75 the chip has
+    (measured on one v5e chip), then as 2.79 GB blocks of it. The ranks
+    now come as two more streams of the slot gather: the program's
+    temporaries are the plain program's at the shape and the two rank
+    blocks [Q, S, CHUNK] beside them, nothing padded."""
     from elasticsearch_tpu.serving.packed_view import (F_RANGE, F_TERM,
                                                        F_TERM_VALS)
-    Q, S, P, N, NC = 256, 256, 1 << 25, 262_144, 2
+    Q, S, P, NC = 256, 256, 1 << 25, 2
 
     def sd(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     # as on the chip: the Pallas gather compiled, not interpreted
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    args = (sd((Q, 3 * S + 1), jnp.int32), sd((P,), jnp.int32),
+            sd((P,), jnp.float32), sd((P,), jnp.float32),
+            *[sd((), jnp.float32)] * 4)
+    static = {"S": S, "CHUNK": CHUNK, "R": 8, "k": 1024}
     compiled = K.bm25_serve_packed_filtered.jit.lower(
-        sd((Q, 3 * S + 1), jnp.int32), sd((P,), jnp.int32),
-        sd((P,), jnp.float32), sd((P,), jnp.float32),
-        *[sd((), jnp.float32)] * 4, sd((NC, N), jnp.int32),
+        *args, (sd((P,), jnp.int32),) * NC,
         *[sd((Q, F_RANGE), jnp.int32)] * 4,
         sd((Q, F_TERM), jnp.int32), sd((Q, F_TERM, F_TERM_VALS), jnp.int32),
-        sd((Q, F_TERM), jnp.int32), S=S, CHUNK=CHUNK, R=8, k=1024,
+        sd((Q, F_TERM), jnp.int32), **static,
         FR=F_RANGE, FT=F_TERM, TV=F_TERM_VALS).compile()
+    plain = K.bm25_serve_packed.jit.lower(*args, **static).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "f64[" not in text
-    # one padded block of 2.1 GB and a few [Q, S x CHUNK] rows: 2.79 GB.
-    # (The float64 columns' two blocks took turns at one buffer: 3.01 GB,
-    # the low halves' compares beside it; all queries at once read 17.0 GB.)
-    assert compiled.memory_analysis().temp_size_in_bytes < 2_900_000_000
+    rank_blocks = NC * Q * S * CHUNK * 4
+    assert compiled.memory_analysis().temp_size_in_bytes <= \
+        plain.memory_analysis().temp_size_in_bytes + rank_blocks
 
 
 @pytest.mark.parametrize("kind,Q", [("hist", 4), ("count", 1), ("terms", 1)])
@@ -214,7 +215,9 @@ def dispatched(tmp_path_factory):
     filtered = {"query": {"bool": {
         "must": [{"match": {"body": "common filler"}}],
         "filter": [{"term": {"tag": "t1"}},
-                   {"range": {"price": {"gte": 5, "lte": 70}}}]}}, "size": 20}
+                   {"range": {"price": {"gte": 5, "lte": 70}}}],
+        "must_not": [{"range": {"price": {"gt": 30, "lt": 46}}}]}},
+        "size": 20}
     _index(node, 0, 60)
     node.search("g", plain)
     node.search("g", filtered)
@@ -256,6 +259,7 @@ def dispatched(tmp_path_factory):
     assert folds() == folded + 2
     assert hits["plain"]["hits"]["total"] == 87
     assert 0 < hits["filtered"]["hits"]["total"] < 30
+    calls["columns"] = {f: c.vals for f, c in view._filter_cols.items()}
     yield calls, before, after
     node.close()
 
@@ -264,35 +268,91 @@ def dispatched(tmp_path_factory):
 def test_program_with_the_kernel_returns_the_same_table(dispatched, program):
     calls, _, _ = dispatched
     args, static, want = calls[program]
-    filters = None
+    static, filters, ranks = dict(static), None, ()
     if program == "filtered":
-        args, filters = args[:8], args[8:] + tuple(
+        args, ranks, filters = args[:8], args[8], args[9:] + tuple(
             static.pop(n) for n in ("FR", "FT", "TV"))
+        assert len(ranks) == 2                      # the two columns
     assert K.packed_gather_form() == "sliced"       # what `want` ran
     got = jax.jit(functools.partial(
         K._serve_packed_impl, **static, filters=filters,
-        gather="blocked"))(*args)
+        gather="blocked"))(*args, ranks=ranks)
     assert got.shape == want.shape == (args[0].shape[0], 2 * static["k"] + 1)
     np.testing.assert_array_equal(np.asarray(got), want)
     assert (want[:, -1] > 0).any()                  # it found something
 
 
-def test_filter_blocks_cover_every_candidate_and_stop_after_the_last(
-        dispatched, monkeypatch):
-    """`packed.filters` takes `FILTER_ROWS` candidate rows at a time and only
-    the blocks that hold a candidate. With blocks of 16 columns the recorded
-    batch (some two hundred candidates of 16,384 lanes) takes over ten
-    blocks of the 1,024: the same table, number for number."""
+def _per_document_form(packed_q, doc_ids, tf, dl, k1, b, avgdl, const, cols,
+                       fr_col, fr_lo, fr_hi, fr_neg, ft_col, ft_targets,
+                       ft_neg, *, S, R, k, FR, FT, TV):
+    """The filtered program as it stood before its ranks rode the postings:
+    the plain program's phases, then every column `cols` i32[NC, Npad]
+    gathered at each sorted candidate's document and compared at the ends
+    of the runs. The reference of the posting-aligned form."""
+    Q, W, PAD = packed_q.shape[0], S * CHUNK, jnp.int32(K.PACKED_PAD_DOC)
+    lens, min_match = packed_q[:, S:2 * S], packed_q[:, 3 * S]
+    weights = jax.lax.bitcast_convert_type(packed_q[:, 2 * S:3 * S],
+                                           jnp.float32)
+    d, t, l = jax.vmap(jax.vmap(lambda s: tuple(
+        jax.lax.dynamic_slice(x, (s,), (CHUNK,))
+        for x in (doc_ids, tf, dl))))(packed_q[:, :S])
+    valid = jnp.arange(CHUNK, dtype=jnp.int32) < lens[:, :, None]
+    d = jnp.where(valid, d, PAD)
+    valid = valid & (d != PAD)
+    impact = t / (t + k1 * (1.0 - b + b * l / avgdl))
+    contrib = jnp.where(valid, weights[:, :, None] * impact, 0.0)
+    d, total, count = jax.lax.sort(
+        (d.reshape(Q, W), contrib.reshape(Q, W).astype(jnp.float32),
+         valid.astype(jnp.float32).reshape(Q, W)), dimension=1, num_keys=1)
+    contrib, cnt = total, count
+    for j in range(1, R):
+        same = (d == jnp.roll(d, j, axis=1)).at[:, :j].set(False)
+        total = total + jnp.where(same, jnp.roll(contrib, j, axis=1), 0.0)
+        count = count + jnp.where(same, jnp.roll(cnt, j, axis=1), 0.0)
+    ends = jnp.concatenate([d[:, :-1] != d[:, 1:], jnp.ones((Q, 1), bool)],
+                           axis=1) & (d != PAD)
+    keep = ends & (count >= min_match[:, None].astype(jnp.float32))
+    vals = cols.take(d, axis=1, mode="clip")            # [NC, Q, W]
+
+    def slot(code, match, neg):
+        v = jnp.take_along_axis(vals, jnp.maximum(code, 0)[None, :, None],
+                                axis=0)[0]
+        m = jnp.where((code == -2)[:, None], False, match(v))
+        m = jnp.where((neg > 0)[:, None], ~m, m)
+        return jnp.where((code != -1)[:, None], m, True)
+
+    for fi in range(FR):
+        keep = keep & slot(fr_col[:, fi], lambda v: (
+            v >= fr_lo[:, fi, None]) & (v <= fr_hi[:, fi, None]),
+            fr_neg[:, fi])
+    for fi in range(FT):
+        keep = keep & slot(ft_col[:, fi], lambda v: (
+            v[None] == ft_targets[:, fi, :, None].swapaxes(0, 1)).any(0),
+            ft_neg[:, fi])
+    top, pos = jax.lax.top_k(jnp.where(keep, total + const, -jnp.inf), k)
+    docs = jnp.where(top > -jnp.inf, jnp.take_along_axis(d, pos, axis=1), PAD)
+    return jnp.concatenate(
+        [jax.lax.bitcast_convert_type(top, jnp.int32), docs,
+         jnp.sum(keep, axis=1, dtype=jnp.int32)[:, None]], axis=1)
+
+
+def test_filtered_table_equals_the_per_document_forms(dispatched):
+    """On a view that a refresh extended, with tombstones folded into its
+    postings and two columns (a keyword term, a long range): the ranks a
+    batch hands the program are the columns read at each posting's document,
+    and the table is the one the column gather at the candidates (the form
+    before) gives, number for number."""
     calls, _, _ = dispatched
     args, static, want = calls["filtered"]
-    static = {k: v for k, v in static.items() if k not in ("FR", "FT", "TV")}
-    filters = args[8:] + (pv.F_RANGE, pv.F_TERM, pv.F_TERM_VALS)
-    d = np.asarray(args[1])
-    assert static["S"] * CHUNK // 16 == 1024 and (d != K.PACKED_PAD_DOC).any()
-    monkeypatch.setattr(K, "FILTER_ROWS", 16 * args[0].shape[0])
+    ranks, doc_ids = args[8], np.asarray(args[1])
+    cols = jnp.stack([calls["columns"][f] for f in ("tag", "price")])
+    for stream, col in zip(ranks, np.asarray(cols)):
+        np.testing.assert_array_equal(
+            np.asarray(stream), col[np.minimum(doc_ids, len(col) - 1)])
+    assert (doc_ids[:1 << 10] == K.PACKED_PAD_DOC).any()    # folded postings
     got = jax.jit(functools.partial(
-        K._serve_packed_impl, **static, filters=filters,
-        gather="sliced"))(*args[:8])
+        _per_document_form, **{n: v for n, v in static.items()
+                               if n != "CHUNK"}))(*args[:8], cols, *args[9:])
     np.testing.assert_array_equal(np.asarray(got), want)
     assert 0 < want[:, -1].max() < 30           # the filter kept some, not all
 

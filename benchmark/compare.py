@@ -13,7 +13,10 @@ Numbers (limits in `benchmark/limits.json`, a cell's file may override one):
   source_wrong        hits whose `_source` is not the document that was sent
   buckets_wrong       answers with an aggregation bucket count off
 
-`compare_answer` never raises on a wrong answer: it counts.
+`compare_answer` never raises on a wrong answer: it counts. A rescore's
+answer carries the reference's doubts: `alt`, a hit's score on the other side
+of its shard's window edge, `reach`, the documents a shard may keep, and
+`sure`, those it must; a knn answer its `size` (at most `k` hits).
 """
 
 from __future__ import annotations
@@ -66,12 +69,12 @@ def compare_answer(tally: Tally, label: str, body: dict, resp: dict,
     if "error" in resp or "hits" not in resp:
         tally.note("unanswered", f"{label}: {str(resp)[:200]}")
         return
-    want = ref.answer(body)
+    want = ref.answer(body, tol)
     hits = resp["hits"]
     if hits["total"] != want["total"]:
         tally.note("totals_wrong",
                    f"{label}: total {hits['total']} != {want['total']}")
-    size = body.get("size", 10)
+    size = want.get("size", body.get("size", 10))
     got = hits["hits"]
     want_n = min(size, want["total"])
     if len(got) != want_n:
@@ -98,6 +101,9 @@ def _compare_hits(tally, label, body, got, want, reference, tol) -> None:
     scores = np.array([h["_score"] for h in got], dtype=np.float64)
     tally.hits += len(ids)
     mask, ref_score = want["mask"], want["score"]
+    alt = want.get("alt", ref_score)     # a rescore's other side of its edge
+    if "reach" in want:
+        mask = mask & want["reach"]
     bad = None
     if not np.all(np.isfinite(scores)):
         bad = "non-finite score"
@@ -110,13 +116,15 @@ def _compare_hits(tally, label, body, got, want, reference, tol) -> None:
     if bad:
         tally.note("hits_wrong", f"{label}: {bad}")
         return
-    ref = ref_score[ids]
-    err = float(np.max(np.abs(scores - ref) / np.maximum(np.abs(ref), 1e-30)))
+    err = np.minimum(*(np.abs(scores - r[ids]) / np.maximum(np.abs(r[ids]),
+                                                             1e-30)
+                       for r in (ref_score, alt)))
+    err = float(np.max(err))
     tally.n["score_rel_err_max"] = max(tally.n["score_rel_err_max"], err)
     # every matching document that scores clearly above the last returned
     # hit must have been returned
-    floor = scores[-1] * (1.0 + tol)
-    better = mask & (ref_score > floor)
+    floor = scores[-1] * (1.0 + tol if scores[-1] >= 0 else 1.0 - tol)
+    better = want.get("sure", mask) & (np.minimum(ref_score, alt) > floor)
     better[ids] = False
     if better.any():
         tally.note("hits_wrong",
@@ -143,5 +151,6 @@ def compare_request(tally: Tally, label: str, request: dict, data: bytes,
             return
     else:
         items = [resp]
+    ref.prepare(bodies)
     for i, (body, item) in enumerate(zip(bodies, items)):
         compare_answer(tally, f"{label}[{i}]", body, item, ref, tol)

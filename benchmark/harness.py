@@ -1,9 +1,10 @@
 """One run of one cell: set-up, the measured window, the check, the result.
 
 Nothing here knows a cell, a configuration or a metric by name. `run` finds
-the cell in `BENCHMARK.json`, its parameters in `benchmark/workloads/`, its
-configuration's file by the path `BENCHMARK.json` gives, and each metric's
-reader through `benchmark/metrics/<metric>.json`.
+the cell in `BENCHMARK.json`, its parameters in `benchmark/workloads/` (or in
+the file its entry names under an optional `file`, as a test's cell does),
+its configuration's file by the path `BENCHMARK.json` gives, and each
+metric's reader through `benchmark/metrics/<metric>.json`.
 
 The process that calls `run` holds the chip and serves: `NodeService` +
 `HttpServer` on threads. The documents are sent by ingest workers and the
@@ -38,6 +39,11 @@ class NoDevice(Exception):
     """JAX does not see the chips the cell asks for."""
 
 
+class Unsettled(Exception):
+    """The warm-up's last allowed round still compiled or was refused: the
+    program cannot serve the cell settled, and no window is opened."""
+
+
 def load_json(*parts: str):
     with open(os.path.join(*parts)) as f:
         return json.load(f)
@@ -68,7 +74,9 @@ class Cell:
         conf, = [c for c in self.bench["configs"]
                  if c["name"] == self.entry["config"]]
         self.cfg = load_json(ROOT, conf["file"])
-        self.workload = load_json(HERE, "workloads", f"{name}.json")
+        self.workload = load_json(ROOT, self.entry["file"]) \
+            if "file" in self.entry \
+            else load_json(HERE, "workloads", f"{name}.json")
         self.harness = load_json(HERE, "harness.json")
         for key, value in (overrides or {}).items():
             if key == "chips":          # a test's virtual devices
@@ -274,11 +282,22 @@ class Serving:
             self.rate = ingest(cell, self.client, seed, procs)
             log(f"ingested {cell.cfg['documents']} documents at "
                 f"{self.rate:.0f}/s")
-            send_pilots(cell, self.client)
-            self.compiles_left, self.refused_left = self.replay()
+            self.warm_up()
         except BaseException:
             self.close()
             raise
+
+    def warm_up(self) -> None:
+        """The pilots, then the replay; `Unsettled` where its last allowed
+        round still compiled or was refused."""
+        send_pilots(self.cell, self.client)
+        self.compiles_left, self.refused_left = self.replay()
+        if self.compiles_left or self.refused_left:
+            raise Unsettled(
+                f"the warm-up did not settle: the last of "
+                f"{self.cell.workload['warmup']['rounds']} replay rounds "
+                f"compiled {self.compiles_left} programs and was refused "
+                f"{self.refused_left} requests")
 
     def replay(self) -> tuple[int, int]:
         """The warm-up: the cell's own kind of traffic through its own loop
@@ -440,6 +459,8 @@ def run(name: str, seed: int, seconds: float, trace: bool, *,
         ctally = compare.Tally()
         for i in sorted(keep & {r["i"] for r in records}):
             if i < len(requests):
+                ref.prepare(requests[i]["bodies"])
+                low.prepare(requests[i]["bodies"])
                 for j, body in enumerate(requests[i]["bodies"]):
                     compare.compare_answer(ctally, f"control {i}[{j}]", body,
                                            low.respond(body), ref,

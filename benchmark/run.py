@@ -9,7 +9,9 @@ the check compared, beside its limit). It exits non-zero and prints no result
 unless JAX reports the TPU chips the cell asks for.
 
 A watchdog armed before anything else ends the run with `correct: false` if
-set-up, window and check together pass the limit in `benchmark/harness.json`,
+set-up, window and check together pass the limit in `benchmark/harness.json`;
+a warm-up whose last allowed replay round still compiled or was refused ends
+it the same way, with a non-zero exit code, before any window opens;
 and the process leaves through `os._exit` once its children have ended, so no
 thread, pool or request under way can keep a run alive.
 """
@@ -94,6 +96,10 @@ def main(argv=None) -> int:
     except harness.NoDevice as e:
         print(f"no result: {e}", file=sys.stderr)
         leave(3, procs)
+    except harness.Unsettled as e:
+        print(f"no window: {e}", file=sys.stderr)
+        print(failure_line(str(e)))
+        leave(1, procs)
     except BaseException:
         traceback.print_exc()
         leave(1, procs)

@@ -12,6 +12,10 @@ template is a search body in which these placeholders are drawn per body:
       {"gte": lo, "lt": hi}: a window of a..b days inside the field's span
   {"$choice": [v, ...]}
       one of the values
+  {"$vector": {"field": f}}
+      one query vector from vector field f's own law (a cluster by its
+      Zipf, the configuration's centres, its `spread`, `normalize`), as
+      the list of floats that its float32 values print as (`corpus.f32_text`)
 
 Nothing rides in the window but the mix. `warmup.pilots` are bodies sent
 before it, each `warmup.copies` times at once (`pilot_requests`): shapes the
@@ -48,6 +52,9 @@ def _expand(node, cfg: dict, rng):
             return _time_range(spec, cfg, rng)
         if key == "$choice":
             return spec[int(rng.integers(0, len(spec)))]
+        if key == "$vector":
+            v = corpus.draw_vectors(rng, cfg["fields"][spec["field"]], 1)
+            return json.loads(b"[" + corpus.f32_text(v)[0][:-1] + b"]")
     return {k: _expand(v, cfg, rng) for k, v in node.items()}
 
 

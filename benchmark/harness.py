@@ -378,10 +378,12 @@ class Serving:
 def run(name: str, seed: int, seconds: float, trace: bool, *,
         platform: str = "tpu", overrides: dict | None = None,
         control: bool = False, t_start: float | None = None,
-        procs: list | None = None, bench_file: str | None = None) -> dict:
+        procs: list | None = None, bench_file: str | None = None,
+        on_settled=lambda: None) -> dict:
     """-> the result line's object. `procs` collects the child processes so
     that the caller can end them whatever happens. `bench_file` stands in
-    for `BENCHMARK.json` (a test's, with entries a later PR would add)."""
+    for `BENCHMARK.json` (a test's, with entries a later PR would add).
+    `on_settled` is called once the warm-up has settled."""
     t_start = time.perf_counter() if t_start is None else t_start
     procs = [] if procs is None else procs
     cell = Cell(name, overrides, bench_file)
@@ -396,6 +398,7 @@ def run(name: str, seed: int, seconds: float, trace: bool, *,
         log(f"window open after {opened['setup_s']:.1f} s of set-up")
 
     serving = Serving(cell, seed, devices, procs)
+    on_settled()
     try:
         w = serving.window(requests, keep, seconds, trace, on_open)
     finally:

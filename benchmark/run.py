@@ -9,7 +9,9 @@ the check compared, beside its limit). It exits non-zero and prints no result
 unless JAX reports the TPU chips the cell asks for.
 
 A watchdog armed before anything else ends the run with `correct: false` if
-set-up, window and check together pass the limit in `benchmark/harness.json`;
+set-up, window and check together pass the limit in `benchmark/harness.json`:
+`warm` where this cell's warm-up has settled on this compile cache before,
+under the same program, benchmark, cell files and checkout, `cold` otherwise;
 a warm-up whose last allowed replay round still compiled or was refused ends
 it the same way, with a non-zero exit code, before any window opens;
 and the process leaves through `os._exit` once its children have ended, so no
@@ -23,6 +25,7 @@ import time
 T_START = time.perf_counter()
 
 import argparse  # noqa: E402
+import hashlib  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import sys  # noqa: E402
@@ -69,6 +72,71 @@ def watchdog(limit_s: float, procs: list) -> None:
     t.start()
 
 
+def cache_dir() -> str:
+    """The compile cache the package uses (`elasticsearch_tpu/__init__.py`)."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") \
+        or os.path.join(ROOT, ".xla_cache")
+
+
+def _sources(d: str) -> list:
+    """The `.py` files under `d`, in a fixed order, hidden directories
+    (a run's `.run/`) left out."""
+    out = []
+    for sub, dirs, files in os.walk(d):
+        dirs[:] = sorted(x for x in dirs if not x.startswith("."))
+        out += [os.path.join(sub, f) for f in sorted(files)
+                if f.endswith(".py")]
+    return out
+
+
+def _cell_files(root: str, cell: str) -> list:
+    """The cell's workload file and its configuration's file, as
+    `BENCHMARK.json` names them; none where it names no such cell."""
+    try:
+        with open(os.path.join(root, "BENCHMARK.json")) as f:
+            bench = json.load(f)
+        w, = [w for w in bench["workloads"] if w["name"] == cell]
+        c, = [c for c in bench["configs"] if c["name"] == w["config"]]
+    except (OSError, ValueError):
+        return []
+    return [os.path.join(root, "benchmark", "workloads", cell + ".json"),
+            os.path.join(root, c["file"])]
+
+
+def program_key(cell: str, root: str = ROOT) -> str:
+    """What this cell's programs are keyed by besides their shapes: the
+    checkout's path (a Pallas kernel's key holds its call stack's source
+    positions), the package's source, and what shapes the cell's warm-up:
+    the benchmark's code and the cell's workload and configuration files."""
+    h = hashlib.sha256(os.path.abspath(root).encode())
+    for path in (_sources(os.path.join(root, "elasticsearch_tpu")) +
+                 _sources(os.path.join(root, "benchmark")) +
+                 _cell_files(root, cell)):
+        h.update(os.path.relpath(path, root).encode())
+        with open(path, "rb") as fh:
+            h.update(fh.read())
+    return h.hexdigest()
+
+
+def settled_marker(cache: str, cell: str, key: str) -> str:
+    """One mark a cell and key: checkouts that share a cache keep theirs."""
+    return os.path.join(cache, ".settled", f"{cell}.{key}")
+
+
+def mark_settled(cache: str, cell: str, key: str) -> None:
+    path = settled_marker(cache, cell, key)
+    os.makedirs(os.path.dirname(path), exist_ok=True)
+    open(path, "w").close()
+
+
+def watchdog_limit(limits: dict, cache: str, cell: str, key: str) -> float:
+    """`warm` only where this cell's warm-up has settled on this cache under
+    this program key: a cell whose programs are new compiles all of them,
+    however many entries other cells have left in the cache."""
+    warm = os.path.isfile(settled_marker(cache, cell, key))
+    return limits["warm" if warm else "cold"]
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
@@ -83,16 +151,18 @@ def main(argv=None) -> int:
     procs: list = []
     with open(os.path.join(HERE, "harness.json")) as f:
         limits = json.load(f)["watchdog_s"]
-    cache = os.environ.get("JAX_COMPILATION_CACHE_DIR") \
-        or os.path.join(ROOT, ".xla_cache")
-    cold = not (os.path.isdir(cache) and os.listdir(cache))
-    watchdog(limits["cold" if cold else "warm"], procs)
+    cache, key = cache_dir(), program_key(args.workload)
+    limit = watchdog_limit(limits, cache, args.workload, key)
+    print(f"watchdog: {limit:.0f} s", file=sys.stderr)
+    watchdog(limit, procs)
 
     import harness
     try:
         result = harness.run(args.workload, args.seed, args.seconds,
                              bool(args.trace), control=bool(args.control),
-                             t_start=T_START, procs=procs)
+                             t_start=T_START, procs=procs,
+                             on_settled=lambda: mark_settled(
+                                 cache, args.workload, key))
     except harness.NoDevice as e:
         print(f"no result: {e}", file=sys.stderr)
         leave(3, procs)

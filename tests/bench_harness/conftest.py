@@ -19,6 +19,14 @@ with open(os.path.join(REPO, "BENCHMARK.json")) as f:
     B = json.load(f)
 
 
+def entries_through(last: str) -> list:
+    """The metrics of `BENCHMARK.json` up to the per-layer entry `last`: what
+    stood when `last` was the newest, so that a test of those entries is
+    left standing by later entries and by a later cell that joins them."""
+    names = [m["name"] for m in B["per_layer"]]
+    return B["end_to_end"] + B["per_layer"][:names.index(last) + 1]
+
+
 def with_entries(bench: dict, added: dict) -> dict:
     """`bench` and the entries of one more cell: its configuration, its
     entry under `workloads`, its own per-layer metrics, and its name in the
@@ -35,6 +43,21 @@ def with_entries(bench: dict, added: dict) -> dict:
 
 with open(os.path.join(FIXTURES, "dashboard-entries.json")) as f:
     B_PLUS = with_entries(B, json.load(f))
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _own_span_table():
+    """Each file's spans in a table of its own: a file that serves requests
+    in this process leaves nothing in the span table that a later file on
+    the same worker reads whole (`/_metrics`' `es_span_*`)."""
+    try:
+        from elasticsearch_tpu.common import tracing
+    except ImportError:     # a copy of the benchmark alone serves nothing
+        yield
+        return
+    kept, tracing.AGGREGATE = tracing.AGGREGATE, tracing.SpanAggregate()
+    yield
+    tracing.AGGREGATE = kept
 
 
 @pytest.fixture(scope="session")

@@ -116,12 +116,40 @@ def test_names_are_unique_and_every_configuration_is_used(bench):
 def test_the_source_of_each_configuration_is_kept():
     """What is no cut of scale stays as the source has it: Elasticsearch
     2.0's shard request cache is off, and rally-tracks http_logs says so
-    in its index settings."""
+    in its index settings; the index has the file's shard count, which is
+    its source's (`published.number_of_shards` where the file states one,
+    Elasticsearch 2.0's default of 5 where it states none) unless the entry
+    lists `number_of_shards` under `reduced`, as one chip's share of a
+    deployment spread over more."""
     for entry in B_PLUS["configs"]:
         with open(os.path.join(REPO, entry["file"])) as f:
-            settings = json.load(f)["index_settings"]
+            cfg = json.load(f)
+        settings = cfg["index_settings"]
         assert settings.get("index.requests.cache.enable", False) is False
-        assert settings["number_of_shards"] == 5
+        assert _shard_count_kept(entry, cfg), entry["name"]
+
+
+def _shard_count_kept(entry: dict, cfg: dict) -> bool:
+    source = cfg.get("published", {}).get("number_of_shards", 5)
+    return cfg["index_settings"]["number_of_shards"] == \
+        cfg["number_of_shards"] and (cfg["number_of_shards"] == source or
+                                     "number_of_shards" in entry["reduced"])
+
+
+@pytest.mark.parametrize("published, shards, reduced, kept", [
+    ({}, 5, [], True), ({}, 3, [], False),
+    ({"number_of_shards": 2}, 2, [], True),
+    ({"number_of_shards": 2}, 5, [], False),
+    ({"number_of_shards": 20}, 5, ["number_of_shards"], True)],
+    ids=["default", "off-the-default", "published", "off-the-published",
+         "a-listed-cut"])
+def test_a_shard_count_off_its_source_stands_only_as_a_listed_cut(
+        published, shards, reduced, kept):
+    cfg = {"number_of_shards": shards, "published": published,
+           "index_settings": {"number_of_shards": shards}}
+    assert _shard_count_kept({"reduced": reduced}, cfg) is kept
+    cfg["index_settings"]["number_of_shards"] = shards + 1
+    assert not _shard_count_kept({"reduced": reduced}, cfg)
 
 
 def test_peaks_table_names_its_source():

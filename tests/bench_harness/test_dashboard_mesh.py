@@ -17,7 +17,7 @@ import pytest
 import harness
 import work
 import xtrace
-from conftest import B, BENCH
+from conftest import B, BENCH, entries_through
 from readers import hbm_balance, lane_share, roofline
 from reference import Reference
 
@@ -150,15 +150,15 @@ def test_the_traffic_is_the_one_chip_cells_mix():
 
 
 def test_the_cell_joins_the_one_chip_cells_metrics_and_adds_two():
-    listed = {m["name"] for m in B["end_to_end"] + B["per_layer"]
-              if CELL in m.get("workloads", [])}
-    beside = {m["name"] for m in B["end_to_end"] + B["per_layer"]
+    held = entries_through("hbm_chip_balance.mesh")
+    listed = {m["name"] for m in held if CELL in m.get("workloads", [])}
+    beside = {m["name"] for m in held
               if "httplogs.dash-panels" in m.get("workloads", [])}
     assert listed - beside == {"mesh_lane_share.mesh",
                                "hbm_chip_balance.mesh"}
     assert beside <= listed and "latency_p95_ms" not in listed
-    own = [m for m in B["per_layer"] if m["name"].endswith(".mesh")]
-    assert [m["workloads"] for m in own] == [[CELL], [CELL]]
+    own = [m for m in held if m["name"].endswith(".mesh")]
+    assert len(own) == 2 and all(CELL in m["workloads"] for m in own)
     assert {m["moves"] for m in own} == {"latency_p50_ms"}
 
 

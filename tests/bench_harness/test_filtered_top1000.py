@@ -18,7 +18,7 @@ import corpus
 import harness
 import traffic
 import work
-from conftest import B, BENCH
+from conftest import B, BENCH, entries_through
 from readers import label_share, span_ms
 from reference import Reference
 
@@ -203,15 +203,15 @@ def test_packed_filter_prep_reads_nothing_from_a_program_without_the_span():
 
 
 def test_the_cell_joins_the_rerank_cells_metrics_and_adds_two():
-    listed = {m["name"] for m in B["end_to_end"] + B["per_layer"]
-              if CELL in m.get("workloads", [])}
-    beside = {m["name"] for m in B["end_to_end"] + B["per_layer"]
+    held = entries_through("packed_filtered_share.qps")
+    listed = {m["name"] for m in held if CELL in m.get("workloads", [])}
+    beside = {m["name"] for m in held
               if "wiki.rerank-top1000" in m.get("workloads", [])}
     assert listed - beside == {"packed_filter_prep_ms.qps",
                                "packed_filtered_share.qps"}
     assert beside <= listed and len(beside) == 13
-    own = [m for m in B["per_layer"] if m["name"] in listed - beside]
-    assert [m["workloads"] for m in own] == [[CELL], [CELL]]
+    own = [m for m in held if m["name"] in listed - beside]
+    assert len(own) == 2 and all(CELL in m["workloads"] for m in own)
     assert {m["moves"] for m in own} == {"queries_per_s"}
     roof, = [m for m in B["per_layer"]
              if m["name"] == "packed_roofline_share.qps"]

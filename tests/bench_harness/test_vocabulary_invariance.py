@@ -4,60 +4,46 @@ harness learned dense vectors and `dis_max`: for each configuration under
 under `benchmark/workloads/`, the requests of a 40 s window, the least bytes
 of its first 32 bodies and the reference's answer (mask and score, every
 bit) to its first 8. The digests were computed before the widening and are
-pinned here. The reference runs at 12,000 documents: the code path is the
-cell's, the size one a test run can hold."""
+pinned one file each, under `pins/configs/<name>.json` and
+`pins/workloads/<cell>.json`, so that a new configuration or cell brings its
+pin as a new file. The reference runs at 12,000 documents: the code path is
+the cell's, the size one a test run can hold.
+
+    python3 tests/bench_harness/test_vocabulary_invariance.py <file>
+
+prints the pin of a file under `benchmark/configs/` or
+`benchmark/workloads/` (a cell's configuration as `BENCHMARK.json` names
+it), as the text of its pin file."""
 
 import hashlib
 import json
 import os
+import sys
 
 import numpy as np
 import pytest
 
-import corpus
-import traffic
-import work
-from conftest import BENCH
-from reference import Reference
+from conftest import B_PLUS, BENCH  # puts benchmark/ on the import path
+import corpus  # noqa: E402
+import traffic  # noqa: E402
+import work  # noqa: E402
+from reference import Reference  # noqa: E402
 
 SEED = 2 ** 31 + 5
 DOCS = 12_000
+PINS = os.path.join(os.path.dirname(os.path.abspath(__file__)), "pins")
 
-CONFIG_OF = {
-    "wiki.rerank-top1000": "wiki-bm25-5s",
-    "wiki.match-top10": "wiki-bm25-5s",
-    "wiki.filtered-top1000": "wiki-filtered-5s",
-    "httplogs.dash-panels": "httplogs-dash-5s",
-    "httplogs.dashboard-mesh": "httplogs-5s-mesh",
-    "httplogs.dashboard": "httplogs-5s",
-}
 
-CHUNK_DIGESTS = {
-    "httplogs-5s":
-        "1bcee7a7c18a9afe0ee6514046bdfe7edb9903cd17b1226a48d459451484975b",
-    "httplogs-5s-mesh":
-        "1bcee7a7c18a9afe0ee6514046bdfe7edb9903cd17b1226a48d459451484975b",
-    "httplogs-dash-5s":
-        "1bcee7a7c18a9afe0ee6514046bdfe7edb9903cd17b1226a48d459451484975b",
-    "wiki-bm25-5s":
-        "439d11ceba980d7358a35d8983753495689861a8442fe475a0757e60e6752a65",
-    "wiki-filtered-5s":
-        "d8a460de3dd5b17a89b0c68fae83c252baa297138fa6409dcbb847335a74d73a",
-}
-TRAFFIC_DIGESTS = {
-    "httplogs.dash-panels":
-        "c93115f5ecc7ccdee5d07df75a2635b2eb7b821afd26e71f8dbc6549a1a5f88a",
-    "httplogs.dashboard":
-        "964d93944287780621bc932b7e96104240882de6fcd9b52f2df4e49c889c20e2",
-    "httplogs.dashboard-mesh":
-        "9ac918cd3c7bcbfcfe4ea97e191d7ab6748bb3ccd43e4aa74bbbf06632266ba9",
-    "wiki.filtered-top1000":
-        "6fc9c995bbdcd5c8948e731e7292ad902fe9ec8415e267904467351c91949a40",
-    "wiki.match-top10":
-        "6a2fd79d53438c597d06c2f65f0b65c7fb94ae0041e6e0e9e9e934b70c0186c9",
-    "wiki.rerank-top1000":
-        "ad22aa70c109824a6fa26991164e2a0306b137105964af0a52b40f3a36cd1e37",
-}
+def _pins(kind: str) -> dict:
+    out = {}
+    for f in sorted(os.listdir(os.path.join(PINS, kind))):
+        with open(os.path.join(PINS, kind, f)) as fh:
+            out[f[:-5]] = json.load(fh)
+    return out
+
+
+CONFIG_PINS = _pins("configs")
+CELL_PINS = _pins("workloads")
 
 
 def _load(*parts):
@@ -99,20 +85,44 @@ def traffic_digest(workload: dict, cfg: dict) -> str:
 
 
 def test_every_configuration_and_cell_file_is_pinned():
-    assert set(CHUNK_DIGESTS) == {
-        f[:-5] for f in os.listdir(os.path.join(BENCH, "configs"))}
-    assert set(TRAFFIC_DIGESTS) == set(CONFIG_OF) == {
-        f[:-5] for f in os.listdir(os.path.join(BENCH, "workloads"))}
+    """Both ways: every file has its pin, and every pin has its file; a
+    cell is pinned with the configuration `BENCHMARK.json` gives it."""
+    for kind, pins in (("configs", CONFIG_PINS), ("workloads", CELL_PINS)):
+        assert set(pins) == {
+            f[:-5] for f in os.listdir(os.path.join(BENCH, kind))}, kind
+    for w in B_PLUS["workloads"]:
+        if "file" not in w:             # a fixture's traffic lies elsewhere
+            assert CELL_PINS[w["name"]]["config"] == w["config"], w["name"]
 
 
-@pytest.mark.parametrize("name", sorted(CHUNK_DIGESTS))
+@pytest.mark.parametrize("name", sorted(CONFIG_PINS))
 def test_corpus_chunk_and_payload_are_the_parents(name):
     assert chunk_digest(_load("configs", f"{name}.json")) == \
-        CHUNK_DIGESTS[name]
+        CONFIG_PINS[name]["digest"]
 
 
-@pytest.mark.parametrize("cell", sorted(TRAFFIC_DIGESTS))
+@pytest.mark.parametrize("cell", sorted(CELL_PINS))
 def test_requests_bytes_and_answers_are_the_parents(cell):
-    cfg = _load("configs", f"{CONFIG_OF[cell]}.json")
+    pin = CELL_PINS[cell]
+    cfg = _load("configs", f"{pin['config']}.json")
     assert traffic_digest(_load("workloads", f"{cell}.json"), cfg) == \
-        TRAFFIC_DIGESTS[cell]
+        pin["digest"]
+
+
+def pin_of(path: str) -> dict:
+    """The pin of a configuration's or a cell's file."""
+    kind = os.path.basename(os.path.dirname(os.path.abspath(path)))
+    name = os.path.basename(path)[:-5]
+    with open(path) as f:
+        spec = json.load(f)
+    if kind == "configs":
+        return {"digest": chunk_digest(spec)}
+    if kind != "workloads":
+        raise SystemExit(f"{path} is no configuration or cell file")
+    config, = [w["config"] for w in B_PLUS["workloads"] if w["name"] == name]
+    return {"config": config,
+            "digest": traffic_digest(spec, _load("configs", f"{config}.json"))}
+
+
+if __name__ == "__main__":
+    print(json.dumps(pin_of(sys.argv[1]), indent=1))

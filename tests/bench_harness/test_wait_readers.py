@@ -105,11 +105,13 @@ def test_the_entry_reads_what_this_pr_adds_where_it_is(name):
     if layer is None:       # the panel lane's own layer, letter for letter
         layer, = {m["layer"] for m in B["per_layer"]
                   if m["name"] == "agg_program_wall_ms.dash"}
-    assert (entry["layer"], entry["workloads"]) == (layer, cells)
+    # a later cell may join the entry, and later entries follow it
+    assert entry["layer"] == layer and set(cells) <= set(entry["workloads"])
     assert (entry["source"], entry["better"], entry["moves"]) \
         == ("program_span", "lower", "latency_p50_ms")
     assert entry["unit"] == ("%" if "share" in name else "ms")
-    assert B["per_layer"].index(entry) >= len(B["per_layer"]) - len(NEW)
+    names = [m["name"] for m in B["per_layer"]]
+    assert names.index(name) > names.index("packed_filtered_share.qps")
     with open(os.path.join(BENCH, "metrics", name + ".json")) as f:
         spec = json.load(f)
     if name.startswith("host_offcpu_share"):
